@@ -6,17 +6,19 @@ in three variants (DESIGN.md §8):
   * ``vmap_ref``  — the pre-v2 baseline: per-processor LC ``vmap``ed over
     P (and again over the batch), sum-of-squares reduction separate;
   * ``batched``   — the v2 engine path: one batched-grid fused op over
-    the whole (B, P) stack (on CPU the XLA-compiled batched reference,
-    on TPU the compiled Pallas kernels);
+    the whole (B, P) stack — the compiled Pallas kernels on TPU; off TPU
+    the XLA-compiled jnp reference, and the report says so
+    (``batched_impl``);
   * ``interpret`` — the Pallas kernels through the interpreter (the CI
     parity path; orders of magnitude slower, timed for trend only).
 
 Each cell reports achieved GB/s for the batched variant against the
 ``roofline.lc_bytes`` HBM model (A read exactly twice per step) and the
-memory-bound time floor at the backend's bandwidth estimate
-(``--bw`` overrides). Results land in ``BENCH_kernels.json`` with
-backend / device / commit provenance so CI can archive the trajectory
-alongside ``BENCH_serve.json``.
+memory-bound time floor at the device's published HBM bandwidth
+(``roofline.PEAKS``, keyed by ``device_kind``). A device without a
+published peak (the CPU among them) needs an explicit ``--bw``. Results
+land in ``BENCH_kernels.json`` with backend / device kind / commit
+provenance.
 
   PYTHONPATH=src python benchmarks/bench_kernels.py [--smoke] [--bw BPS]
 
@@ -36,7 +38,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "src"))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from roofline import BW_BY_BACKEND, git_commit, lc_bytes  # noqa: E402
+from roofline import device_peaks, git_commit, lc_bytes  # noqa: E402
 
 
 def time_variants(ops: dict, reps: int, inner: int = 3) -> dict:
@@ -71,25 +73,28 @@ def make_row_ops(b: int, p: int, m: int, n: int, interpret_cells: bool):
     y = jnp.asarray(rng.normal(size=(b, p, mp_)).astype(np.float32))
     z = jnp.asarray(rng.normal(size=(b, p, mp_)).astype(np.float32))
 
+    # the kernels take tile-aligned operands (the engine pads once)
+    ap, yp = pad_row_shards(a, y)
+    zp = jnp.pad(z, ((0, 0), (0, 0), (0, ap.shape[-2] - mp_)))
+    xp = jnp.pad(x, ((0, 0), (0, ap.shape[-1] - n)))
+    pallas = lambda interpret: jax.jit(jax.vmap(
+        lambda a_, x_, y_, z_: amp_local_grid(
+            a_, x_, y_, z_, 0.3, p, use_pallas=True, interpret=interpret)))
+
     vb = jax.jit(jax.vmap(
         lambda a_, x_, y_, z_: amp_local_ref_vmap(a_, x_, y_, z_, 0.3, p)))
-    bb = jax.jit(jax.vmap(
-        lambda a_, x_, y_, z_: amp_local_ref_grid(a_, x_, y_, z_, 0.3, p)))
-    if jax.default_backend() == "tpu":
-        bb = jax.jit(jax.vmap(
-            lambda a_, x_, y_, z_: amp_local_grid(
-                a_, x_, y_, z_, 0.3, p, use_pallas=True)))
-
     block = lambda r: jax.block_until_ready(r)
-    ops = {"vmap_ref": lambda: block(vb(a, x, y, z)),
-           "batched": lambda: block(bb(a, x, y, z))}
+    ops = {"vmap_ref": lambda: block(vb(a, x, y, z))}
+    if jax.default_backend() == "tpu":
+        bb = pallas(False)
+        ops["batched"] = lambda: block(bb(ap, xp, yp, zp))
+    else:
+        bb = jax.jit(jax.vmap(
+            lambda a_, x_, y_, z_: amp_local_ref_grid(a_, x_, y_, z_, 0.3,
+                                                      p)))
+        ops["batched"] = lambda: block(bb(a, x, y, z))
     if interpret_cells:
-        ap, yp = pad_row_shards(a, y)
-        zp = jnp.pad(z, ((0, 0), (0, 0), (0, ap.shape[-2] - mp_)))
-        xp = jnp.pad(x, ((0, 0), (0, ap.shape[-1] - n)))
-        ib = jax.jit(jax.vmap(
-            lambda a_, x_, y_, z_: amp_local_grid(
-                a_, x_, y_, z_, 0.3, p, use_pallas=True, interpret=True)))
+        ib = pallas(True)
         ops["interpret"] = lambda: block(ib(ap, xp, yp, zp))
     return ops
 
@@ -120,7 +125,8 @@ def make_col_ops(b: int, p: int, m: int, n: int, interpret_cells: bool):
 
     def step_vmap(a_, x_, z_, g_):
         # per-processor vmap baseline: one column block at a time
-        r = jax.vmap(lambda ap, xp_: ap @ xp_)(a_, x_)
+        r = jax.vmap(lambda ap, xp_: jnp.dot(
+            ap, xp_, precision=jax.lax.Precision.HIGHEST))(a_, x_)
         xn, c, _ = jax.vmap(
             lambda ap, xp_, zp: col_inner_step_ref(
                 ap[None], xp_[None], xp_[None], zp[None], g_, mask, *pri,
@@ -136,15 +142,19 @@ def make_col_ops(b: int, p: int, m: int, n: int, interpret_cells: bool):
             return r, xn, c
         return f
 
+    # the kernels take tile-aligned operands (the engine pads once)
+    apad, gpad = pad_col_shards(a, g)
+    zpad = jnp.pad(z, ((0, 0), (0, 0), (0, apad.shape[-2] - m)))
     vb = jax.jit(jax.vmap(step_vmap))
-    bb = jax.jit(jax.vmap(step_pallas(False)
-                          if jax.default_backend() == "tpu" else step_ref))
     block = lambda r: jax.block_until_ready(r)
-    ops = {"vmap_ref": lambda: block(vb(a, x, z, g)),
-           "batched": lambda: block(bb(a, x, z, g))}
+    ops = {"vmap_ref": lambda: block(vb(a, x, z, g))}
+    if jax.default_backend() == "tpu":
+        bb = jax.jit(jax.vmap(step_pallas(False)))
+        ops["batched"] = lambda: block(bb(apad, x, zpad, gpad))
+    else:
+        bb = jax.jit(jax.vmap(step_ref))
+        ops["batched"] = lambda: block(bb(a, x, z, g))
     if interpret_cells:
-        apad, gpad = pad_col_shards(a, g)
-        zpad = jnp.pad(z, ((0, 0), (0, 0), (0, apad.shape[-2] - m)))
         ib = jax.jit(jax.vmap(step_pallas(True)))
         ops["interpret"] = lambda: block(ib(apad, x, zpad, gpad))
     return ops
@@ -158,15 +168,25 @@ def main():
     ap.add_argument("--reps", type=int, default=7)
     ap.add_argument("--bw", type=float, default=None,
                     help="memory bandwidth for the roofline bound "
-                         "(default: per-backend estimate)")
+                         "(default: the device's published HBM peak; "
+                         "required where none is published, e.g. CPU)")
     ap.add_argument("--json", default="BENCH_kernels.json",
                     help="machine-readable output path ('' disables)")
     args = ap.parse_args()
 
     import jax
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     backend = jax.default_backend()
-    bw = args.bw or BW_BY_BACKEND.get(backend, BW_BY_BACKEND["cpu"])
+    kind = jax.devices()[0].device_kind
+    try:
+        bw = args.bw or device_peaks(kind)["hbm_bw"]
+    except KeyError as e:
+        sys.exit(f"{e.args[0]}: pass --bw to compare against a bandwidth "
+                 f"of your own")
+    # off TPU the "batched" variant is the jnp reference, not the kernels
+    impl = "pallas" if backend == "tpu" else "jnp_ref"
     # smoke keeps the full problem size (at M=256-class shapes the B=8
     # cells are dispatch-dominated and the vmap-vs-batched gap drowns in
     # jitter) but trims the cell grid and reps for CI wall-clock
@@ -178,12 +198,13 @@ def main():
         batches, procs = (1, 8, 32), (1, 4, 8)
 
     report = {
-        "backend": backend, "devices": jax.device_count(),
+        "backend": backend, "device_kind": kind,
+        "devices": jax.device_count(), "batched_impl": impl,
         "commit": git_commit(), "smoke": bool(args.smoke),
         "m": m, "n": n, "bw_model": bw, "cells": [],
     }
-    print(f"LC kernel suite: M={m} N={n} backend={backend} "
-          f"bw_model={bw/1e9:.0f} GB/s")
+    print(f"LC kernel suite: M={m} N={n} device={kind!r} "
+          f"batched={impl} bw_model={bw/1e9:.0f} GB/s")
     hdr = (f"{'layout':>6s} {'B':>3s} {'P':>3s} {'vmap_ref':>10s} "
            f"{'batched':>10s} {'speedup':>8s} {'GB/s':>7s} {'roofl%':>7s} "
            f"{'interpret':>10s}")

@@ -653,8 +653,18 @@ def main():
 
     import jax  # first jax import happens after XLA_FLAGS is set
 
+    if args.devices > 1 and jax.default_backend() != "cpu":
+        # the forced devices are host-platform (CPU) devices: on an
+        # accelerator backend the mesh numbers would be labelled with
+        # devices the run never used
+        sys.exit(f"--devices {args.devices} forces CPU host devices, but "
+                 f"the backend is {jax.default_backend()!r}; rerun with "
+                 f"JAX_PLATFORMS=cpu")
     assert jax.device_count() >= args.devices, \
         (jax.device_count(), args.devices)
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from roofline import git_commit  # benchmarks/ is the script dir
 

@@ -140,7 +140,6 @@ def bench_compressed_psum() -> list[str]:
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.core.compression import QuantConfig, compressed_psum
 
     n_dev = jax.device_count()
@@ -151,10 +150,10 @@ def bench_compressed_psum() -> list[str]:
     x = jnp.asarray(rng.normal(size=(n_dev, 1 << 16)).astype(np.float32))
     rows = []
     for bits in (8, 4):
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda v: compressed_psum(v[0], "d", QuantConfig(bits=bits))[0][None],
             mesh=mesh, in_specs=P("d", None), out_specs=P("d", None),
-            axis_names={"d"}, check=False))
+            axis_names={"d"}, check_vma=False))
         out = np.asarray(fn(x))[0]
         t0 = time.time()
         for _ in range(5):
@@ -186,6 +185,8 @@ def bench_roofline() -> list[str]:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     all_rows: list[str] = []
     print("=== Fig. 1 reproduction (SDR + rates per iteration) ===")
     all_rows += bench_fig1()

@@ -1,74 +1,26 @@
-"""Version-compat shims for the jax API surface this repo uses.
+"""Mesh construction and shard_map capability notes for the installed jax.
 
-The repo targets the current jax API (``jax.shard_map`` with ``axis_names``/
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``). Older installs — the
-CI/container image pins jax 0.4.x — expose the same machinery under
-``jax.experimental.shard_map.shard_map`` (with ``check_rep``/``auto``) and a
-``make_mesh`` without ``axis_types``. Everything in-repo goes through these
-two wrappers so version skew is handled in exactly one place.
+``make_mesh`` pins every axis to ``AxisType.Auto`` (the sharding-in-types
+``Explicit`` default would change how the solver's shard_maps and jits
+propagate shardings). ``PARTIAL_MANUAL_SHARD_MAP`` records that
+shard_map with *partial* manual axes (manual: some, auto: the rest) —
+the compressed pod-axis gradient fusion of ``launch/steps.py`` — is not
+usable: the XLA SPMD partitioner aborts the process with a CHECK in
+``spmd_partitioner_util.cc`` when it lowers that pattern, so it cannot
+even be probed at run time. Fully-manual shard_map — every solver path,
+``compressed_psum``, ``AmpEngine.solve_sharded`` — is unaffected.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax
-    AxisType = None
+__all__ = ["make_mesh", "PARTIAL_MANUAL_SHARD_MAP"]
 
-__all__ = ["AxisType", "axis_size", "make_mesh", "shard_map",
-           "supports_partial_manual"]
-
-
-def supports_partial_manual() -> bool:
-    """Whether shard_map with *partial* manual axes (manual: some, auto:
-    the rest) is usable on the installed jax.
-
-    jax 0.4.x exposes the pattern via the experimental ``auto=`` argument,
-    but the XLA SPMD partitioner it ships trips an ``IsManualSubgroup``
-    CHECK (a process abort, so this cannot be probed at runtime) on the
-    compressed pod-axis fusion pattern; the capability arrived with the
-    jax >= 0.5 explicit AxisType machinery. Fully-manual shard_map — all
-    of the solver paths, ``compressed_psum``, ``AmpEngine.solve_sharded``
-    — works on both lines and needs no gate.
-    """
-    return AxisType is not None
-
-
-def axis_size(axis_name):
-    """Static size of a manual mesh axis inside shard_map, on any jax.
-
-    ``lax.axis_size`` post-dates 0.4.x; ``psum`` of a unit literal is the
-    long-standing equivalent (evaluated eagerly to a Python int).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+PARTIAL_MANUAL_SHARD_MAP = False
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """jax.make_mesh with Auto axis_types when the installed jax has them."""
-    if AxisType is not None:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=(AxisType.Auto,) * len(axis_shapes))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check=False):
-    """New-style shard_map on any jax version.
-
-    ``axis_names`` is the set of *manual* mesh axes (None = all of them);
-    ``check`` maps to ``check_vma`` (new) / ``check_rep`` (old).
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, auto=auto)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_shapes))

@@ -11,8 +11,10 @@ Sec. 3.3], making the solver fully data-driven.
 This is the P=1, lossless-fusion frontend of the unified ``core/engine.py``
 solver: with one processor the LC/GC split reduces exactly to the
 centralized recursion above (same iterates, bit-for-bit math), so the whole
-solve is one scan-compiled engine call. ``amp_iteration`` is kept as the
-public single-step API.
+solve is one scan-compiled engine call. It always runs the engine's jnp
+path at ``Precision.HIGHEST``, never the Pallas kernels: it is the plain
+f32 reference the kernels are checked against on the chip.
+``amp_iteration`` is kept as the public single-step API.
 """
 from __future__ import annotations
 
@@ -27,6 +29,8 @@ from .denoisers import BernoulliGauss, eta
 from .engine import AmpEngine, EngineConfig, ExactFusion
 
 __all__ = ["AMPState", "amp_iteration", "amp_solve", "sample_problem"]
+
+_HI = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass
@@ -45,12 +49,13 @@ def amp_iteration(x, z, y, a_mat, prior: BernoulliGauss):
     """One centralized AMP iteration. Returns (x_new, z_new, sigma2_hat)."""
     m = y.shape[0]
     n = x.shape[0]
-    f = x + a_mat.T @ z
+    f = x + jnp.dot(a_mat.T, z, precision=_HI)
     sigma2_hat = jnp.sum(z * z) / m
     eta_fn = lambda v: eta(v, sigma2_hat, prior, xp=jnp)
     x_new = eta_fn(f)
     eta_mean_deriv = jax.grad(lambda v: jnp.sum(eta_fn(v)))(f).mean()
-    z_new = y - a_mat @ x_new + (n / m) * eta_mean_deriv * z
+    z_new = (y - jnp.dot(a_mat, x_new, precision=_HI)
+             + (n / m) * eta_mean_deriv * z)
     return x_new, z_new, sigma2_hat
 
 
@@ -58,7 +63,8 @@ def amp_solve(y, a_mat, prior: BernoulliGauss, n_iter: int,
               s0: np.ndarray | None = None) -> AMPTrace:
     """Run centralized AMP for ``n_iter`` iterations (one engine scan)."""
     engine = AmpEngine(
-        prior, EngineConfig(n_proc=1, n_iter=n_iter, collect_symbols=False,
+        prior, EngineConfig(n_proc=1, n_iter=n_iter, use_kernel=False,
+                            collect_symbols=False,
                             collect_xs=s0 is not None),
         ExactFusion())
     trace = engine.solve(y, a_mat)
@@ -74,5 +80,5 @@ def sample_problem(key, n: int, m: int, prior: BernoulliGauss, sigma_e2: float):
     s0 = jnp.where(support, gauss, 0.0)
     a = jax.random.normal(k3, (m, n)) / jnp.sqrt(m * 1.0)
     e = jnp.sqrt(sigma_e2) * jax.random.normal(k4, (m,))
-    y = a @ s0 + e
+    y = jnp.dot(a, s0, precision=_HI) + e
     return np.asarray(s0), np.asarray(a), np.asarray(y)

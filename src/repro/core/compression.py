@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 
 __all__ = ["QuantConfig", "quantize_blocks", "dequantize_blocks",
            "pack_int4", "unpack_int4", "compressed_psum", "quant_noise_var",
@@ -134,7 +133,7 @@ def compressed_psum(x, axis_name: str, qc: QuantConfig = QuantConfig()):
     psum up to quantization error; returns (sum, injected_noise_var) where
     injected_noise_var follows the paper's P * sigma_Q^2 accounting.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     shape = x.shape
     flat = x.reshape(-1).astype(jnp.float32)
     # chunk so every device owns flat_len/n contiguous elements

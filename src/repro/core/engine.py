@@ -64,7 +64,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec, SingleDeviceSharding
 
-from ..compat import axis_size, shard_map
 from ..kernels.amp_fused.ops import (amp_local_grid, col_inner_step,
                                      col_residual, pad_col_shards,
                                      pad_row_shards)
@@ -76,6 +75,11 @@ from .quantize import (GaussMixture, dequantize_midtread, ecsq_entropy,
 from .rate_alloc import BTController, rate_for_sigma_q2
 from .rate_distortion import RDModel
 from .state_evolution import CSProblem, se_trajectory_col
+
+# every jnp contraction on A runs at full f32 precision: on TPU the default
+# rounds matmul operands to bf16, and the jnp path is the f32 reference
+# the Pallas kernels are held to
+_HI = lax.Precision.HIGHEST
 
 __all__ = [
     "AmpEngine", "EngineConfig", "EngineTrace", "ErasureSpec",
@@ -338,7 +342,7 @@ def _drop_rescale(f_local, drop, axis: str):
     scale)`` so callers can apply the matching factors to their own noise
     accounting."""
     keep = 1.0 - drop
-    n_dev = axis_size(axis)
+    n_dev = lax.axis_size(axis)
     scale = n_dev / jnp.maximum(lax.psum(keep, axis), 1.0)
     return f_local * keep * scale, keep, scale
 
@@ -1283,7 +1287,7 @@ class AmpEngine:
         for t in range(n_inner):
             s2_p = jnp.sum(z_p * z_p, axis=-1, keepdims=True) / m_eff
             fn = lambda v, s2=s2_p: eta_fn(v, s2)
-            f_p = x + jnp.einsum("pmn,pm->pn", a_cp, z_p)
+            f_p = x + jnp.einsum("pmn,pm->pn", a_cp, z_p, precision=_HI)
             if n_mask is None:
                 x_new = fn(f_p)
                 deriv = jax.grad(lambda v: jnp.sum(fn(v)))(f_p)
@@ -1293,7 +1297,8 @@ class AmpEngine:
             c_p = jnp.sum(deriv, axis=-1) / m_eff
             if t + 1 < n_inner:
                 z_p = (g[None, :]
-                       - jnp.einsum("pmn,pn->pm", a_cp, x_new - x0)
+                       - jnp.einsum("pmn,pn->pm", a_cp, x_new - x0,
+                                    precision=_HI)
                        + c_p[:, None] * z_p)
             x = x_new
         return x, c_p, z_p
@@ -1339,7 +1344,7 @@ class AmpEngine:
                 x = x * er_keep
                 if self.cfg.layout.carry_fused:
                     coef = coef * (lax.psum(er_keep, axis)
-                                   / axis_size(axis))
+                                   / lax.axis_size(axis))
                 else:
                     coef = coef * er_keep
                 # likewise neutralize the device collectives' rescale
@@ -1348,7 +1353,8 @@ class AmpEngine:
             r_p = col_residual(a_cp, x, use_pallas=True,
                                interpret=self.cfg.kernel_interpret)
         else:
-            r_p = jnp.einsum("pmn,pn->pm", a_cp.astype(jnp.float32), x)
+            r_p = jnp.einsum("pmn,pn->pm", a_cp.astype(jnp.float32), x,
+                             precision=_HI)
         r, extra, syms = self._fuse(r_p, delta, drop)
         if er_keep is not None:
             # only the delivered packets inject quantization noise (an
@@ -1356,14 +1362,14 @@ class AmpEngine:
             if axis is None:
                 extra = extra * (jnp.sum(er_keep) / r_p.shape[0])
             else:
-                extra = extra * (lax.psum(er_keep, axis) / axis_size(axis))
+                extra = extra * (lax.psum(er_keep, axis) / lax.axis_size(axis))
         g = y - r
         # boundary Onsager correction sum_q c_q z_q^last (ColumnPartition
         # docstring); scalar * previous-g on the n_inner == 1 fast path
         if self.cfg.layout.carry_fused:
             g = g + coef * mem
         else:
-            corr = jnp.einsum("p,pm->m", coef, mem)
+            corr = jnp.einsum("p,pm->m", coef, mem, precision=_HI)
             if axis is not None:
                 corr = lax.psum(corr, axis)
             g = g + corr
@@ -1960,12 +1966,12 @@ class AmpEngine:
                     body, init, (jnp.arange(cfg.n_iter), sched, drops[:, 0]))
                 return x, outs
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 solve_fn, mesh=mesh,
                 in_specs=(PartitionSpec(axis, None, None),
                           PartitionSpec(axis, None), PartitionSpec(),
                           PartitionSpec(None, axis)),
-                out_specs=PartitionSpec(), axis_names={axis}, check=False)
+                out_specs=PartitionSpec(), axis_names={axis}, check_vma=False)
             return jax.jit(fn)
 
         return self._cached(("sharded", m, n, mesh, axis), build)
@@ -1993,11 +1999,11 @@ class AmpEngine:
                     body, init, (jnp.arange(cfg.n_iter), sched, drops[:, 0]))
                 return self._col_gather_x(x, axis), outs
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 solve_fn, mesh=mesh,
                 in_specs=(PartitionSpec(axis, None, None), PartitionSpec(),
                           PartitionSpec(), PartitionSpec(None, axis)),
-                out_specs=PartitionSpec(), axis_names={axis}, check=False)
+                out_specs=PartitionSpec(), axis_names={axis}, check_vma=False)
             return jax.jit(fn)
 
         return self._cached(("col_sharded", m, n, mesh, axis), build)
@@ -2067,11 +2073,11 @@ class AmpEngine:
                     body, init, (jnp.arange(cfg.n_iter), hp.sched, drops))
                 return x, outs
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 solve_one, mesh=mesh,
                 in_specs=(PartitionSpec(axis, None, None),
                           PartitionSpec(axis, None), PartitionSpec()),
-                out_specs=PartitionSpec(), axis_names={axis}, check=False)
+                out_specs=PartitionSpec(), axis_names={axis}, check_vma=False)
 
             def solve_padded(a_p, y_p, hp: HetParams):
                 # tile-align the global operands once, before shard_map
@@ -2109,11 +2115,11 @@ class AmpEngine:
                     body, init, (jnp.arange(cfg.n_iter), hp.sched, drops))
                 return self._col_gather_x(x, axis), outs
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 solve_one, mesh=mesh,
                 in_specs=(PartitionSpec(axis, None, None), PartitionSpec(),
                           PartitionSpec()),
-                out_specs=PartitionSpec(), axis_names={axis}, check=False)
+                out_specs=PartitionSpec(), axis_names={axis}, check_vma=False)
 
             def solve_padded(a_cp, y, hp: HetParams):
                 # tile-align the global operands once, before shard_map

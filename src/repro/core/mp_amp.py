@@ -82,8 +82,11 @@ def mp_local_step(x, z_p, onsager_coef, a_p, y_p):
     """LC: residual update + per-processor message. Returns (z_new, f_p, s2)."""
     n_proc = a_p.shape[0]
     m = a_p.shape[0] * a_p.shape[1]
-    z_new = y_p - jnp.einsum("pmn,n->pm", a_p, x) + onsager_coef * z_p
-    f_p = x[None, :] / n_proc + jnp.einsum("pmn,pm->pn", a_p, z_new)
+    hi = jax.lax.Precision.HIGHEST
+    z_new = (y_p - jnp.einsum("pmn,n->pm", a_p, x, precision=hi)
+             + onsager_coef * z_p)
+    f_p = x[None, :] / n_proc + jnp.einsum("pmn,pm->pn", a_p, z_new,
+                                           precision=hi)
     sigma2_hat = jnp.sum(z_new * z_new) / m
     return z_new, f_p, sigma2_hat
 

@@ -117,6 +117,8 @@ class RDModel:
 
     ``distortion_msg(rate, sigma_t2, n_proc)`` returns the quantization MSE
     sigma_Q^2 of one message F_t^p when coded at ``rate`` bits/element.
+    ``build=False`` loads the cached table or raises ``FileNotFoundError``
+    (a build takes tens of minutes per prior).
     """
 
     prior: BernoulliGauss
@@ -126,6 +128,7 @@ class RDModel:
     r_max: float = 12.0
     dr: float = 0.05
     n_grid: int = 769
+    build: bool = True
 
     def __post_init__(self):
         self.sigmas = np.geomspace(self.sigma_min, self.sigma_max, self.n_sigma)
@@ -136,6 +139,9 @@ class RDModel:
         if os.path.exists(path):
             z = np.load(path)
             self.log_d = z["log_d"]
+        elif not self.build:
+            raise FileNotFoundError(f"no cached RD table for {self.prior} "
+                                    f"at {path}")
         else:
             self.log_d = self._build()
             np.savez(path, log_d=self.log_d)

@@ -1,16 +1,13 @@
 """Pallas TPU kernels for the AMP local-computation (LC) step — batched
-grids over the full processor stack (kernel suite v2).
+grids over the full processor stack.
 
 The LC step is two matvecs against the same sensing-matrix shard A^p:
     z' = y - A x + b z          (contraction over N)
     f  = x/P + A^T z'           (contraction over M/P)
 
-v1 ran one (M, N) shard per ``pallas_call`` and the engine ``vmap``ed it
-over the processor axis P (and again over the request batch B), so each
-(b, p) cell was its own grid. v2 folds P into the Pallas grid as a leading
-parallel dimension — one launch covers the whole (P, M/P, N) stack with
-the same VMEM tiling — and fuses the sigma2_hat sum-of-squares reduction
-into the z-pass (the plug-in numerator accumulates into a scalar output as
+The processor axis P is the leading grid dimension: one launch covers the
+whole (P, M/P, N) stack, and the sigma2_hat sum-of-squares reduction is
+fused into the z-pass (the estimate's numerator accumulates into a scalar as
 each z tile completes, so z' is never re-read from HBM for the reduction).
 The request batch B enters the grid through the ``pallas_call`` vmap
 batching rule, which prepends a grid axis: a ``solve_many``/``solve_het``
@@ -19,13 +16,26 @@ batch is still a single kernel launch.
 A may be stored in bf16 (``EngineConfig.a_dtype``): tiles stream from HBM
 at half width and are upcast to f32 in VMEM before hitting the MXU, so
 accumulation precision is unchanged while HBM traffic on the dominant
-operand halves.
+operand halves. Every f32 contraction runs at ``Precision.HIGHEST``.
 
-Grid conventions: the reduction axis is the *last* grid dim (sequential on
-TPU), accumulating into the output tile with an init at step 0. The scalar
-``ss`` output maps every grid step to the same (1,) block, which is only
-race-free because no grid dimension is declared parallel — revisit this if
-``dimension_semantics`` ever marks P parallel on real hardware.
+TPU layout rules (the (8, 128) tiling of the last two block dims):
+
+* vectors ride lane-major as ``(P, 1, len)`` / ``(1, len)`` arrays, so a
+  block's last two dims are ``(1, tile)``: the 1 equals the array dim and
+  the tile is either a lane multiple (128) or the whole padded length;
+* A blocks are ``(1, bm, bn)`` with ``bm`` a multiple of 8 (the whole
+  padded shard) or of 128 (tiled shards, whose z' blocks must then be lane
+  multiples too) and ``bn`` a multiple of 128 — ``ops.row_tiles``;
+* scalars (the Onsager coefficient in, the sum of squares out) live in
+  SMEM as ``(1, 1)`` arrays.
+
+Grid conventions: the reduction axis is the *last* grid dim, accumulating
+into the output tile with an init at step 0. Every grid step of the
+z-pass adds into the same SMEM ``ss`` scalar, which is race-free only
+while the grid runs sequentially: its dimension semantics are therefore
+all ``"arbitrary"``. The f-pass writes disjoint (p, n) tiles and marks
+those two axes ``"parallel"``. A vmapped batch axis is always
+``"parallel"`` (Pallas adds it); each batch element owns its ``ss``.
 """
 from __future__ import annotations
 
@@ -34,25 +44,35 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BM = 128   # rows of A per tile (M axis)
-BN = 512   # cols of A per tile (N axis)
+BM = 512   # max rows of A per tile (M axis) when a shard is split
+BN = 512   # max cols of A per tile (N axis)
+
+_HI = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))   # (1, k) x (m, k) -> (1, m)
+_NN = (((1,), (0,)), ((), ()))   # (1, k) x (k, n) -> (1, n)
+_SEQUENTIAL = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
+_MATVEC_T = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _dot(u, a, dims):
+    return jax.lax.dot_general(u, a, dims, precision=_HI,
+                               preferred_element_type=jnp.float32)
 
 
 def _z_kernel(ons_ref, a_ref, x_ref, y_ref, z_ref, o_ref, ss_ref, *, nj):
     """o[p,m] = y[p,m] - sum_n A[p,m,n] x[n] + onsager * z[p,m];
-    grid (P, Mp/BM, N/BN); ss accumulates sum(o**2) as tiles complete."""
+    grid (P, Mp/bm, N/bn); ss accumulates sum(o**2) as tiles complete."""
     p, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
-        o_ref[0] = y_ref[0] + ons_ref[0] * z_ref[0]
+        o_ref[0] = y_ref[0] + ons_ref[0, 0] * z_ref[0]
 
-    a = a_ref[0].astype(jnp.float32)
-    x = x_ref[...]
-    o_ref[0] -= jax.lax.dot_general(
-        a, x[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]
+    o_ref[0] -= _dot(x_ref[...], a_ref[0].astype(jnp.float32), _NT)
 
     @pl.when(j == nj - 1)
     def _reduce():
@@ -62,26 +82,20 @@ def _z_kernel(ons_ref, a_ref, x_ref, y_ref, z_ref, o_ref, ss_ref, *, nj):
 
         @pl.when(first)
         def _first():
-            ss_ref[0] = s
+            ss_ref[0, 0] = s
 
-        @pl.when(~first)
+        @pl.when(jnp.logical_not(first))
         def _acc():
-            ss_ref[0] += s
+            ss_ref[0, 0] += s
 
 
 def _f_kernel(a_ref, z_ref, x_ref, o_ref, *, inv_p):
-    """o[p,n] = x[n]/P + sum_m A[p,m,n] z'[p,m]; grid (P, N/BN, Mp/BM)."""
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
+    """o[p,n] = x[n]/P + sum_m A[p,m,n] z'[p,m]; grid (P, N/bn, Mp/bm)."""
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[0] = inv_p * x_ref[...]
 
-    a = a_ref[0].astype(jnp.float32)    # (BM, BN) tile
-    z = z_ref[0]                         # (BM,)
-    o_ref[0] += jax.lax.dot_general(
-        z[None, :], a, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[0]
+    o_ref[0] += _dot(z_ref[0], a_ref[0].astype(jnp.float32), _NN)
 
 
 @partial(jax.jit, static_argnames=("n_proc", "interpret", "bm", "bn"))
@@ -97,48 +111,40 @@ def amp_local_pallas_grid(a_p, x, y_p, z_p, onsager, n_proc: int,
     p, mp_, n = a_p.shape
     assert mp_ % bm == 0 and n % bn == 0, (a_p.shape, bm, bn)
     ni, nj = mp_ // bm, n // bn
-    ons = jnp.asarray(onsager, jnp.float32).reshape(1)
+    ons = jnp.asarray(onsager, jnp.float32).reshape(1, 1)
+    x2 = x.reshape(1, n)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vec_m = pl.BlockSpec((1, 1, bm), lambda p, i, j: (p, 0, i))
 
     z_new, ss = pl.pallas_call(
         partial(_z_kernel, nj=nj),
         grid=(p, ni, nj),
         in_specs=[
-            pl.BlockSpec((1,), lambda p, i, j: (0,)),
+            smem,
             pl.BlockSpec((1, bm, bn), lambda p, i, j: (p, i, j)),
-            pl.BlockSpec((bn,), lambda p, i, j: (j,)),
-            pl.BlockSpec((1, bm), lambda p, i, j: (p, i)),
-            pl.BlockSpec((1, bm), lambda p, i, j: (p, i)),
+            pl.BlockSpec((1, bn), lambda p, i, j: (0, j)),
+            vec_m, vec_m,
         ],
-        out_specs=[
-            pl.BlockSpec((1, bm), lambda p, i, j: (p, i)),
-            pl.BlockSpec((1,), lambda p, i, j: (0,)),
-        ],
+        out_specs=[vec_m, smem],
         out_shape=[
-            jax.ShapeDtypeStruct((p, mp_), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
+            jax.ShapeDtypeStruct((p, 1, mp_), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(ons, a_p, x, y_p, z_p)
+    )(ons, a_p, x2, y_p.reshape(p, 1, mp_), z_p.reshape(p, 1, mp_))
 
     f = pl.pallas_call(
         partial(_f_kernel, inv_p=1.0 / n_proc),
-        grid=(p, n // bn, ni),
+        grid=(p, nj, ni),
         in_specs=[
-            pl.BlockSpec((1, bm, bn), lambda p, i, j: (p, j, i)),
-            pl.BlockSpec((1, bm), lambda p, i, j: (p, j)),
-            pl.BlockSpec((bn,), lambda p, i, j: (i,)),
+            pl.BlockSpec((1, bm, bn), lambda p, j, i: (p, i, j)),
+            pl.BlockSpec((1, 1, bm), lambda p, j, i: (p, 0, i)),
+            pl.BlockSpec((1, bn), lambda p, j, i: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda p, i, j: (p, i)),
-        out_shape=jax.ShapeDtypeStruct((p, n), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, bn), lambda p, j, i: (p, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((p, 1, n), jnp.float32),
+        compiler_params=_MATVEC_T,
         interpret=interpret,
-    )(a_p, z_new, x)
-    return z_new, f, ss[0]
-
-
-@partial(jax.jit, static_argnames=("n_proc", "interpret"))
-def amp_local_pallas(a, x, y, z, onsager, n_proc: int, interpret: bool = False):
-    """Single-shard fused LC step (v1 signature, kept for the per-op tests
-    and external callers). a (M, N) with M % BM == 0, N % BN == 0."""
-    z_new, f, _ = amp_local_pallas_grid(a[None], x, y[None], z[None],
-                                        onsager, n_proc, interpret=interpret)
-    return z_new[0], f[0]
+    )(a_p, z_new, x2)
+    return z_new.reshape(p, mp_), f.reshape(p, n), ss[0, 0]

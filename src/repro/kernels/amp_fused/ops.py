@@ -9,12 +9,15 @@ jaxpr). Zero-padding is exact end-to-end: padded rows/columns of A are
 zero, so residuals/messages in the padded region are identically zero and
 every transport maps 0 -> 0.
 
-Tile sizes adapt to the problem (``row_tiles``): full 128 x 512 MXU tiles
-when the shard is big enough, shrinking to the (8, 128) f32 minimum so
-serving-sized shards (e.g. Mp = 32) do not pay 4x padded compute.
+Tile sizes adapt to the problem (``row_tiles`` / ``col_tiles``): a shard
+of at most ``BM`` rows is one M tile padded to the 8-row sublane quantum
+(its lane-major z' block then equals the whole padded length), so
+serving-sized shards such as Mp = 75 or 100 pad to 80 / 104 rather than
+to a lane multiple; taller shards split into 128-row-aligned tiles. N
+splits into balanced 128-column-aligned tiles of at most ``BN``.
 
-``amp_local_step`` keeps the v1 single-shard signature (pads per call) for
-per-op tests and external callers; the engine no longer uses it.
+``amp_local_step`` keeps the single-shard signature (pads per call) for
+per-op tests and external callers; the engine does not use it.
 """
 from __future__ import annotations
 
@@ -24,8 +27,8 @@ import numpy as np
 
 from .amp_fused import BM, BN, amp_local_pallas_grid
 from .col import col_inner_pallas, col_residual_pallas, eta_bg_and_deriv
-from .ref import (amp_local_ref, amp_local_ref_grid, amp_local_ref_vmap,
-                  col_inner_step_ref, col_residual_ref)
+from .ref import (amp_local_ref, amp_local_ref_grid, col_inner_step_ref,
+                  col_residual_ref)
 
 __all__ = [
     "amp_local_step", "amp_local_grid", "col_residual", "col_inner_step",
@@ -48,16 +51,25 @@ def _balanced_tile(dim: int, full: int, quantum: int) -> int:
     return _round_up(-(-dim // k), quantum)
 
 
+def _m_tile(m: int, full: int) -> int:
+    """M tile for a lane-major z'/r block: the whole shard (8-row quantum)
+    when it fits one tile of ``full`` rows, else balanced 128-row tiles."""
+    if m <= full:
+        return _round_up(max(m, 1), 8)
+    return _balanced_tile(m, full, 128)
+
+
 def row_tiles(mp: int, n: int) -> tuple[int, int]:
-    """(bm, bn) for a (P, Mp, N) row-shard stack: (128, 512) MXU tiles at
-    large shards, balanced smaller tiles (8/128-aligned minimum) so small
-    or slightly-off serving shards pad by at most one quantum per tile."""
-    return _balanced_tile(mp, BM, 8), _balanced_tile(n, BN, 128)
+    """(bm, bn) for a (P, Mp, N) row-shard stack: one M tile up to ``BM``
+    rows, (<= BM, <= BN) tiles beyond; small or slightly-off serving
+    shards pad by at most one quantum per tile."""
+    return _m_tile(mp, BM), _balanced_tile(n, BN, 128)
 
 
 def col_tiles(m: int) -> int:
-    """bm for a (P, M, Np) column-shard stack (Np rides untiled)."""
-    return _balanced_tile(m, BM, 8)
+    """bm for a (P, M, Np) column-shard stack (Np rides untiled, so the
+    (bm, Np) A tile stays at 128 rows to bound VMEM)."""
+    return _m_tile(m, 128)
 
 
 def pad_row_shards(a_p, y_p):

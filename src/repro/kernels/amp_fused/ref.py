@@ -13,11 +13,20 @@ processor axis a batch dim of one op, not a ``vmap`` of P small ops) with
 the elementwise tails and the sum-of-squares fused behind one jit — this
 is the "batched grid" on CPU, and what ``benchmarks/bench_kernels.py``
 measures against the per-processor ``vmap`` baseline.
+
+Every contraction runs at ``Precision.HIGHEST``: on TPU the default f32
+matmul rounds its operands to bf16, and these oracles are the f32
+reference the kernels are held to on the chip.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+
+_HI = jax.lax.Precision.HIGHEST
+_einsum = partial(jnp.einsum, precision=_HI)
 
 
 def amp_local_ref(a, x, y, z, onsager, n_proc: int):
@@ -27,8 +36,8 @@ def amp_local_ref(a, x, y, z, onsager, n_proc: int):
         f  = x / P + A^T z'
 
     a: (M, N); x: (N,); y, z: (M,). Returns (z', f)."""
-    z_new = y - a @ x + onsager * z
-    f = x / n_proc + a.T @ z_new
+    z_new = y - jnp.dot(a, x, precision=_HI) + onsager * z
+    f = x / n_proc + jnp.dot(a.T, z_new, precision=_HI)
     return z_new, f
 
 
@@ -42,8 +51,8 @@ def amp_local_ref_grid(a_p, x, y_p, z_p, onsager, n_proc: int):
     (the sigma2_hat numerator, fused exactly like the Pallas kernels).
     """
     a32 = a_p.astype(jnp.float32)
-    z_new = y_p - jnp.einsum("pmn,n->pm", a32, x) + onsager * z_p
-    f_p = x / n_proc + jnp.einsum("pmn,pm->pn", a32, z_new)
+    z_new = y_p - _einsum("pmn,n->pm", a32, x) + onsager * z_p
+    f_p = x / n_proc + _einsum("pmn,pm->pn", a32, z_new)
     return z_new, f_p, jnp.sum(z_new * z_new)
 
 
@@ -51,7 +60,7 @@ def col_residual_ref(a_cp, x):
     """Column-layout residual contributions r_p = A_p x_p.
 
     a_cp (P, M, Np) column shards; x (P, Np). Returns (P, M)."""
-    return jnp.einsum("pmn,pn->pm", a_cp.astype(jnp.float32), x)
+    return _einsum("pmn,pn->pm", a_cp.astype(jnp.float32), x)
 
 
 def col_inner_step_ref(a_cp, x, x0, z_p, g, n_mask, m_eff,
@@ -73,14 +82,14 @@ def col_inner_step_ref(a_cp, x, x0, z_p, g, n_mask, m_eff,
 
     a32 = a_cp.astype(jnp.float32)
     s2_p = jnp.sum(z_p * z_p, axis=-1, keepdims=True) / m_eff
-    f_p = x + jnp.einsum("pmn,pm->pn", a32, z_p)
+    f_p = x + _einsum("pmn,pm->pn", a32, z_p)
     val, deriv = eta_bg_and_deriv(f_p, s2_p, eps, mu_s, sigma_s2)
     if n_mask is not None:
         val = val * n_mask
         deriv = deriv * n_mask
     c_p = jnp.sum(deriv, axis=-1) / m_eff
     if update_z:
-        z_new = (g[None, :] - jnp.einsum("pmn,pn->pm", a32, val - x0)
+        z_new = (g[None, :] - _einsum("pmn,pn->pm", a32, val - x0)
                  + c_p[:, None] * z_p)
     else:
         z_new = z_p

@@ -28,6 +28,7 @@ import time
 import jax
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..core.amp import sample_problem
 from ..core.denoisers import BernoulliGauss
 from ..core.state_evolution import CSProblem
@@ -95,6 +96,7 @@ def main():
                     help="dump the final metrics snapshot as Prometheus "
                          "text exposition format")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_req = 16 if args.smoke else args.requests
     policies = args.policies.split(",")

@@ -103,13 +103,8 @@ def init_cluster(coordinator_address: str | None = None,
     # instantiates the backend client, after which
     # jax.distributed.initialize refuses ("must be called before any JAX
     # computations are executed")
-    try:
-        from jax._src import distributed as _dist
-        already = _dist.global_state.client is not None
-    except Exception:   # private-module layout drift: assume fresh
-        already = False
     if (coordinator_address and num_processes and num_processes > 1
-            and not already):
+            and not jax.distributed.is_initialized()):
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes, process_id=process_id)

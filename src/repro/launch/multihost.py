@@ -1,11 +1,15 @@
-"""Two-process ``jax.distributed`` cluster driver (DESIGN.md §11).
+"""Two-process ``jax.distributed`` cluster drill on the CPU (DESIGN.md §11).
+
+This is a CPU drill, never a chip run: every child is pinned to
+``JAX_PLATFORMS=cpu``, so on a host with an accelerator the processes
+never contend for it (a chip belongs to one process at a time).
 
 Run with no cluster env set, this module is the **parent**: it picks
 free ports, spawns one child per process (same interpreter, same argv)
-with ``AMP_COORDINATOR`` / ``AMP_NUM_PROCESSES`` / ``AMP_PROCESS_ID``
-and ``--xla_force_host_platform_device_count`` fake devices, waits, and
-propagates the worst child exit code — the CI ``multihost`` job's entry
-point.
+with ``AMP_COORDINATOR`` / ``AMP_NUM_PROCESSES`` / ``AMP_PROCESS_ID``,
+``JAX_PLATFORMS=cpu`` and ``--xla_force_host_platform_device_count``
+fake devices, waits, and propagates the worst child exit code — the CI
+``multihost`` job's entry point. The parent itself never imports jax.
 
 With ``AMP_PROCESS_ID`` set, it is a **child**: every process joins the
 ``jax.distributed`` cluster via ``init_cluster`` (real coordinator
@@ -62,6 +66,7 @@ def parent(args) -> int:
         "AMP_COORDINATOR": f"127.0.0.1:{coord}",
         "AMP_NUM_PROCESSES": str(args.processes),
         "AMP_BACKEND_PORTS": ",".join(map(str, backend_ports)),
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": (env.get("XLA_FLAGS", "")
                       + f" --xla_force_host_platform_device_count="
                         f"{_DEVICES_PER_HOST}").strip(),
@@ -87,7 +92,7 @@ def parent(args) -> int:
             if p.poll() is None:
                 p.kill()
     worst = max(abs(c) for c in codes)
-    print(f"multihost parent: child exit codes {codes}")
+    print(f"multihost parent (CPU drill): child exit codes {codes}")
     return worst
 
 

@@ -8,6 +8,9 @@ Gradient fusion across the 'pod' axis optionally runs through the paper's
 lossy compression (core/compression.compressed_psum) inside a partial-manual
 shard_map (manual: pod; auto: data/model) — wire bytes drop 4x (int8) or 8x
 (int4) on exactly the links where the paper's technique targets its savings.
+The installed XLA aborts on that pattern (``compat.PARTIAL_MANUAL_SHARD_MAP``),
+so ``build_train_step`` refuses it instead of handing XLA a program that
+kills the process.
 """
 from __future__ import annotations
 
@@ -18,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from ..compat import PARTIAL_MANUAL_SHARD_MAP
 from ..configs.base import ModelConfig, ShapeSpec
 from ..core.compression import QuantConfig, compressed_psum
 from ..models import chunked_xent_loss, get_model, lm_logits
@@ -164,6 +167,12 @@ def build_train_step(cfg: ModelConfig, mesh, shape: ShapeSpec,
     # partitioner CHECK at 512 devices — see EXPERIMENTS.md §Dry-run notes —
     # so MoE archs currently fuse uncompressed across pods.)
     if pod_axis and tcfg.compression_bits is not None:
+        if not PARTIAL_MANUAL_SHARD_MAP:
+            raise NotImplementedError(
+                "compressed pod-axis gradient fusion needs partial-manual "
+                "shard_map, which the XLA SPMD partitioner aborts on "
+                "(compat.PARTIAL_MANUAL_SHARD_MAP); use "
+                "compression_bits=None")
         qc = QuantConfig(bits=tcfg.compression_bits)
 
         def pod_body(params, tokens, labels, aux):
@@ -179,13 +188,13 @@ def build_train_step(cfg: ModelConfig, mesh, shape: ShapeSpec,
 
         # partial-manual shard_map: specs may only mention the manual axis
         # ('pod'); data/model sharding stays under GSPMD control (auto).
-        pod_grads = shard_map(
+        pod_grads = jax.shard_map(
             pod_body, mesh=mesh,
             in_specs=({k: P() for k in p_specs},
                       P("pod", None), P("pod", None),
                       {k: P("pod", None, None) for k in aux_abstract}),
             out_specs=(P(), {k: P() for k in p_specs}, P()),
-            axis_names={"pod"}, check=False)
+            axis_names={"pod"}, check_vma=False)
     else:
         def pod_grads(params, tokens, labels, aux):  # single-pod: plain GSPMD
             loss, grads = grads_microbatched(params, tokens, labels, aux, rules)

@@ -49,6 +49,8 @@ from __future__ import annotations
 
 import dataclasses
 
+from ..kernels.amp_fused.col import COL_NP_MAX
+
 __all__ = ["BucketPolicy", "BucketKey", "bucket_for", "pad_batch_size",
            "batch_width_ladder", "placement_for", "round_up",
            "TRANSPORT_BLOCK"]
@@ -152,10 +154,13 @@ def placement_for(n: int, m: int, n_proc: int, n_devices: int,
     Aspect-ratio layout (DESIGN.md §7): tall requests (N/M >=
     ``policy.col_aspect``) whose N splits evenly over the processors run
     column-partitioned — the fusion then exchanges length-M residual
-    contributions instead of length-N messages.
+    contributions instead of length-N messages — as long as the padded
+    per-processor slice fits the column kernels (``COL_NP_MAX``); wider
+    slices stay on the row layout.
     """
-    layout = "col" if (n >= policy.col_aspect * m
-                       and n % n_proc == 0) else "row"
+    layout = "col" if (n >= policy.col_aspect * m and n % n_proc == 0
+                       and round_up(n // n_proc, policy.mp_quantum)
+                       <= COL_NP_MAX) else "row"
     if n_devices <= 1:
         return "local", layout
     if n * m >= policy.shard_elems and n_proc % n_devices == 0:

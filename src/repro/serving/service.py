@@ -287,8 +287,13 @@ class SolveService:
                  singleton_fastpath: bool = True,
                  donate: bool = True,
                  wire_model: WireModel | None = None,
-                 telemetry: bool = True):
+                 telemetry: bool = True,
+                 col_inner: int = 1):
         self.policy = policy or BucketPolicy()
+        # local AMP iterations per fusion round of every column bucket
+        # (ColumnPartition.n_inner); the column rate controllers plan for
+        # one, so lossy policies at col_inner > 1 are not rate-optimal
+        self.col_inner = col_inner
         self.collect_xs = collect_xs
         self.rate_accounting = rate_accounting
         self.use_kernel = use_kernel
@@ -326,6 +331,10 @@ class SolveService:
         self._singleton_dispatches = 0
         self._prewarm_report: dict | None = None
         self._prewarm_thread: threading.Thread | None = None
+        # a background prewarm's failure, re-raised by the next
+        # stats()/flush()/poll()/stream() collection instead of dying
+        # with its daemon thread
+        self._prewarm_error: BaseException | None = None
         # guards id assignment and engine-map mutation against a background
         # prewarm thread racing foreground submits
         self._lock = threading.RLock()
@@ -392,8 +401,16 @@ class SolveService:
             self._pending.append(self._dispatch_bucket(*full))
         return req.request_id
 
+    def _raise_prewarm_error(self):
+        """Surface (once) a failure of a background ``prewarm`` thread."""
+        with self._lock:
+            err, self._prewarm_error = self._prewarm_error, None
+        if err is not None:
+            raise RuntimeError("background prewarm failed") from err
+
     def _collect_pending(self):
         """Materialize every dispatched batch into ``_completed`` (FIFO)."""
+        self._raise_prewarm_error()
         pending, self._pending = self._pending, []
         for finalize in pending:
             self._completed.extend(finalize())
@@ -543,7 +560,8 @@ class SolveService:
                     use_kernel=self.use_kernel,
                     kernel_interpret=self.kernel_interpret,
                     collect_symbols=wire, collect_xs=self.collect_xs,
-                    layout=(ColumnPartition(n_inner=1) if key.layout == "col"
+                    layout=(ColumnPartition(n_inner=self.col_inner)
+                            if key.layout == "col"
                             else RowPartition()),
                     # batched operands are per-flush temporaries -> donate;
                     # the proc placement's jit donates only y (engine.py):
@@ -680,11 +698,20 @@ class SolveService:
         only), so reuse is safe."""
         ck = (key.layout, self._fingerprint(r), key.n_proc, key.mp_pad,
               key.n_pad, eng.cfg.a_dtype)
-        build = lambda: jnp.asarray(self._pad_a_one(key, r),
-                                    eng.cfg.a_jdtype)
+        build = lambda: self._put_a(key, self._pad_a_one(key, r), eng)
         if self._opcache is None:
             return build()
         return self._opcache.get(ck, build)
+
+    def _put_a(self, key: BucketKey, a_pad: np.ndarray, eng: AmpEngine):
+        """Upload one request's padded A shards. A processor-sharded
+        bucket places each device's processors on that device straight
+        from the host, so the whole matrix never lands on one device."""
+        if key.placement == "proc":
+            return jax.device_put(
+                a_pad.astype(eng.cfg.a_jdtype),
+                NamedSharding(self.mesh, PartitionSpec(self.mesh_axis)))
+        return jnp.asarray(a_pad, eng.cfg.a_jdtype)
 
     def _a_batch(self, key: BucketKey, batch: list, eng: AmpEngine,
                  use_cache: bool = True):
@@ -1218,12 +1245,14 @@ class SolveService:
         """AOT-compile the bucket x batch-width grid for a traffic menu of
         ``PrewarmSpec``s, so steady-state requests never block on XLA.
 
-        Blocking by default (returns the report dict); with
-        ``background=True`` compilation runs on a daemon thread (returns
-        the ``Thread``; traffic may flow immediately and converges to
-        zero-compile as programs land — per-engine compile locks serialize
-        against foreground dispatches of the same program). The report is
-        surfaced on ``stats()["prewarm"]`` either way.
+        Blocking by default (returns the report dict; a compile error
+        raises here); with ``background=True`` compilation runs on a
+        daemon thread (returns the ``Thread``; traffic may flow
+        immediately and converges to zero-compile as programs land —
+        per-engine compile locks serialize against foreground dispatches
+        of the same program), and a compile error there is raised by the
+        next ``stats()``/``flush()``/``poll()``/``stream()`` collection.
+        The report is surfaced on ``stats()["prewarm"]`` either way.
 
         Dummy operands bypass the operand cache (zero-A entries would
         poison it) and compiled programs key on operand avals, so runtime
@@ -1231,12 +1260,20 @@ class SolveService:
         """
         menu = list(menu)
         if background:
-            th = threading.Thread(target=self._prewarm_run, args=(menu,),
-                                  name="solve-prewarm", daemon=True)
+            th = threading.Thread(target=self._prewarm_background,
+                                  args=(menu,), name="solve-prewarm",
+                                  daemon=True)
             self._prewarm_thread = th
             th.start()
             return th
         return self._prewarm_run(menu)
+
+    def _prewarm_background(self, menu: list) -> None:
+        try:
+            self._prewarm_run(menu)
+        except Exception as e:   # thread boundary: hand it to the caller
+            with self._lock:
+                self._prewarm_error = e
 
     def _prewarm_run(self, menu: list) -> dict:
         t0 = time.perf_counter()
@@ -1250,8 +1287,10 @@ class SolveService:
                 a_b, y_b, params, has_bt = self._het_operands(
                     key, [req], use_cache=False)
                 hp = jax.tree.map(lambda v: np.asarray(v)[0], params)
-                eng.dispatch_sharded(a_b[0], y_b[0], hp, self.mesh,
-                                     has_bt=has_bt, compile_only=True)
+                # placed like runtime A, so the program keys alike
+                eng.dispatch_sharded(self._put_a(key, a_b[0], eng), y_b[0],
+                                     hp, self.mesh, has_bt=has_bt,
+                                     compile_only=True)
                 programs += 1
                 continue
             widths = spec.batch_widths
@@ -1364,6 +1403,7 @@ class SolveService:
         torn report where ``compiles.total`` disagrees with the engines
         that exist or demand counts reflect a different instant than the
         compile counts they are read next to."""
+        self._raise_prewarm_error()
         with self._lock:
             engines = ([(k, e, "") for k, e in self._engines.items()]
                        + [(k, e, "/wire")

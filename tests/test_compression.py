@@ -42,16 +42,15 @@ def test_compressed_psum_multidevice(multidev):
     multidev("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.compression import compressed_psum, QuantConfig
 mesh = jax.make_mesh((8,), ('d',))
 rng = np.random.default_rng(1)
 x = jnp.asarray(rng.normal(size=(8, 3000)).astype(np.float32))
 for bits, tol in ((8, 0.02), (4, 0.25)):
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda v: compressed_psum(v[0], 'd', QuantConfig(bits=bits, block=256))[0][None],
         mesh=mesh, in_specs=P('d', None), out_specs=P('d', None),
-        axis_names={'d'}, check=False))
+        axis_names={'d'}, check_vma=False))
     y = np.asarray(fn(x))
     ref = np.asarray(x).sum(0)
     rel = np.abs(y[0] - ref).max() / np.abs(ref).max()
@@ -67,13 +66,12 @@ def test_compressed_psum_int8_wire_visible(multidev):
     multidev("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.compression import compressed_psum, QuantConfig
 mesh = jax.make_mesh((8,), ('d',))
-fn = jax.jit(shard_map(
+fn = jax.jit(jax.shard_map(
     lambda v: compressed_psum(v[0], 'd', QuantConfig(bits=8, block=256))[0][None],
     mesh=mesh, in_specs=P('d', None), out_specs=P('d', None),
-    axis_names={'d'}, check=False))
+    axis_names={'d'}, check_vma=False))
 txt = fn.lower(jnp.zeros((8, 3000), jnp.float32)).compile().as_text()
 coll = [l for l in txt.splitlines() if 'all-to-all' in l or 'all-gather' in l]
 int8_coll = [l for l in coll if 's8[' in l or 'u8[' in l]
@@ -108,14 +106,13 @@ def test_compressed_psum_int4_wire_visible(multidev):
     multidev("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.compression import compressed_psum, QuantConfig
 mesh = jax.make_mesh((8,), ('d',))
 qc = QuantConfig(bits=4, block=256)
-fn = jax.jit(shard_map(
+fn = jax.jit(jax.shard_map(
     lambda v: compressed_psum(v[0], 'd', qc)[0][None],
     mesh=mesh, in_specs=P('d', None), out_specs=P('d', None),
-    axis_names={'d'}, check=False))
+    axis_names={'d'}, check_vma=False))
 n = 2999  # odd, not a multiple of the 8*256*2 chunking quantum
 txt = fn.lower(jnp.zeros((8, n), jnp.float32)).compile().as_text()
 coll = [l for l in txt.splitlines() if 'all-to-all' in l or 'all-gather' in l]

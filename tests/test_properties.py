@@ -85,13 +85,12 @@ def test_compressed_psum_zero_and_determinism(multidev):
     multidev("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.compression import compressed_psum, QuantConfig
 mesh = jax.make_mesh((4,), ('d',))
-fn = jax.jit(shard_map(
+fn = jax.jit(jax.shard_map(
     lambda v: compressed_psum(v[0], 'd', QuantConfig(bits=8, block=128))[0][None],
     mesh=mesh, in_specs=P('d', None), out_specs=P('d', None),
-    axis_names={'d'}, check=False))
+    axis_names={'d'}, check_vma=False))
 # zeros -> exactly zeros (no bias injected by the scale floor)
 z = jnp.zeros((4, 1000), jnp.float32)
 assert np.all(np.asarray(fn(z)) == 0.0)

@@ -133,6 +133,25 @@ def test_prewarm_background_thread(inst):
     assert svc.stats()["prewarm"]["programs"] == 2
 
 
+@pytest.mark.parametrize("background", [False, True])
+def test_prewarm_failure_surfaces(inst, background):
+    """A prewarm that fails raises: at once in the foreground, and from the
+    next collection (here ``stats()``) when it ran on a background thread
+    — once, not again on every later call."""
+    prior = inst[0]
+    svc = SolveService(policy=POLICY)
+    bad = [PrewarmSpec(n=N, m=M + 1, n_proc=P, n_iter=T, prior=prior)]
+    if not background:
+        with pytest.raises(AssertionError, match="not divisible"):
+            svc.prewarm(bad)
+        return
+    svc.prewarm(bad, background=True).join(timeout=120)
+    with pytest.raises(RuntimeError, match="background prewarm failed") as e:
+        svc.stats()
+    assert isinstance(e.value.__cause__, AssertionError)
+    assert svc.stats()["prewarm"] is None
+
+
 # ---------------------------------------------------------------------------
 # operand cache through the service: hits, mutation misses, eviction
 # ---------------------------------------------------------------------------

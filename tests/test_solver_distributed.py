@@ -1,19 +1,19 @@
 """Mesh-distributed MP-AMP solver tests (8 fake devices, subprocess).
 
-All solver paths are *fully-manual* shard_map and run on every supported
-jax line; only the partial-manual train-step tests below carry a skip,
-gated on the capability probe in ``repro/compat.py``.
+All solver paths are *fully-manual* shard_map; only the compressed
+pod-axis training test below carries a skip, gated on the capability
+recorded in ``repro/compat.py``.
 """
 import pytest
 
-from repro.compat import supports_partial_manual
+from repro.compat import PARTIAL_MANUAL_SHARD_MAP
 
 # The compressed pod-axis gradient fusion uses *partial-manual* shard_map
-# (manual: pod; auto: data/model) — see compat.supports_partial_manual for
-# why jax 0.4.x cannot run (or even safely probe) that pattern.
+# (manual: pod; auto: data/model) — see compat.PARTIAL_MANUAL_SHARD_MAP for
+# why the installed XLA cannot run (or even safely probe) that pattern.
 partial_manual = pytest.mark.skipif(
-    not supports_partial_manual(),
-    reason="partial-manual shard_map needs jax >= 0.5 (explicit AxisType)")
+    not PARTIAL_MANUAL_SHARD_MAP,
+    reason="the XLA SPMD partitioner aborts on partial-manual shard_map")
 
 
 def test_distributed_solver_matches_centralized(multidev):
@@ -57,12 +57,14 @@ print('ok')
 """, 8, timeout=900)
 
 
-@partial_manual
 def test_train_step_lowers_on_small_mesh(multidev):
-    """CI-scale version of the dry-run: 2x4 mesh, smoke config, pod axis."""
+    """CI-scale version of the dry-run: 2x2x2 mesh, smoke config, pod axis.
+    Exact pod fusion lowers; compressed pod fusion, which needs the
+    partial-manual shard_map the XLA partitioner aborts on, is refused
+    with an error instead of handed to XLA."""
     multidev("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.compat import PARTIAL_MANUAL_SHARD_MAP, make_mesh
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.launch.steps import build_train_step, build_serve_step, TrainStepConfig
@@ -70,16 +72,27 @@ from repro.launch.steps import build_train_step, build_serve_step, TrainStepConf
 cfg = get_config('granite-3-8b').smoke_config()
 shape = ShapeSpec('t', 64, 8, 'train')
 mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+bits = 8 if PARTIAL_MANUAL_SHARD_MAP else None
+if not PARTIAL_MANUAL_SHARD_MAP:
+    try:
+        build_train_step(cfg, mesh, shape,
+                         TrainStepConfig(microbatches=2, moe_groups=2,
+                                         compression_bits=8))
+        raise AssertionError('compressed pod fusion was not refused')
+    except NotImplementedError:
+        pass
 fn, sh, ab = build_train_step(cfg, mesh, shape,
                               TrainStepConfig(microbatches=2, moe_groups=2,
-                                              compression_bits=8))
+                                              compression_bits=bits))
 jitted = jax.jit(fn, in_shardings=(sh['params'], sh['opt_state'], sh['tokens'],
                                    sh['labels'], sh['aux']))
 comp = jitted.lower(ab['params'], ab['opt_state'], ab['tokens'], ab['labels'],
                     ab['aux']).compile()
 txt = comp.as_text()
-assert any(('s8[' in l or 'u8[' in l) and ('all-to-all' in l or 'all-gather' in l)
-           for l in txt.splitlines()), 'compressed pod fusion not visible'
+if bits is not None:
+    assert any(('s8[' in l or 'u8[' in l)
+               and ('all-to-all' in l or 'all-gather' in l)
+               for l in txt.splitlines()), 'compressed pod fusion not visible'
 
 # decode step lowers too
 shape_d = ShapeSpec('d', 128, 8, 'decode')
