@@ -1,0 +1,6 @@
+"""SE-drift tail per batch, the service's own span, in the backlog cells (moves solves_per_s)."""
+import layer
+
+
+def read(ctx):
+    return layer.span_ms(ctx, "drift")

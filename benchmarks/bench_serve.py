@@ -22,11 +22,10 @@ Measurements (DESIGN.md §5-§6, hot path §9):
     measured payload vs the model entropy H_Q, bytes-on-wire /
     time-on-air / energy columns, and (with ``--erasure``) the same load
     over a lossy link under both recovery policies, and
-  * telemetry plane (DESIGN.md §12) — B=32 throughput with the metrics /
-    span / drift instrumentation on vs off (ISSUE 9 acceptance: <=2%
-    overhead), SE-drift percentiles + incomplete-span-tree counts on the
-    latency stream, and per-frame TCP round-trips over a loopback
-    ``BackendServer`` leg in the cluster section.
+  * telemetry plane (DESIGN.md §12) — SE-drift percentiles +
+    incomplete-span-tree counts on the latency stream, and per-frame TCP
+    round-trips over a loopback ``BackendServer`` leg in the cluster
+    section. (The telemetry plane's cost is measured on the chip, PERF.md.)
 
 Timing methodology (shared with ``bench_kernels.py``): explicit warmup
 first (compiles and cache fills excluded), then min over ``--reps``
@@ -209,77 +208,6 @@ def bench_latency(n: int, m: int, p: int, t: int, n_req: int, reps: int,
         "monitored_requests": len(drifts),
         "incomplete_spans": int(incomplete),
     }, stats
-
-
-def bench_telemetry_overhead(n: int, m: int, p: int, t: int, b: int,
-                             reps: int, prewarm: bool):
-    """Telemetry-plane cost on the hot path (DESIGN.md §12): the same
-    B-request bucket through one prewarmed service with the telemetry
-    flag toggled between solves. Acceptance (ISSUE 9): <=2% throughput
-    overhead at B=32 in the deployment configuration (the SolveService
-    defaults ``ClusterService``/``amp_serve`` construct backends with,
-    i.e. rate accounting on). The dispatch-only lean config every other
-    section of this bench uses (``rate_accounting=False``) is reported
-    alongside as ``*_lean`` — the same absolute delta over a ~4x smaller
-    baseline — so the per-batch telemetry cost stays visible rather
-    than hidden by the denominator.
-
-    Methodology: one instance, flag toggled at runtime — two separately
-    constructed services differ by up to ~250us/solve from memory/
-    program layout alone, swamping the signal. Strictly alternating
-    on/off pairs (order flipped every pair), each leg averaged over a
-    short inner loop (per-solve jitter suppressed before differencing),
-    and the *median* of per-pair deltas — unlike min-over-reps, paired
-    medians cancel machine-load drift between the two variants, which
-    at a ~100us/batch signal dwarfs it on a shared box."""
-    import statistics
-
-    from repro.serving import BucketPolicy, PrewarmSpec, SolveService
-
-    prior, _, reqs, _ = make_load(n, m, p, t, b)
-    # the per-pair delta is a ~100-300us signal under ms-scale load
-    # jitter: the median needs a deep pair pool to stabilize run-to-run
-    pairs = max(reps, 60)
-    inner = 3
-
-    def measure(rate_accounting: bool):
-        svc = SolveService(policy=BucketPolicy(max_batch=max(b, 1),
-                                               n_quantum=64, mp_quantum=8),
-                           rate_accounting=rate_accounting, telemetry=True)
-        if prewarm:
-            svc.prewarm([PrewarmSpec(n=n, m=m, n_proc=p, n_iter=t,
-                                     policy="fixed", prior=prior,
-                                     batch_widths=(b,))])
-        for _ in range(3):                     # warmup: compiles + caches
-            svc.telemetry = True
-            svc.solve(reqs)
-            svc.telemetry = False
-            svc.solve(reqs)
-        deltas, offs = [], []
-        for i in range(pairs):
-            order = (True, False) if i % 2 == 0 else (False, True)
-            tt = {}
-            for on in order:
-                svc.telemetry = on
-                t0 = time.perf_counter()
-                for _ in range(inner):
-                    svc.solve(reqs)
-                tt[on] = (time.perf_counter() - t0) / inner
-            offs.append(tt[False])
-            deltas.append(tt[True] - tt[False])
-        return statistics.median(offs), statistics.median(deltas)
-
-    t_off, d_med = measure(rate_accounting=True)
-    t_off_l, d_med_l = measure(rate_accounting=False)
-    return {
-        "batch": b, "pairs": pairs, "inner": inner, "prewarm": prewarm,
-        "config": "deployment (rate_accounting=True)",
-        "req_s_on": b / (t_off + d_med), "req_s_off": b / t_off,
-        "overhead_s": d_med,
-        "overhead_frac": d_med / t_off,
-        "overhead_s_lean": d_med_l,
-        "overhead_frac_lean": d_med_l / t_off_l,
-    }
 
 
 def bench_tcp_rtt(n: int, m: int, p: int, t: int, b: int, prewarm: bool):
@@ -702,15 +630,6 @@ def main():
             "batch": b, "seq_req_s": b / dt_seq, "svc_req_s": b / dt_svc,
             "speedup": sp, "max_mse_diff": dmse})
 
-    # telemetry-plane overhead at the acceptance batch width (ISSUE 9):
-    # one prewarmed service, telemetry flag toggled, paired medians
-    tel = bench_telemetry_overhead(n, m, p, t, 32, reps, args.prewarm)
-    print(f"\ntelemetry overhead (B=32): on {tel['req_s_on']:.1f} req/s  "
-          f"off {tel['req_s_off']:.1f} req/s  "
-          f"({tel['overhead_frac'] * 100:+.2f}% deployment, "
-          f"{tel['overhead_frac_lean'] * 100:+.2f}% lean dispatch-only)")
-    report["telemetry_overhead"] = tel
-
     # hot-path latency percentiles through a prewarmed stream (ISSUE 6)
     n_req, lat_reps = (48, 2) if args.smoke else (96, 4)
     latency, counters = bench_latency(n, m, p, t, n_req, lat_reps,
@@ -849,10 +768,6 @@ def main():
         failures.append(f"B=1 speedup {speedups[1]:.2f}x below the 1x "
                         f"acceptance target (prewarm + singleton fast "
                         f"path, ISSUE 6)")
-    if tel["overhead_frac"] > 0.02:
-        failures.append(f"telemetry overhead "
-                        f"{tel['overhead_frac'] * 100:.2f}% above the 2% "
-                        f"B=32 acceptance budget (ISSUE 9)")
     if latency["incomplete_spans"] != 0:
         failures.append(f"{latency['incomplete_spans']} requests returned "
                         f"incomplete span trees (must be 0)")

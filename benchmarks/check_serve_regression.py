@@ -94,9 +94,8 @@ def main() -> int:
     # alert line (DRIFT_ALERT = 1.0 in repro.telemetry.drift) means the
     # SE predictions no longer describe realized solves — a modeling or
     # rating bug, not runner jitter. Incomplete span trees mean a
-    # dispatch path stopped stamping its stages. The overhead budget
-    # (<=2% at B=32, deployment config) is re-checked here so the
-    # archived bench surfaces a creeping hot-path cost on the PR.
+    # dispatch path stopped stamping its stages. (The telemetry plane's
+    # cost is measured on the chip, with tracing on and off; PERF.md.)
     # p95 threshold is 2x the per-request alert line: at the bench's
     # small N the drift tail is heavy with finite-size realization
     # noise (p95 ~1.2 on a healthy run), while a systematic modeling
@@ -111,12 +110,6 @@ def main() -> int:
     if bad_spans:
         warnings.append(f"{bad_spans} requests returned incomplete or "
                         f"non-monotonic span trees (must be 0)")
-    f_tel = fresh.get("telemetry_overhead") or {}
-    ovh = f_tel.get("overhead_frac")
-    if ovh is not None and ovh > 0.02:
-        lean = f_tel.get("overhead_frac_lean", 0.0) * 100
-        warnings.append(f"telemetry overhead {ovh * 100:.2f}% above the "
-                        f"2% B=32 budget (lean {lean:.2f}%)")
 
     # cluster tier (DESIGN.md §11): aggregate throughput drift at same
     # host count, plus the hard invariants (zero steady-state recompiles,

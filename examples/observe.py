@@ -65,8 +65,9 @@ def main():
         print(f"  N={n:5d} snr_decl={snr_decl:4.1f} (true {snr_true:4.1f})"
               f"  drift {drift}{flag}")
         print(f"    {tree}")
-        for name, _, t0, t1 in res.spans:
-            print(f"    {name:>10s}  {1e3 * (t1 - t0):8.3f} ms")
+        for name, _, t0, t1, *counts in res.spans:
+            print(f"    {name:>10s}  {1e3 * (t1 - t0):8.3f} ms"
+                  + (f"  {counts[0]}" if counts else ""))
 
     snap = svc.metrics()
     for metric in snap["metrics"]:

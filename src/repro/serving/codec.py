@@ -132,7 +132,10 @@ def _prior_to_dict(p: BernoulliGauss) -> dict:
 
 
 def _prior_from_dict(d: dict) -> BernoulliGauss:
-    return BernoulliGauss(**d)
+    try:
+        return BernoulliGauss(**d)
+    except TypeError as e:
+        raise CodecError(f"bad prior: {e}") from e
 
 
 def bucket_to_dict(key: BucketKey) -> dict:

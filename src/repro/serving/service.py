@@ -61,8 +61,7 @@ from ..core.rate_distortion import RDModel
 from ..core.state_evolution import CSProblem
 from ..telemetry import (DRIFT_ALERT, DRIFT_BUCKETS, MetricsRegistry,
                          prometheus_text, se_drift, se_drift_batch)
-from ..telemetry.spans import now as _tnow
-from ..telemetry.spans import span as _tspan
+from ..telemetry.spans import phase as _phase
 from .batcher import Batcher
 from .buckets import (BucketKey, BucketPolicy, batch_width_ladder,
                       bucket_for, pad_batch_size, placement_for, round_up)
@@ -199,7 +198,7 @@ class SolveResult:
     #                                      (telemetry/drift.py); None when
     #                                      telemetry is off
     spans: list | None = None            # completed trace spans
-    #                                      (admit..complete) for this
+    #                                      (admit..drift) for this
     #                                      request
 
     def mse(self, s0: np.ndarray) -> float:
@@ -262,10 +261,6 @@ _SHARDED_TRANSPORTS = {
 # a dispatched-but-unmaterialized engine call (dispatch-ahead): calling it
 # materializes the device results into SolveResults
 _Pending = Callable[[], "list[SolveResult]"]
-
-# sentinel: _finish_telemetry computes the drift itself (singleton /
-# proc-sharded paths); the batched path passes a precomputed value
-_COMPUTE = object()
 
 # the operating-point fields that must agree across a bucket group for the
 # vectorized drift path (one C-level multi-attr fetch per request beats six
@@ -341,10 +336,12 @@ class SolveService:
         # telemetry plane (DESIGN.md §12): event-driven histograms/counters
         # on the request path plus a pull-time collector over the sources
         # that already keep their own atomic counters (engine, operand
-        # cache, batcher). ``telemetry=False`` strips every hot-path write
-        # — the bench's overhead baseline.
+        # cache, batcher). ``telemetry=False`` strips every hot-path write,
+        # span and profiler annotation.
         self.telemetry = telemetry
         self._registry = None
+        # running totals of the drift tails' SE-prediction lookups
+        self._se_lookups = {"hit": 0, "miss": 0}
         # per-layout label-bound metric children (metrics._Child): the
         # dispatch tails bump these without re-resolving label keys
         self._children: dict = {}
@@ -378,26 +375,26 @@ class SolveService:
         (results buffered until ``flush``/``stream`` hands them out).
         Processor-sharded requests dispatch at once — they consume the
         whole mesh, so queuing them behind a batch buys nothing."""
-        t_admit = _tnow() if self.telemetry else 0.0
-        req = self._prepare(req)
-        key = self._key_for(req)
-        if self.telemetry:
-            # forwarded requests (cluster handoff) get their admit span
-            # appended to an own copy of the list — the frontend's decoded
-            # request must not see backend appends. Local requests stash
-            # only the admit timestamp; the dispatch tails build the span
-            # (one float attr beats a list build on the hot path, and
-            # amp_requests_total is likewise bumped per dispatched group).
-            sp = req.spans
-            if sp:
-                req.spans = [*sp, ["admit", None, t_admit, _tnow()]]
-            else:
-                req._t_admit = t_admit
+        tel = self.telemetry
+        with _phase("admit", tel) as adm:
+            req = self._prepare(req)
+            key = self._key_for(req)
+            if tel:
+                # the request carries its admit span into the queue; the
+                # phase stamps its end on exit. Forwarded requests (cluster
+                # handoff) get it appended to an own copy of their list —
+                # the frontend's decoded request must not see backend
+                # appends. Local requests keep it beside the fields, and
+                # the dispatch tails put it first in the result's list.
+                if req.spans:
+                    req.spans = [*req.spans, adm]
+                else:
+                    req._admit = adm
+            full = (None if key.placement == "proc"
+                    else self._batcher.add(key, req))
         if key.placement == "proc":
             self._pending.append(self._dispatch_bucket(key, [req]))
-            return req.request_id
-        full = self._batcher.add(key, req)
-        if full is not None:
+        elif full is not None:
             self._pending.append(self._dispatch_bucket(*full))
         return req.request_id
 
@@ -533,7 +530,9 @@ class SolveService:
             assert req.deltas is not None, "fixed policy needs deltas"
             assert len(req.deltas) == req.n_iter
         if req.policy == "dp" and req.deltas is None:
-            req = dataclasses.replace(req, deltas=self._dp_deltas(req))
+            with _phase("dp_allocate", self.telemetry):
+                deltas = self._dp_deltas(req)
+            req = dataclasses.replace(req, deltas=deltas)
         return req
 
     def _key_for(self, req: SolveRequest) -> BucketKey:
@@ -806,7 +805,8 @@ class SolveService:
         """Launch one bucket group on its placement; materialization is
         deferred to the returned ``_Pending.finalize``."""
         if key.placement == "proc":
-            return self._dispatch_proc(key, reqs)
+            (r,) = reqs     # proc requests dispatch alone, from submit
+            return self._dispatch_proc(key, r)
         if len(reqs) == 1 and self._singleton_ok(key, reqs[0]):
             return self._dispatch_singleton(key, reqs[0])
 
@@ -824,32 +824,31 @@ class SolveService:
         # trace); pure streams of either kind never double-compile
         wire = any(r.measure_wire for r in reqs)
         eng = self._engine(key, wire)
-        t_op0 = _tnow() if self.telemetry else 0.0
-        a_b = self._a_batch(key, batch, eng)
-        y_b, params, has_bt = self._y_and_params(key, batch)
-        if key.placement == "data":
-            shard = NamedSharding(self.mesh, PartitionSpec(self.mesh_axis))
-            a_b, y_b, params = jax.device_put((a_b, y_b, params), shard)
-        t_c0 = _tnow() if self.telemetry else 0.0
+        tel = self.telemetry
+        with _phase("operands", tel) as op:
+            with _phase("a_stack", tel):
+                a_b = self._a_batch(key, batch, eng)
+            with _phase("params", tel):
+                y_b, params, has_bt = self._y_and_params(key, batch)
+            if key.placement == "data":
+                shard = NamedSharding(self.mesh,
+                                      PartitionSpec(self.mesh_axis))
+                a_b, y_b, params = jax.device_put((a_b, y_b, params), shard)
         # a_b/y_b are per-flush temporaries: the donating engine consumes
         # them (the cached per-request shards behind the stack survive)
-        x_outs = eng.dispatch_het(a_b, y_b, params, has_bt=has_bt)
+        with _phase("dispatch", tel):
+            x_outs = eng.dispatch_het(a_b, y_b, params, has_bt=has_bt)
 
         def finalize() -> list[SolveResult]:
-            trace = eng.trace_of(x_outs)
-            shared = self._batch_spans(t_op0, t_c0)
-            if not self.telemetry or wire:
-                # measured-wire groups keep the per-request tail (their
-                # wire_measure span interleaves result assembly); with
-                # telemetry off there is no tail at all
-                return [self._result_one(key, r, trace, i, b_real,
-                                         shared_spans=shared)
-                        for i, r in enumerate(reqs)]
-            t_fin0 = _tnow()
-            out = [self._result_one(key, r, trace, i, b_real, defer=True)
-                   for i, r in enumerate(reqs)]
-            self._batch_tail(key, reqs, out, shared, trace, t_fin0)
-            return out
+            with _phase("pull", tel) as pl:
+                trace = eng.trace_of(x_outs)
+            shared = self._batch_spans(op, pl)
+            if shared is not None and not wire:
+                return self._batch_tail(key, reqs, trace, shared)
+            # measured-wire groups keep the per-request tail (each
+            # request's wire_measure span nests in its own results span)
+            return [self._request_tail(key, r, trace, i, b_real, shared)
+                    for i, r in enumerate(reqs)]
 
         return finalize
 
@@ -866,65 +865,75 @@ class SolveService:
             }
         return ch
 
-    def _batch_tail(self, key: BucketKey, reqs: list, results: list,
-                    shared: list, trace, t_fin0: float) -> None:
-        """Telemetry tail for one batched group in a single warm pass:
-        spans assembled per request with the operands/compute/complete
-        spans shared verbatim (the batch is the unit of execution, so
-        its finalization is one ``complete`` span), the drift-path
-        uniformity check folded into the same loop, histograms fed by
-        one bulk observe per metric. Replaces B per-request
-        ``_finish_telemetry`` calls on the hot path — the <=2% overhead
-        budget (DESIGN.md §12)."""
+    @staticmethod
+    def _admit_spans(r: SolveRequest) -> list:
+        """The spans a request brought to dispatch: a forwarded request's
+        list (frontend admit/route, then this service's admit), or a local
+        request's admit span."""
+        if r.spans:
+            return r.spans
+        adm = getattr(r, "_admit", None)
+        return [] if adm is None else [adm]
+
+    def _batch_tail(self, key: BucketKey, reqs: list, trace,
+                    shared: list) -> list[SolveResult]:
+        """Results and telemetry tail of one batched group. Its
+        finalization is one ``complete`` span, shared verbatim by every
+        request like the operands/compute/pull spans before it (the batch
+        is the unit of execution), with two children: ``results``, each
+        request's slice-out and rate accounting, and ``drift``, the SE
+        drift of the group (``_drift_tail``). Spans are assembled in one
+        pass, histograms fed by one bulk observe per metric; the latency
+        ends where ``complete`` ends, drift tail included."""
         ch = self._layout_children(key.layout)
-        t_end = _tnow()
-        sh0 = shared[0][2]
-        op_s, cp_s = shared
-        co_s = ["complete", None, t_fin0, t_end]
-        lats: list = []
-        waits: list = []
-        lat_add, wait_add = lats.append, waits.append
+        b_real = len(reqs)
+        with _phase("complete") as co:
+            with _phase("results") as rs:
+                out = [self._result_one(key, r, trace, i, b_real)
+                       for i, r in enumerate(reqs)]
+            with _phase("drift") as dr:
+                dr.append(self._drift_tail(key, reqs, out, trace, ch))
+            tail = [*shared, co, rs, dr]
+            sh0 = shared[0][2]
+            starts: list = []
+            waits: list = []
+            for r, res in zip(reqs, out):
+                head = self._admit_spans(r)
+                t_a = head[-1][3] if head else sh0
+                res.spans = [*head, ["batch_wait", None, t_a, sh0], *tail]
+                waits.append(sh0 - t_a)
+                if head and head[0][0] == "admit":
+                    starts.append(head[0][2])
+        ch["requests"].inc(b_real)
+        ch["batch_wait"].observe_many(waits)
+        if starts:
+            t_end = co[3]
+            ch["latency"].observe_many([t_end - t for t in starts])
+        return out
+
+    def _tally(self, counts: dict) -> None:
+        """Add one tail's SE-prediction lookups to the running totals."""
+        tot = self._se_lookups
+        tot["hit"] += counts["lookups"] - counts["misses"]
+        tot["miss"] += counts["misses"]
+
+    def _drift_tail(self, key: BucketKey, reqs: list, results: list,
+                    trace, ch: dict) -> dict:
+        """SE drift for a whole bucket group (DESIGN.md §12), written
+        onto the already-built results; returns the group's
+        SE-prediction lookups and misses. A group uniform in operating
+        point pays one vectorized masked log-ratio pass (one memoized
+        prediction lookup per distinct realized schedule, see
+        ``se_drift_batch``); mixed groups fall back to the per-request
+        memoized path. A BT answer's schedule is its own, so its lookup
+        misses and the SE recursion runs: the tail's cost on the chip is
+        in PERF.md."""
+        layout = "col" if key.layout == "col" else "row"
         r0 = reqs[0]
         v0 = _DRIFT_ATTRS(r0)
         p0 = r0.prior
-        uniform = True
-        for r, res in zip(reqs, results):
-            rs = r.spans
-            if rs:
-                t_a = rs[-1][3]
-                spans = [*rs, ["batch_wait", None, t_a, sh0],
-                         op_s, cp_s, co_s]
-                if rs[0][0] == "admit":
-                    lat_add(t_end - rs[0][2])
-            else:
-                # local request: submit stashed only the admit timestamp
-                # (an instant span); batch_wait covers submit -> dispatch
-                t_a = getattr(r, "_t_admit", sh0)
-                spans = [["admit", None, t_a, t_a],
-                         ["batch_wait", None, t_a, sh0], op_s, cp_s, co_s]
-                lat_add(t_end - t_a)
-            res.spans = spans
-            wait_add(sh0 - t_a)
-            if uniform and not (r.prior is p0 and _DRIFT_ATTRS(r) == v0):
-                uniform = False
-        ch["requests"].inc(len(reqs))
-        if lats:
-            ch["latency"].observe_many(lats)
-        ch["batch_wait"].observe_many(waits)
-        self._drift_tail(key, reqs, results, trace, uniform, ch)
-
-    def _drift_tail(self, key: BucketKey, reqs: list, results: list,
-                    trace, uniform: bool, ch: dict) -> None:
-        """SE drift for a whole bucket group (DESIGN.md §12), written
-        onto the already-built results. A group uniform in operating
-        point — the steady-stream common case — pays one vectorized
-        masked log-ratio pass (one memoized prediction lookup per
-        distinct realized schedule, see ``se_drift_batch``); mixed
-        groups fall back to the per-request memoized path. Amortizing
-        here is what keeps enabled telemetry inside the <=2% overhead
-        budget (``BENCH_serve.json`` telemetry_overhead)."""
-        layout = "col" if key.layout == "col" else "row"
-        r0 = reqs[0]
+        uniform = all(r.prior is p0 and _DRIFT_ATTRS(r) == v0 for r in reqs)
+        counts = {"lookups": 0, "misses": 0}
         dr: list = []
         dr_add = dr.append
         isfin = math.isfinite
@@ -936,7 +945,8 @@ class SolveService:
                 sched = ev[0] if np.array_equiv(ev[:1], ev) else ev
                 drifts = se_drift_batch(
                     r0.problem(), s2, sched, layout=layout,
-                    n_proc=r0.n_proc, erasure_rate=r0.erasure_rate)
+                    n_proc=r0.n_proc, erasure_rate=r0.erasure_rate,
+                    counts=counts)
                 for res, d in zip(results, drifts.tolist()):
                     if isfin(d):
                         res.se_drift = d
@@ -949,7 +959,8 @@ class SolveService:
                         d, _ = se_drift(r.problem(), s2_all[i, :r.n_iter],
                                         ev_all[i, :r.n_iter], layout=layout,
                                         n_proc=r.n_proc,
-                                        erasure_rate=r.erasure_rate)
+                                        erasure_rate=r.erasure_rate,
+                                        counts=counts)
                     except Exception:
                         continue
                     if isfin(d):
@@ -957,23 +968,24 @@ class SolveService:
                         dr_add(d)
         except Exception:
             # the monitor is advisory: a drift failure never fails a solve
-            return
+            dr.clear()
+        self._tally(counts)
         if dr:
             ch["drift"].observe_many(dr)
             n_alert = sum(1 for d in dr if d > DRIFT_ALERT)
             if n_alert:
                 ch["alerts"].inc(n_alert)
+        return counts
 
-    def _batch_spans(self, t_op0: float, t_c0: float) -> list | None:
-        """Batch-level spans stamped at finalize time: operand build/
-        upload (t_op0 -> dispatch) and device compute (dispatch -> trace
-        materialized). Shared verbatim by every request in the batch —
-        the batch is the unit of execution."""
-        if not self.telemetry:
+    @staticmethod
+    def _batch_spans(op: list | None, pl: list | None) -> list | None:
+        """Batch-level spans, shared verbatim by every request in the
+        batch (the batch is the unit of execution): operand build/upload,
+        device compute (dispatch -> results materialized, i.e. the end of
+        the pull) and the pull itself. None with telemetry off."""
+        if op is None:
             return None
-        t_done = _tnow()
-        return [_tspan("operands", t_op0, t_c0),
-                _tspan("compute", t_c0, t_done)]
+        return [op, ["compute", None, op[3], pl[3]], pl]
 
     def _singleton_ok(self, key: BucketKey, r: SolveRequest) -> bool:
         """Whether a lone request may skip batch padding + het-operand
@@ -995,74 +1007,76 @@ class SolveService:
         cache-resident)."""
         eng = self._single_engine(r)
         self._singleton_dispatches += 1
-        t_op0 = _tnow() if self.telemetry else 0.0
-        ck = ("single", self._fingerprint(r), r.n_proc,
-              eng.cfg.kernel_on, eng.cfg.a_dtype)
-        # _split row-splits + tile-aligns + casts; cache the result so a
-        # repeated-A stream pays it once
-        build = lambda: eng._split(np.zeros(r.m, np.float32), r.a)[0]
-        a_p = build() if self._opcache is None \
-            else self._opcache.get(ck, build)
-        p = r.n_proc
-        mp = r.m // p
-        y_p = np.asarray(r.y, np.float32).reshape(p, mp)
-        mp_pad = a_p.shape[1]
-        if mp_pad != mp:   # kernel-path tile alignment
-            y_p = np.pad(y_p, ((0, 0), (0, mp_pad - mp)))
-        if r.policy in ("fixed", "dp"):
-            sched = np.asarray(r.deltas, np.float32)
-        else:
-            sched = np.full(r.n_iter, np.inf, np.float32)
-        t_c0 = _tnow() if self.telemetry else 0.0
-        x_outs = eng.dispatch_single(a_p, y_p, r.m, r.n, sched=sched)
+        tel = self.telemetry
+        with _phase("operands", tel) as op:
+            with _phase("a_stack", tel):
+                ck = ("single", self._fingerprint(r), r.n_proc,
+                      eng.cfg.kernel_on, eng.cfg.a_dtype)
+                # _split row-splits + tile-aligns + casts; cache the
+                # result so a repeated-A stream pays it once
+                build = lambda: eng._split(np.zeros(r.m, np.float32), r.a)[0]
+                a_p = build() if self._opcache is None \
+                    else self._opcache.get(ck, build)
+            with _phase("params", tel):
+                p = r.n_proc
+                mp = r.m // p
+                y_p = np.asarray(r.y, np.float32).reshape(p, mp)
+                mp_pad = a_p.shape[1]
+                if mp_pad != mp:   # kernel-path tile alignment
+                    y_p = np.pad(y_p, ((0, 0), (0, mp_pad - mp)))
+                if r.policy in ("fixed", "dp"):
+                    sched = np.asarray(r.deltas, np.float32)
+                else:
+                    sched = np.full(r.n_iter, np.inf, np.float32)
+        with _phase("dispatch", tel):
+            x_outs = eng.dispatch_single(a_p, y_p, r.m, r.n, sched=sched)
 
         def finalize() -> list[SolveResult]:
-            trace = eng.trace_of(x_outs)
-            return [self._result_one(key, r, trace, None, 1,
-                                     shared_spans=self._batch_spans(
-                                         t_op0, t_c0))]
+            with _phase("pull", tel) as pl:
+                trace = eng.trace_of(x_outs)
+            return [self._request_tail(key, r, trace, None, 1,
+                                       self._batch_spans(op, pl))]
 
         return finalize
 
-    def _dispatch_proc(self, key: BucketKey, reqs: list) -> _Pending:
-        """Processor-sharded placement: each request owns the whole mesh for
-        one ``dispatch_sharded`` call (still padded to the bucket shape, so
-        the compile cache stays bounded). A rides from the operand cache —
-        for these mesh-sized matrices the once-per-fingerprint pad+upload
-        is the dominant saving; the sharded jit donates only y."""
+    def _dispatch_proc(self, key: BucketKey, r: SolveRequest) -> _Pending:
+        """Processor-sharded placement: the request owns the whole mesh
+        for one ``dispatch_sharded`` call (still padded to the bucket
+        shape, so the compile cache stays bounded). A rides from the
+        operand cache — for these mesh-sized matrices the
+        once-per-fingerprint pad+upload is the dominant saving; the
+        sharded jit donates only y."""
         eng = self._engine(key)
-        t_op0 = _tnow() if self.telemetry else 0.0
-        dispatched = []
-        for r in reqs:
-            assert not r.measure_wire, \
-                "measure_wire is unsupported on the processor-sharded " \
-                "placement (symbols stay per-device); pin layout/shape " \
-                "to a local or data-parallel bucket"
-            a_p = self._a_slice(key, r, eng)
-            y_b, params, has_bt = self._y_and_params(key, [r])
-            hp = jax.tree.map(lambda v: np.asarray(v)[0], params)
-            t_c0 = _tnow() if self.telemetry else 0.0
-            dispatched.append((eng.dispatch_sharded(
-                a_p, y_b[0], hp, self.mesh, has_bt=has_bt), t_c0))
+        assert not r.measure_wire, \
+            "measure_wire is unsupported on the processor-sharded " \
+            "placement (symbols stay per-device); pin layout/shape " \
+            "to a local or data-parallel bucket"
+        tel = self.telemetry
+        with _phase("operands", tel) as op:
+            with _phase("a_stack", tel):
+                a_p = self._a_slice(key, r, eng)
+            with _phase("params", tel):
+                y_b, params, has_bt = self._y_and_params(key, [r])
+                hp = jax.tree.map(lambda v: np.asarray(v)[0], params)
+        with _phase("dispatch", tel):
+            x_outs = eng.dispatch_sharded(a_p, y_b[0], hp, self.mesh,
+                                          has_bt=has_bt)
 
         def finalize() -> list[SolveResult]:
-            return [self._result_one(key, r, eng.trace_of(x_outs), None, 1,
-                                     shared_spans=self._batch_spans(
-                                         t_op0, t_c0))
-                    for r, (x_outs, t_c0) in zip(reqs, dispatched)]
+            with _phase("pull", tel) as pl:
+                trace = eng.trace_of(x_outs)
+            return [self._request_tail(key, r, trace, None, 1,
+                                       self._batch_spans(op, pl))]
 
         return finalize
 
     def _result_one(self, key: BucketKey, r: SolveRequest, trace,
-                    i: int | None, batch_size: int,
-                    shared_spans: list | None = None,
-                    drift=_COMPUTE, defer: bool = False) -> SolveResult:
+                    i: int | None, batch_size: int) -> SolveResult:
         """Unpad one request's slice of a trace (``i=None``: unbatched
-        processor-sharded trace). ``defer=True`` (the batched hot path)
-        skips the per-request telemetry tail: the caller has the drift
-        precomputed and assembles spans + histograms for the whole group
-        in ``_batch_tail``."""
-        t_fin0 = _tnow() if self.telemetry and not defer else 0.0
+        singleton / processor-sharded trace) and account its rates. With
+        telemetry on, a measured-wire result carries its
+        ``wire_measure`` span for the tail to place; the tails fill
+        ``se_drift`` and the span list."""
         t = r.n_iter
         sel = (lambda a: a[:t]) if i is None else (lambda a: a[i, :t])
         x_pad = trace.x if i is None else trace.x[i]
@@ -1085,21 +1099,11 @@ class SolveService:
             # payload = length-N messages (row) / length-M residual
             # contributions (col); padding columns quantize zeros
             n_elem = r.m if key.layout == "col" else r.n
-            t_w0 = _tnow() if self.telemetry else 0.0
-            wire = measure_wire(syms[:t, :, :n_elem], deltas, n_elem,
-                                drop=self._drop_mask(r),
-                                recovery=r.recovery,
-                                model=self.wire_model)
-            if self.telemetry:
-                wire_span = _tspan("wire_measure", t_w0)
-        if defer:
-            # batched hot path: _batch_tail/_drift_tail fill spans and
-            # se_drift for the whole group after the listcomp
-            drift, spans = None, None
-        else:
-            drift, spans = self._finish_telemetry(
-                key, r, s2, extra_var, t_fin0, shared_spans, wire_span,
-                drift=drift)
+            with _phase("wire_measure", self.telemetry) as wire_span:
+                wire = measure_wire(syms[:t, :, :n_elem], deltas, n_elem,
+                                    drop=self._drop_mask(r),
+                                    recovery=r.recovery,
+                                    model=self.wire_model)
         return SolveResult(
             request_id=r.request_id,
             x=x.copy(),
@@ -1111,60 +1115,53 @@ class SolveService:
             payload_bytes=None if wire is None else wire["payload_bytes"],
             time_on_air_s=None if wire is None else wire["time_on_air_s"],
             energy_j=None if wire is None else wire["energy_j"],
-            se_drift=drift, spans=spans,
+            spans=None if wire_span is None else [wire_span],
         )
 
-    def _finish_telemetry(self, key: BucketKey, r: SolveRequest, s2,
-                          extra_var, t_fin0: float,
-                          shared_spans: list | None,
-                          wire_span: list | None, drift=_COMPUTE):
-        """Per-request telemetry tail for the singleton / proc-sharded /
-        measured-wire paths (the batched hot path uses ``_batch_tail``
-        instead): SE drift vs the operating point's prediction (memoized
-        — telemetry/drift.py) plus span assembly (batch_wait derived from
-        the admit span's end to the group's operand-build start) and the
-        latency/drift histograms."""
-        if not self.telemetry:
-            return None, None
+    def _request_tail(self, key: BucketKey, r: SolveRequest, trace,
+                      i: int | None, batch_size: int,
+                      shared: list | None) -> SolveResult:
+        """One request's result and telemetry tail, for the singleton,
+        processor-sharded and measured-wire paths (the batched hot path
+        uses ``_batch_tail``): the same ``complete`` span with its
+        ``results`` and ``drift`` children, per request, and the
+        latency/drift histograms. ``shared`` is None with telemetry off."""
+        if shared is None:
+            return self._result_one(key, r, trace, i, batch_size)
         ch = self._layout_children(key.layout)
+        sh0 = shared[0][2]
+        with _phase("complete") as co:
+            with _phase("results") as rs:
+                res = self._result_one(key, r, trace, i, batch_size)
+            with _phase("drift") as dr:
+                counts = {"lookups": 0, "misses": 0}
+                try:
+                    d, _ = se_drift(
+                        r.problem(), res.sigma2_hat, res.extra_var,
+                        layout="col" if key.layout == "col" else "row",
+                        n_proc=r.n_proc, erasure_rate=r.erasure_rate,
+                        counts=counts)
+                except Exception:
+                    # a drift failure must never fail the solve: the
+                    # monitor is advisory (NaN drift shows up in the
+                    # histogram's absence)
+                    d = math.nan
+                self._tally(counts)
+                dr.append(counts)
+            if math.isfinite(d):
+                res.se_drift = d
+                ch["drift"].observe(d)
+                if d > DRIFT_ALERT:
+                    ch["alerts"].inc()
+            head = self._admit_spans(r)
+            t_a = head[-1][3] if head else sh0
+            res.spans = [*head, ["batch_wait", None, t_a, sh0], *shared,
+                         co, rs, *(res.spans or ()), dr]
         ch["requests"].inc()
-        if drift is _COMPUTE:
-            try:
-                drift, _ = se_drift(
-                    r.problem(), s2, extra_var,
-                    layout="col" if key.layout == "col" else "row",
-                    n_proc=r.n_proc, erasure_rate=r.erasure_rate)
-            except Exception:
-                # a drift failure must never fail the solve: the monitor
-                # is advisory (NaN drift shows up in the histogram's
-                # absence)
-                drift = None
-            if drift is not None and not math.isfinite(drift):
-                drift = None
-        if drift is not None:
-            ch["drift"].observe(drift)
-            if drift > DRIFT_ALERT:
-                ch["alerts"].inc()
-        spans = list(r.spans or [])
-        if not spans:
-            # local request: submit stashed only the admit timestamp
-            t_a = getattr(r, "_t_admit", None)
-            if t_a is not None:
-                spans = [["admit", None, t_a, t_a]]
-        if shared_spans:
-            t_admit_end = spans[-1][3] if spans else shared_spans[0][2]
-            spans.append(["batch_wait", None, t_admit_end,
-                          shared_spans[0][2]])
-            spans.extend(shared_spans)
-            ch["batch_wait"].observe(shared_spans[0][2] - t_admit_end)
-        if wire_span is not None:
-            spans.append(wire_span)
-        t_tail0 = wire_span[3] if wire_span is not None else t_fin0
-        t_end = _tnow()
-        spans.append(["complete", None, t_tail0, t_end])
-        if spans and spans[0][0] == "admit":
-            ch["latency"].observe(t_end - spans[0][2])
-        return drift, spans
+        ch["batch_wait"].observe(sh0 - t_a)
+        if head and head[0][0] == "admit":
+            ch["latency"].observe(co[3] - head[0][2])
+        return res
 
     def _rates(self, req: SolveRequest, s2, deltas, bt_rates,
                extra_var) -> np.ndarray:
@@ -1342,9 +1339,15 @@ class SolveService:
 
     def _collect_metrics(self, reg: MetricsRegistry) -> None:
         """Snapshot-time collector: mirror the sources that already keep
-        their own atomic counters into the registry (no hot-path writes —
-        the ≤2% telemetry-overhead budget, DESIGN.md §12)."""
+        their own atomic counters into the registry (no hot-path writes;
+        the telemetry plane's cost on the chip is in PERF.md)."""
         st = self.stats()
+        lk = reg.counter("amp_se_prediction_lookups_total",
+                         "SE-prediction lookups of the drift tails, by "
+                         "memo result (a miss runs the SE recursion)",
+                         ("result",))
+        for result, v in self._se_lookups.items():
+            lk.set_total(v, result=result)
         comp = reg.counter("amp_engine_compiles_total",
                            "XLA compiles per bucket engine", ("bucket",))
         disp = reg.counter("amp_engine_dispatches_total",

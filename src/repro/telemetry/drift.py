@@ -27,8 +27,12 @@ solve (e.g. the request declares the wrong SNR, or the quantizer's true
 MSE is not what the RD table claims) lands decades off on the log scale.
 
 Predictions are memoized on the operating point (prior, shape, SNR,
-layout, P, T, erasure rate, rounded quantizer schedule): a steady-state
-request stream pays one dict hit per request, not an SE recursion.
+layout, P, T, erasure rate, rounded quantizer schedule): a request that
+repeats one pays a dict hit, not an SE recursion. A BT request's
+realized schedule depends on its own signal, so its lookup misses.
+Callers that pass ``counts`` (a dict with ``lookups`` and ``misses``)
+get them tallied per answer: a miss is an answer whose prediction the SE
+recursion had to compute.
 """
 from __future__ import annotations
 
@@ -51,10 +55,10 @@ _cache: dict = {}
 _CACHE_MAX = 4096
 # second-level cache in front of ``se_prediction``: keyed by the raw
 # float32 schedule bytes instead of the 5-sig-digit rounded tuple, so a
-# steady stream pays ~1us of key construction per request instead of
-# ~5us of per-element string formatting (the <=2% telemetry-overhead
-# budget, DESIGN.md §12). Bit-identical schedules — the steady-state
-# case, since they come from the same compiled program — always hit.
+# repeated schedule pays ~1us of key construction per request instead
+# of ~5us of per-element string formatting. Bit-identical schedules —
+# lossless and fixed-schedule requests of one operating point — always
+# hit. The tail's cost on the chip, hits and misses, is in PERF.md.
 _fast_cache: dict = {}
 
 
@@ -67,9 +71,11 @@ def _sched_key(extra_var: Optional[np.ndarray], t: int) -> tuple:
 
 def se_prediction(prob: CSProblem, t_max: int, extra_var,
                   *, layout: str = "row", n_proc: int = 1,
-                  erasure_rate: float = 0.0, n_inner: int = 1) -> np.ndarray:
+                  erasure_rate: float = 0.0, n_inner: int = 1,
+                  counts: Optional[dict] = None) -> np.ndarray:
     """Predicted per-iteration variance trajectory (length ``t_max``) for
-    the operating point, memoized process-wide."""
+    the operating point, memoized process-wide; a computed one counts as
+    a miss in ``counts``."""
     key = (prob.n, prob.m, prob.snr_db,
            prob.prior.eps, prob.prior.mu_s, prob.prior.sigma_s,
            layout, int(n_proc), int(n_inner), float(erasure_rate),
@@ -78,6 +84,8 @@ def se_prediction(prob: CSProblem, t_max: int, extra_var,
         pred = _cache.get(key)
     if pred is not None:
         return pred
+    if counts is not None:
+        counts["misses"] += 1
     sq = (np.zeros(t_max) if extra_var is None
           else np.asarray(extra_var, dtype=np.float64)[:t_max] / max(n_proc, 1))
     if layout == "col":
@@ -95,11 +103,15 @@ def se_prediction(prob: CSProblem, t_max: int, extra_var,
 
 
 def _fast_prediction(prob: CSProblem, t_max: int, extra_var, layout: str,
-                     n_proc: int, erasure_rate: float,
-                     n_inner: int) -> tuple:
+                     n_proc: int, erasure_rate: float, n_inner: int,
+                     counts: Optional[dict] = None, answers: int = 1
+                     ) -> tuple:
     """Returns ``(pred, log_pred, ok, ok_all)`` — the prediction plus its
     precomputed log and validity mask (``pred > 0`` and finite), so the
-    batched drift stat pays only the realized-side numpy ops per call."""
+    batched drift stat pays only the realized-side numpy ops per call.
+    The lookup serves ``answers`` answers in ``counts``."""
+    if counts is not None:
+        counts["lookups"] += answers
     ev_b = (None if extra_var is None else
             np.ascontiguousarray(extra_var[:t_max],
                                  dtype=np.float32).tobytes())
@@ -111,7 +123,7 @@ def _fast_prediction(prob: CSProblem, t_max: int, extra_var, layout: str,
     if entry is None:
         pred = se_prediction(prob, t_max, extra_var, layout=layout,
                              n_proc=n_proc, erasure_rate=erasure_rate,
-                             n_inner=n_inner)
+                             n_inner=n_inner, counts=counts)
         ok = (pred > 0.0) & np.isfinite(pred)
         with np.errstate(divide="ignore", invalid="ignore"):
             log_pred = np.where(ok, np.log(np.where(ok, pred, 1.0)), 0.0)
@@ -125,8 +137,8 @@ def _fast_prediction(prob: CSProblem, t_max: int, extra_var, layout: str,
 
 def se_drift(prob: CSProblem, sigma2_hat, extra_var=None,
              *, layout: str = "row", n_proc: int = 1,
-             erasure_rate: float = 0.0, n_inner: int = 1
-             ) -> Tuple[float, np.ndarray]:
+             erasure_rate: float = 0.0, n_inner: int = 1,
+             counts: Optional[dict] = None) -> Tuple[float, np.ndarray]:
     """Compare a realized ``sigma2_hat`` trajectory against its SE
     prediction.  Returns ``(drift, predicted)`` with
     ``drift = mean_t |ln(realized[t]/predicted[t])|``; NaN when no
@@ -134,7 +146,7 @@ def se_drift(prob: CSProblem, sigma2_hat, extra_var=None,
     s2 = np.asarray(sigma2_hat, dtype=np.float64)
     t_max = len(s2)
     pred = _fast_prediction(prob, t_max, extra_var, layout, n_proc,
-                            erasure_rate, n_inner)[0]
+                            erasure_rate, n_inner, counts)[0]
     # T is small (<= a few dozen): a scalar loop beats the ~8 numpy-op
     # masked pipeline by an order of magnitude on the hot path
     tot, k = 0.0, 0
@@ -149,8 +161,8 @@ def se_drift(prob: CSProblem, sigma2_hat, extra_var=None,
 
 def se_drift_batch(prob: CSProblem, sigma2_hat, extra_var=None,
                    *, layout: str = "row", n_proc: int = 1,
-                   erasure_rate: float = 0.0, n_inner: int = 1
-                   ) -> np.ndarray:
+                   erasure_rate: float = 0.0, n_inner: int = 1,
+                   counts: Optional[dict] = None) -> np.ndarray:
     """Vectorized ``se_drift`` over a batch sharing one operating point:
     ``sigma2_hat`` is ``(B, T)``; ``extra_var`` is either one length-T
     realized quantizer schedule shared by every row, or a ``(B, T)``
@@ -159,7 +171,8 @@ def se_drift_batch(prob: CSProblem, sigma2_hat, extra_var=None,
     stay on the vectorized path instead of degrading to B scalar
     ``se_drift`` calls). One masked log-ratio pass covers every row —
     the batched dispatch path's telemetry tail (DESIGN.md §12). Rows
-    with no well-defined ratio come back NaN."""
+    with no well-defined ratio come back NaN. A schedule shared by every
+    row counts one lookup per row and at most one miss."""
     s2 = np.asarray(sigma2_hat, dtype=np.float64)
     ev = None if extra_var is None else np.asarray(extra_var)
     if ev is not None and ev.ndim == 2:
@@ -169,13 +182,15 @@ def se_drift_batch(prob: CSProblem, sigma2_hat, extra_var=None,
         ok_all = True
         for i in range(s2.shape[0]):
             _, lp, okp, oa = _fast_prediction(prob, t_max, ev[i], layout,
-                                              n_proc, erasure_rate, n_inner)
+                                              n_proc, erasure_rate, n_inner,
+                                              counts)
             log_pred[i] = lp
             ok_pred[i] = okp
             ok_all = ok_all and oa
     else:
         _, log_pred, ok_pred, ok_all = _fast_prediction(
-            prob, s2.shape[1], ev, layout, n_proc, erasure_rate, n_inner)
+            prob, s2.shape[1], ev, layout, n_proc, erasure_rate, n_inner,
+            counts, s2.shape[0])
     # clean-trace fast path (the steady-state common case): every entry
     # strictly positive and finite on both sides, so the mask machinery
     # — masked ufuncs are markedly slower than plain ones — and the

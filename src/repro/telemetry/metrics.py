@@ -13,8 +13,8 @@ Design notes (why this is not prometheus_client):
 - Hot-path cost is one dict lookup + float add under a per-registry
   lock.  Expensive sources (engine counters, cache stats, router state)
   are *pulled* by collector callbacks at snapshot time, not pushed per
-  request, which is what keeps enabled-telemetry overhead inside the 2%
-  budget (``BENCH_serve.json`` ``telemetry_overhead``).
+  request; the plane's cost on the chip, with the profiler on and off,
+  is measured in PERF.md.
 - Naming scheme: ``amp_<plane>_<what>_<unit>`` — e.g.
   ``amp_engine_compiles_total``, ``amp_request_latency_seconds``,
   ``amp_se_drift``.  Suffixes follow Prometheus conventions
@@ -74,7 +74,7 @@ class _Child:
     """Label-bound handle (prometheus_client's ``.labels()`` idiom): hot
     paths resolve the label key once and keep the child, turning every
     subsequent bump into a lock + dict update with no per-call label
-    validation (the <=2% telemetry-overhead budget, DESIGN.md §12)."""
+    validation (DESIGN.md §12)."""
 
     __slots__ = ("_metric", "_key")
 
@@ -175,8 +175,7 @@ class Histogram(_Metric):
     def observe_many(self, values: Iterable[float], **labels: str) -> None:
         """Bulk observation under one lock acquisition / label-key build —
         the batched dispatch path records a whole bucket group's
-        latencies and drifts in one call (the <=2% telemetry-overhead
-        budget, DESIGN.md §12)."""
+        latencies and drifts in one call (DESIGN.md §12)."""
         self._observe_key(_label_key(self.labelnames, labels), values)
 
     def _observe_key(self, key: _LabelKey, values: Iterable[float]) -> None:

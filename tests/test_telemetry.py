@@ -23,8 +23,9 @@ from repro.serving.frontend import BackendServer, LocalBackend, TcpBackend
 from repro.telemetry import (DRIFT_ALERT, MetricsRegistry, hist_quantile,
                              merge_snapshots, prometheus_text, se_drift,
                              se_prediction)
+from repro.telemetry import spans as spans_mod
 from repro.telemetry.spans import (chrome_trace_events, expected_spans,
-                                   missing_spans, span, span_names,
+                                   missing_spans, phase, span, span_names,
                                    spans_monotonic, tag_host,
                                    write_trace_jsonl)
 
@@ -177,8 +178,11 @@ def test_merge_snapshots_adds_host_label():
 
 def test_span_vocabulary_helpers():
     assert expected_spans() == ["admit", "batch_wait", "operands",
-                                "compute", "complete"]
-    assert expected_spans(wire=True)[-2:] == ["wire_measure", "complete"]
+                                "compute", "pull", "complete", "results",
+                                "drift"]
+    # coding nests in the request's results span, before the drift tail
+    assert expected_spans(wire=True)[-3:] == ["results", "wire_measure",
+                                              "drift"]
     assert expected_spans(cluster=True)[1] == "route"
     spans = [span(n, i, i + 0.5) for i, n in enumerate(expected_spans())]
     assert missing_spans(spans) == []
@@ -193,6 +197,9 @@ def test_span_vocabulary_helpers():
                             span("c", 6.0, 7.0, host="x")])
     assert tag_host([["a", None, 0.0, 1.0], ["b", "h", 1.0, 2.0]], "z") == \
         [["a", "z", 0.0, 1.0], ["b", "h", 1.0, 2.0]]
+    # a span's counts survive host tagging
+    assert tag_host([["d", None, 0.0, 1.0, {"lookups": 2}]], "z") == \
+        [["d", "z", 0.0, 1.0, {"lookups": 2}]]
 
 
 def test_chrome_trace_export():
@@ -210,6 +217,75 @@ def test_chrome_trace_export():
     assert n == 2
     parsed = [json.loads(l) for l in fp.getvalue().splitlines()]
     assert [e["name"] for e in parsed] == ["admit", "compute"]
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter and
+    exit with its ``perf_counter`` time."""
+
+    def __init__(self):
+        self.log = []
+        rec = self
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                rec.log.append(("enter", self.name, spans_mod.now()))
+
+            def __exit__(self, *exc):
+                rec.log.append(("exit", self.name, spans_mod.now()))
+        self.cls = Ann
+
+    def bounds(self, name):
+        """(enter, exit) times of each annotation ``name``, in order."""
+        ent = [t for k, n, t in self.log if k == "enter" and n == name]
+        ext = [t for k, n, t in self.log if k == "exit" and n == name]
+        return list(zip(ent, ext))
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    rec = _Annotations()
+    monkeypatch.setattr(spans_mod, "TraceAnnotation", rec.cls)
+    return rec
+
+
+def test_phase_span_and_annotation(annotations):
+    with phase("pull") as sp:
+        with phase("inner"):
+            pass
+    assert sp[0] == "pull" and sp[1] is None and sp[3] >= sp[2] > 0.0
+    # the span lies inside its annotation; the child nests in both
+    (pe, px), = annotations.bounds("amp.pull")
+    (ie, ix), = annotations.bounds("amp.inner")
+    assert pe <= sp[2] <= ie <= ix <= sp[3] <= px
+    with phase("off", False) as off:
+        pass
+    assert off is None
+    assert not annotations.bounds("amp.off")
+
+
+def test_chrome_trace_counts_ride_as_args():
+    spans = [span("compute", 2.0, 2.25),
+             ["drift", None, 2.25, 2.5, {"lookups": 3, "misses": 1}]]
+    evs = chrome_trace_events(4, spans)
+    assert "args" not in evs[0]
+    assert evs[1]["args"] == {"lookups": 3, "misses": 1}
+    assert evs[1]["dur"] == pytest.approx(0.25e6)
+
+
+def test_codec_span_counts_roundtrip():
+    spans = [["admit", None, 1.0, 1.5],
+             ["drift", "host0", 2.0, 2.5, {"lookups": 16, "misses": 13}]]
+    _, (req,) = make_reqs(1)
+    req = dataclasses.replace(req)
+    req.spans = [list(s) for s in spans]
+    assert decode_request(encode_request(req)).spans == spans
+    res = dataclasses.replace(_solved_singleton(),
+                              spans=[list(s) for s in spans])
+    assert decode_result(encode_result(res)).spans == spans
 
 
 @settings(max_examples=10, deadline=None)
@@ -294,6 +370,108 @@ def test_batched_path_span_tree(telem_svc):
     assert len(ops) == 1
 
 
+def test_local_admit_span_is_real(telem_svc):
+    """A local request's admit span covers preparing, keying and queueing
+    it: it ends after it starts, and batch_wait starts where it ends."""
+    _, results = telem_svc
+    for r in results:
+        adm, wait = r.spans[0], r.spans[1]
+        assert adm[0] == "admit" and adm[3] > adm[2]
+        assert wait[0] == "batch_wait" and wait[2] == adm[3]
+
+
+def test_complete_covers_results_and_drift():
+    """``complete`` ends after the drift tail, with ``results`` and
+    ``drift`` inside it, and the latency histogram ends where it ends."""
+    svc = SolveService(policy=POL, rate_accounting=False)
+    _, reqs = make_reqs(8, seed=10)
+    results = svc.solve(reqs)
+    want = 0.0
+    for r in results:
+        by = {s[0]: s for s in r.spans}
+        co, rs, dr = by["complete"], by["results"], by["drift"]
+        assert co[2] <= rs[2] <= rs[3] <= dr[2] <= dr[3] <= co[3]
+        # pull ends where compute ends, inside it
+        assert by["compute"][2] <= by["pull"][2] <= by["pull"][3] \
+            == by["compute"][3] <= co[2]
+        want += co[3] - by["admit"][2]
+    by_name = {m["name"]: m for m in svc.metrics()["metrics"]}
+    (lat,) = by_name["amp_request_latency_seconds"]["samples"]
+    assert lat["count"] == len(results)
+    assert lat["sum"] == pytest.approx(want, rel=1e-9)
+
+
+def test_drift_counts_lossless_and_bt():
+    """A batch of lossless and BT requests: the drift span counts one
+    SE-prediction lookup per answer, and no lossless answer misses once
+    its operating point has been seen (lossless schedules repeat). The
+    registry mirrors the running totals."""
+    svc = SolveService(policy=POL, rate_accounting=False)
+    _, warm = make_reqs(2, seed=10, policy="lossless")
+    warm_res = svc.solve(warm)
+    _, lossless = make_reqs(3, seed=20, policy="lossless")
+    _, bt = make_reqs(5, seed=30, policy="bt")
+    results = svc.solve(lossless + bt)
+    drift = {tuple(s[:4]): s[4] for r in results for s in r.spans
+             if s[0] == "drift"}
+    assert len(drift) == 1              # one tail for the one batch
+    (counts,) = drift.values()
+    assert counts["lookups"] == len(results)
+    assert 0 <= counts["misses"] <= len(bt)
+    by_name = {m["name"]: m for m in svc.metrics()["metrics"]}
+    got = {s["labels"]["result"]: s["value"] for s in
+           by_name["amp_se_prediction_lookups_total"]["samples"]}
+    tails = {tuple(s[:4]): s[4] for r in warm_res + results
+             for s in r.spans if s[0] == "drift"}.values()
+    assert got == {"hit": sum(c["lookups"] - c["misses"] for c in tails),
+                   "miss": sum(c["misses"] for c in tails)}
+
+
+def test_operands_and_compute_keep_their_bounds(annotations, monkeypatch):
+    """``operands`` runs from after the engine lookup to the end of the
+    operand build (before the engine call is enqueued); ``compute`` from
+    there to the end of the pull (device results materialized) — the
+    bounds they had before the phases around them were recorded."""
+    from repro.core.engine import AmpEngine
+    from repro.serving import service as service_mod
+
+    calls = {}
+
+    def timed(owner, name):
+        orig = getattr(owner, name)
+
+        def wrapped(*a, **kw):
+            t0 = spans_mod.now()
+            out = orig(*a, **kw)
+            calls.setdefault(name, []).append((t0, spans_mod.now()))
+            return out
+        monkeypatch.setattr(owner, name, wrapped)
+
+    timed(service_mod.SolveService, "_engine")
+    timed(service_mod.SolveService, "_a_batch")
+    timed(service_mod.SolveService, "_y_and_params")
+    timed(AmpEngine, "dispatch_het")
+    timed(AmpEngine, "trace_of")
+    svc = SolveService(policy=POL, rate_accounting=False)
+    _, reqs = make_reqs(8, seed=50)
+    calls.clear()
+    results = svc.solve(reqs)
+    by = {s[0]: s for s in results[0].spans}
+    op, cp, pl = by["operands"], by["compute"], by["pull"]
+    assert calls["_engine"][-1][1] <= op[2] <= calls["_a_batch"][0][0]
+    assert calls["_y_and_params"][0][1] <= op[3] \
+        <= calls["dispatch_het"][0][0]
+    assert cp[2] == op[3]
+    assert calls["trace_of"][0][1] <= cp[3] == pl[3]
+    assert calls["dispatch_het"][0][1] <= pl[2] <= calls["trace_of"][0][0]
+    # the annotations carry the same phases on the profiler's clock
+    for name in ("admit", "operands", "a_stack", "params", "dispatch",
+                 "pull", "complete", "results", "drift"):
+        assert annotations.bounds("amp." + name), name
+    (oe, ox), = annotations.bounds("amp.operands")
+    assert oe <= op[2] <= op[3] <= ox
+
+
 def test_batched_path_drift_clean(telem_svc):
     """Clean solves (true SNR declared) have well-defined drift and a
     typical value well under the alert line. Per-request bounds are NOT
@@ -357,7 +535,8 @@ def test_measure_wire_span_tree():
         assert spans_monotonic(r.spans), r.spans
 
 
-def test_telemetry_off_is_clean():
+def test_telemetry_off_is_clean(annotations):
+    """No spans, no drift, no metrics, and no profiler annotation."""
     svc = SolveService(policy=POL, rate_accounting=False, telemetry=False)
     _, reqs = make_reqs(2, seed=60)
     results = svc.solve(reqs)
@@ -365,6 +544,7 @@ def test_telemetry_off_is_clean():
         assert r.spans is None and r.se_drift is None
     assert svc.metrics() == {"metrics": []}
     assert svc.metrics_text() == ""
+    assert annotations.log == []
 
 
 # ---------------------------------------------------------------------------
