@@ -59,8 +59,9 @@ from ..core.rate_alloc import (dp_allocate, dp_allocate_col,
                                erasure_rate_factors, stack_schedules)
 from ..core.rate_distortion import RDModel
 from ..core.state_evolution import CSProblem
-from ..telemetry import (DRIFT_ALERT, DRIFT_BUCKETS, MetricsRegistry,
-                         prometheus_text, se_drift, se_drift_batch)
+from ..telemetry import (DRIFT_ALERT, DRIFT_BUCKETS, DRIFT_COUNTS,
+                         MetricsRegistry, prometheus_text, se_drift,
+                         se_drift_batch)
 from ..telemetry.spans import phase as _phase
 from .batcher import Batcher
 from .buckets import (BucketKey, BucketPolicy, batch_width_ladder,
@@ -340,8 +341,10 @@ class SolveService:
         # span and profiler annotation.
         self.telemetry = telemetry
         self._registry = None
-        # running totals of the drift tails' SE-prediction lookups
+        # running totals of the drift tails' SE-prediction lookups and
+        # of the MMSE evaluations their SE recursions made
         self._se_lookups = {"hit": 0, "miss": 0}
+        self._se_mmse = {"table": 0, "quadrature": 0}
         # per-layout label-bound metric children (metrics._Child): the
         # dispatch tails bump these without re-resolving label keys
         self._children: dict = {}
@@ -912,16 +915,21 @@ class SolveService:
         return out
 
     def _tally(self, counts: dict) -> None:
-        """Add one tail's SE-prediction lookups to the running totals."""
+        """Add one tail's SE-prediction lookups and MMSE evaluations to
+        the running totals."""
         tot = self._se_lookups
         tot["hit"] += counts["lookups"] - counts["misses"]
         tot["miss"] += counts["misses"]
+        ev = self._se_mmse
+        ev["table"] += counts["table"]
+        ev["quadrature"] += counts["quadrature"]
 
     def _drift_tail(self, key: BucketKey, reqs: list, results: list,
                     trace, ch: dict) -> dict:
         """SE drift for a whole bucket group (DESIGN.md §12), written
         onto the already-built results; returns the group's
-        SE-prediction lookups and misses. A group uniform in operating
+        SE-prediction lookups and misses and its MMSE evaluations
+        (``DRIFT_COUNTS``). A group uniform in operating
         point pays one vectorized masked log-ratio pass (one memoized
         prediction lookup per distinct realized schedule, see
         ``se_drift_batch``); mixed groups fall back to the per-request
@@ -933,7 +941,7 @@ class SolveService:
         v0 = _DRIFT_ATTRS(r0)
         p0 = r0.prior
         uniform = all(r.prior is p0 and _DRIFT_ATTRS(r) == v0 for r in reqs)
-        counts = {"lookups": 0, "misses": 0}
+        counts = dict.fromkeys(DRIFT_COUNTS, 0)
         dr: list = []
         dr_add = dr.append
         isfin = math.isfinite
@@ -1134,7 +1142,7 @@ class SolveService:
             with _phase("results") as rs:
                 res = self._result_one(key, r, trace, i, batch_size)
             with _phase("drift") as dr:
-                counts = {"lookups": 0, "misses": 0}
+                counts = dict.fromkeys(DRIFT_COUNTS, 0)
                 try:
                     d, _ = se_drift(
                         r.problem(), res.sigma2_hat, res.extra_var,
@@ -1348,6 +1356,12 @@ class SolveService:
                          ("result",))
         for result, v in self._se_lookups.items():
             lk.set_total(v, result=result)
+        ev = reg.counter("amp_se_mmse_evaluations_total",
+                         "MMSE evaluations of the drift tails' SE "
+                         "recursions, by path (the per-prior table, or "
+                         "the quadrature outside its domain)", ("path",))
+        for path, v in self._se_mmse.items():
+            ev.set_total(v, path=path)
         comp = reg.counter("amp_engine_compiles_total",
                            "XLA compiles per bucket engine", ("bucket",))
         disp = reg.counter("amp_engine_dispatches_total",

@@ -6,7 +6,8 @@ structures that ride the serving plane's no-pickle codec across host
 boundaries and render as Prometheus text or Chrome trace-event JSONL;
 service phases also annotate jax's profiler trace (``spans.phase``).
 """
-from .drift import DRIFT_ALERT, se_drift, se_drift_batch, se_prediction
+from .drift import (DRIFT_ALERT, DRIFT_COUNTS, se_drift, se_drift_batch,
+                    se_prediction)
 from .metrics import (DRIFT_BUCKETS, LATENCY_BUCKETS, Counter, Gauge,
                       Histogram, MetricsRegistry, hist_quantile,
                       merge_snapshots, prometheus_text)
@@ -22,4 +23,5 @@ __all__ = [
     "missing_spans", "expected_spans", "tag_host", "chrome_trace_events",
     "write_trace_jsonl",
     "se_drift", "se_drift_batch", "se_prediction", "DRIFT_ALERT",
+    "DRIFT_COUNTS",
 ]

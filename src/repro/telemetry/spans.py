@@ -22,7 +22,8 @@ above):
       results    slice-out and rate accounting of each request
         wire_measure  rANS coding + wire-model accounting (measure_wire only)
       drift      SE-drift tail; counts ``{"lookups", "misses"}`` of the
-                 SE-prediction memo (telemetry/drift.py)
+                 SE-prediction memo and ``{"table", "quadrature"}`` of
+                 the SE recursion's MMSE evaluations (telemetry/drift.py)
 
 ``phase`` records one service phase: the span above, and the same
 interval as a ``jax.profiler.TraceAnnotation`` named ``amp.<phase>`` in

@@ -502,6 +502,13 @@ def test_service_metrics_surface(telem_svc):
                for s in by_name["amp_engine_compiles_total"]["samples"])
     assert comp == svc.compile_count() > 0
     assert "amp_operand_cache_hits_total" in by_name
+    # the drift tails' MMSE evaluations, by path, total their spans' counts
+    evals = {s["labels"]["path"]: s["value"] for s in
+             by_name["amp_se_mmse_evaluations_total"]["samples"]}
+    tails = {tuple(s[:4]): s[4] for r in results for s in r.spans
+             if s[0] == "drift"}.values()
+    assert evals == {path: sum(c[path] for c in tails)
+                     for path in ("table", "quadrature")}
     text = svc.metrics_text()
     assert "# TYPE amp_request_latency_seconds histogram" in text
     assert "amp_se_drift_bucket" in text
