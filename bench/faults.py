@@ -4,7 +4,9 @@ Each fault is ``plant(patch)``, where ``patch(obj, name, value)`` sets an
 attribute (``setattr``, or pytest's ``monkeypatch.setattr``, which undoes
 it). The CPU tests plant them at a small size and see ``correct`` come out
 false; ``bench/control.py --fault`` plants one at a cell's own size on the
-chip and prints what each compared number reads under it.
+chip and prints what each compared number reads under it. A fault of
+``MESH_ONLY`` breaks code that runs only on a mesh of several chips, so
+a one-chip cell cannot have it.
 """
 from __future__ import annotations
 
@@ -68,6 +70,28 @@ def exchange_left_out(patch):
     patch(eng.EcsqTransport, "fuse", patched)
 
 
+def mesh_exchange_left_out(patch):
+    """Fusion on the mesh without the exchange between chips: each device
+    stands its own processors' sum, times the device count, in for the
+    psum over the mesh, exact (``PsumFusion``) or int8/int4
+    (``CompressedPsumTransport``)."""
+    from jax import lax
+    eng = _engine()
+
+    def psum_fuse(self, f_p, delta, drop):
+        f_loc, extra_loc, q = self.local.fuse(f_p, delta)
+        n_dev = lax.axis_size(self.axis)
+        return n_dev * f_loc, n_dev * extra_loc, q
+
+    orig = eng.CompressedPsumTransport.fuse
+
+    def compressed_fuse(self, f_p, delta, drop):
+        _, noise, q = orig(self, f_p, delta, drop)
+        return lax.axis_size(self.axis) * f_p.sum(axis=0), noise, q
+    patch(eng.PsumFusion, "fuse", psum_fuse)
+    patch(eng.CompressedPsumTransport, "fuse", compressed_fuse)
+
+
 def bt_bins_coarse(patch):
     """Both BT controllers (row and column) quantize with bins
     ``BT_BIN_FACTOR`` times wider than the ones they chose, three bits
@@ -86,4 +110,6 @@ FAULTS = {"state_unchanged": state_unchanged,
           "half_batch_dropped": half_batch_dropped,
           "exchange_left_out": exchange_left_out,
           "answer_altered": answer_altered,
-          "bt_bins_coarse": bt_bins_coarse}
+          "bt_bins_coarse": bt_bins_coarse,
+          "mesh_exchange_left_out": mesh_exchange_left_out}
+MESH_ONLY = ("mesh_exchange_left_out",)

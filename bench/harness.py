@@ -9,9 +9,16 @@ cell, mix or metric is a new file and a new entry; nothing here names one.
 The service under test is ``repro.serving.SolveService``, driven through
 its public calls: ``submit`` and ``poll`` (what ``stream`` calls per
 request).
+
+A cell on several chips runs the service on a 1-D mesh of the first
+``chips`` devices. Its A is drawn split by rows over that mesh and never
+held whole on one device or on the host (``problems.draw_sensors``), and
+the reference runs the lanes of each A over the same mesh
+(``reference.solve_shared``).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import gc
 import importlib.util
@@ -44,6 +51,7 @@ KERNELS = {"z": r"^%amp_local_pallas_grid[.0-9]* = \(",
 SOLVE_MODULE = "solve_batch"      # the jitted het program of a bucket
 TRACE_SECONDS = 5.0               # how much of a --trace 1 window is traced
 SLICE_S = 5.0                     # stretch of the answers-per-slice report
+MESH_AXIS = "data"                # the service's default ``mesh_axis``
 
 
 class NoChip(RuntimeError):
@@ -102,10 +110,20 @@ def load_reader(root: str, name: str):
 
 # -- the service and its requests -------------------------------------------
 
-def build_service(cfg: dict):
+def make_mesh(devs: list, chips: int):
+    """The 1-D mesh of a cell on several chips, or None on one chip."""
+    if chips <= 1:
+        return None
+    from jax.sharding import Mesh
+    return Mesh(np.asarray(devs[:chips]), (MESH_AXIS,))
+
+
+def build_service(cfg: dict, mesh=None):
     from repro.serving import BucketPolicy, SolveService
     sv = dict(cfg["service"])
     policy = BucketPolicy(**sv.pop("bucket_policy"))
+    if mesh is not None:
+        sv["mesh"] = mesh
     return SolveService(policy=policy, col_inner=cfg["col_inner"], **sv)
 
 
@@ -122,15 +140,20 @@ class Requests:
                        for e in data["eps"]]
         self.iters = problems.sensor_iters(cfg)
 
-    def make(self, s: int, policy: str, k: int):
+    def make(self, s: int, key: str, k: int):
+        """The request of plan entry (sensor, mix key, signal); a device
+        A (a mesh cell's) goes into every request of its sensor as the
+        one array object it is."""
         c = self.cfg
+        policy, transport = traffic.split_key(key, c["transport"])
         kw = {}
         if policy == "dp":
             kw["dp_total_bits"] = c["dp_bits_per_iter"] * self.iters[s]
         return self._req(
             y=self.data["y"][s, k], a=self.data["a"][s], prior=self.priors[s],
             snr_db=c["snr_db"], n_proc=c["n_proc"], n_iter=self.iters[s],
-            policy=policy, bt_c_ratio=c["bt"]["c_ratio"],
+            policy=policy, transport=transport,
+            bt_c_ratio=c["bt"]["c_ratio"],
             bt_r_max=c["bt"]["r_max"], a_id=f"sensor{s}", **kw)
 
 
@@ -140,7 +163,7 @@ class Log:
     """What a loop saw: per request its plan entry and times."""
 
     def __init__(self):
-        self.plan: dict = {}        # request id -> (sensor, policy, signal)
+        self.plan: dict = {}        # request id -> (sensor, mix key, signal)
         self.done: dict = {}        # request id -> host time its result came
         self.results: dict = {}     # request id -> SolveResult
         self.admit: list = []       # (seconds in submit, dispatched a batch)
@@ -230,9 +253,9 @@ class Tracer:
 # -- comparison with the reference -----------------------------------------
 
 def draw_sample(log: Log, ids: list, mix: dict, seed: int) -> list:
-    """Request ids to compare: per policy ``mix['compare'][policy]`` of
+    """Request ids to compare: per mix key ``mix['compare'][key]`` of
     the answers due in the window, drawn from the seed, the longest
-    request of each policy always among them."""
+    request of each key always among them."""
     rng = np.random.default_rng([int(seed) % (1 << 64), 2])
     out = []
     for pol, count in sorted(mix["compare"].items()):
@@ -249,10 +272,14 @@ def draw_sample(log: Log, ids: list, mix: dict, seed: int) -> list:
 
 def reference_answers(cfg: dict, data: dict, cases: list,
                       precision: str, lanes: int = 8) -> dict:
-    """Reference x for each (key, sensor, signal, T) case, computed on the
-    default device in groups of one T, ``lanes`` problems per call."""
+    """Reference x for each (key, sensor, signal, T) case, ``lanes``
+    problems per call. A host A (one chip): on the default device, in
+    groups of one T. Device arrays of A (a mesh): each sensor's lanes over
+    its own A where it lies, in groups of one sensor and T."""
     import jax
     import jax.numpy as jnp
+    if not isinstance(data["a"], np.ndarray):
+        return _shared_answers(cfg, data, cases, precision, lanes)
     a_dev = jnp.asarray(data["a"])
     eps = np.asarray(data["eps"], np.float32)
     out = {}
@@ -275,22 +302,45 @@ def reference_answers(cfg: dict, data: dict, cases: list,
     return out
 
 
+def _shared_answers(cfg: dict, data: dict, cases: list, precision: str,
+                    lanes: int) -> dict:
+    import jax
+    out = {}
+    groups: dict = {}
+    for c in cases:
+        groups.setdefault((c[1], c[3]), []).append(c)
+    for (s, t), group in sorted(groups.items()):
+        for i in range(0, len(group), lanes):
+            chunk = group[i:i + lanes]
+            pad = chunk + [chunk[-1]] * (lanes - len(chunk))
+            ys = np.stack([data["y"][s, c[2]] for c in pad])
+            x = reference.solve_shared(data["a"][s], ys, data["eps"][s], t,
+                                       mu=cfg["mu_s"], sigma=cfg["sigma_s"],
+                                       precision=precision)
+            x = np.asarray(jax.device_get(x))
+            for j, c in enumerate(chunk):
+                out[c[0]] = x[j]
+    return out
+
+
 def compare(cfg: dict, data: dict, log: Log, sample: list,
             answers: dict, x_ref: dict) -> dict:
-    """Per policy, the worst reading over the sampled answers, and how
-    many answers passed their limit. Lossless answers: mean squared
-    difference to the reference over the reference's own MSE. Lossy
-    answers: SDR loss against the reference, in dB."""
+    """Per kind of answer, the worst reading over the sampled answers,
+    and how many answers passed their limit. Exact answers (lossless
+    policy, ECSQ transport: exact fusion): mean squared difference to
+    the reference over the reference's own MSE. Lossy answers (a rate
+    policy, or a quantizing transport such as int8 psum): SDR loss
+    against the reference, in dB."""
     lim = cfg["limits"]
     worst = {"lossless_msd_rel": None, "lossy_loss_db": None}
     bad = 0
     for rid in sample:
-        s, pol, k = log.plan[rid]
+        s, key, k = log.plan[rid]
         s0 = data["s0"][s, k].astype(np.float64)
         xr = x_ref[rid].astype(np.float64)
         x = np.asarray(answers[rid], np.float64)
         mse_ref = max(float(np.mean((xr - s0) ** 2)), 1e-30)
-        if pol == "lossless":
+        if traffic.split_key(key, cfg["transport"]) == ("lossless", "ecsq"):
             name = "lossless_msd_rel"
             v = float(np.mean((x - xr) ** 2)) / mse_ref
         else:
@@ -330,26 +380,37 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     say(f"{workload} seed {seed} on {len(devs)} x {dev.device_kind}, "
         f"backend up at {now() - t_start:.2f}s")
 
-    data = problems.draw_sensors(cfg, mix["signals_per_sensor"], seed)
+    mesh = make_mesh(devs, cell["chips"])
+    data = problems.draw_sensors(cfg, mix["signals_per_sensor"], seed,
+                                 mesh=mesh)
     reqs = Requests(cfg, data)
-    svc = build_service(cfg)
+    svc = build_service(cfg, mesh)
     t_of = sorted(set(reqs.iters))
     say(f"data drawn at {now() - t_start:.2f}s")
 
     # warm-up: the cell's own traffic from another stream of the seed,
     # until every sensor's operands are resident and every horizon T has
-    # run two batches (its programs compiled or loaded from the cache)
+    # run two batches (its programs compiled or loaded from the cache).
+    # On a mesh, where a large request runs alone and its program follows
+    # its policy and transport, also until every (T, mix key) has two
+    # answers.
     warm = Log()
     batches: dict = {}
+    answered: collections.Counter = collections.Counter()
+    keys_due = ({(t, key) for t in t_of for key in mix["policies"]}
+                if mesh is not None else set())
 
     def warmed(lg, _t):
         for r in lg.results.values():
             batches.setdefault(r.deltas.shape[0], set()).add(
                 (r.bucket, lg.done[r.request_id]))
+            s, key, _ = lg.plan[r.request_id]
+            answered[(reqs.iters[s], key)] += 1
         lg.results.clear()
         seen = {lg.plan[i][0] for i in lg.plan}
         return (len(seen) == cfg["sensors"]
-                and all(len(batches.get(t, ())) >= 2 for t in t_of))
+                and all(len(batches.get(t, ())) >= 2 for t in t_of)
+                and all(answered[tk] >= 2 for tk in keys_due))
 
     warm_plan = traffic.Plan(mix, cfg["sensors"], seed, stream=1)
     closed_loop(svc, warm_plan, reqs, warm, warmed)
@@ -393,6 +454,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     missing = [i for i in due if i not in log.results]
     ids = [i for i in due if i in log.results]
     layouts = {log.results[i].bucket.layout for i in ids}
+    placements = [log.results[i].bucket.placement for i in ids]
     slices = np.bincount(np.asarray(
         [int((log.done[i] - t0) // SLICE_S) for i in ids], int), minlength=1)
     say(f"answers per {SLICE_S:g} s of the window: {slices.tolist()}")
@@ -444,6 +506,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         "compiles_in_window": [compiles, 0],
         "answers_missing": [len(missing), 0],
         "wrong_layout": [len(layouts - {cfg["layout"]}), 0],
+        "wrong_placement": [
+            sum(p != cfg["placement"] for p in placements)
+            if "placement" in cfg else None, 0],
     }
     checks = {k: v for k, v in checks.items() if v[0] is not None}
     failed = cmp["bad"] + len(missing)

@@ -5,8 +5,13 @@
     rho = E[s0^2] / kappa,  kappa = M / N.
 
 One jitted call on the device draws every sensor's A and a pool of
-signals per sensor; the arrays then come to the host once, since the
-service takes host arrays.
+signals per sensor; on one chip the arrays then come to the host once,
+since the service takes host arrays. On a mesh of several chips each A
+stays on the devices, split by rows (the M axis) over the mesh, and only
+the signals come to the host: with ``jax_threefry_partitionable`` (the
+default) the sharded draw gives every A and s0 bit for bit as on one
+device. y is the same product over fewer rows per device, so it can
+differ from the one-device draw by float32 rounding of the sum.
 """
 from __future__ import annotations
 
@@ -44,8 +49,7 @@ def key_for(seed: int, stream: int):
     return jax.random.fold_in(k, stream)
 
 
-@functools.lru_cache(maxsize=None)
-def _draw_fn(s: int, k: int, m: int, n: int):
+def _draw_body(s: int, k: int, m: int, n: int):
     import jax
     import jax.numpy as jnp
 
@@ -62,22 +66,52 @@ def _draw_fn(s: int, k: int, m: int, n: int):
                        precision=jax.lax.Precision.HIGHEST) + e
         return a, s0, y
 
-    return jax.jit(draw)
+    return draw
 
 
-def draw_sensors(cfg: dict, pool: int, seed: int, stream: int = 0) -> dict:
-    """Every sensor's A (S, M, N) and ``pool`` signals per sensor: s0
-    (S, pool, N) and y (S, pool, M), as host float32 arrays."""
+@functools.lru_cache(maxsize=None)
+def _draw_fn(s: int, k: int, m: int, n: int):
+    import jax
+    return jax.jit(_draw_body(s, k, m, n))
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_draw_fn(s: int, k: int, m: int, n: int, mesh):
+    """The same draw with each sensor's A (M, N) its own array, its rows
+    split over the mesh's one axis; s0 and y replicated."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+    draw = _draw_body(s, k, m, n)
+
+    def draw_split(*args):
+        a, s0, y = draw(*args)
+        return tuple(a[i] for i in range(s)), s0, y
+
+    rows = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0], None))
+    whole = NamedSharding(mesh, PartitionSpec())
+    return jax.jit(draw_split, out_shardings=((rows,) * s, whole, whole))
+
+
+def draw_sensors(cfg: dict, pool: int, seed: int, stream: int = 0,
+                 mesh=None) -> dict:
+    """Every sensor's A and ``pool`` signals per sensor: s0 (S, pool, N)
+    and y (S, pool, M), as host float32 arrays. Without ``mesh`` A is a
+    host array (S, M, N); with a 1-D mesh, a tuple of S device arrays
+    (M, N), each split by rows over it."""
     import jax
     import jax.numpy as jnp
 
     eps = sensor_eps(cfg)
     s, m, n = cfg["sensors"], cfg["m"], cfg["n"]
-    fn = _draw_fn(s, pool, m, n)
     sig_e = np.sqrt([noise_var(cfg, e) for e in eps]).astype(np.float32)
-    out = fn(key_for(seed, stream), jnp.asarray(eps, jnp.float32),
-             jnp.float32(cfg["mu_s"]), jnp.float32(cfg["sigma_s"]),
-             jnp.asarray(sig_e))
+    args = (key_for(seed, stream), jnp.asarray(eps, jnp.float32),
+            jnp.float32(cfg["mu_s"]), jnp.float32(cfg["sigma_s"]),
+            jnp.asarray(sig_e))
+    if mesh is not None:
+        a, s0, y = _sharded_draw_fn(s, pool, m, n, mesh)(*args)
+        s0, y = jax.device_get((s0, y))
+        return {"a": a, "s0": s0, "y": y, "eps": eps}
+    out = _draw_fn(s, pool, m, n)(*args)
     a, s0, y = jax.device_get(out)
     del out
     return {"a": a, "s0": s0, "y": y, "eps": eps}

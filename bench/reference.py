@@ -9,6 +9,11 @@ It imports nothing of the system under test. Row MP-AMP with lossless
 fusion, and column MP-AMP with one inner iteration per round, compute
 exactly this recursion, so one reference serves both layouts.
 
+``solve`` runs a batch of problems, each with its own A. ``solve_shared``
+runs the lanes of one A, the signals of one sensor: each iteration is
+then two matrix products, and A may be split by rows over a mesh, jit's
+partitioner putting in whatever exchange the products need.
+
 ``precision`` is how the two matrix-vector products are computed:
 ``"highest"`` is float32 (``Precision.HIGHEST``); ``"bf16x3"`` is the
 three-pass bfloat16 product, ``Precision.HIGH`` on a TPU. The latter is
@@ -38,7 +43,8 @@ def _split_bf16(v):
 
 
 def _matvec(a, v, spec: str, precision: str):
-    """einsum(spec, a, v) in float32 or in three bfloat16 passes."""
+    """einsum(spec, a, v) in float32 or in three bfloat16 passes; the
+    shared-A recursion passes matrices for v."""
     import jax
     import jax.numpy as jnp
     if precision == "highest":
@@ -100,3 +106,41 @@ def solve(a, y, eps, n_iter: int, mu: float = 0.0, sigma: float = 1.0,
     return fn(jnp.asarray(a, jnp.float32), jnp.asarray(y, jnp.float32),
               jnp.asarray(eps, jnp.float32), jnp.float32(mu),
               jnp.float32(sigma * sigma))
+
+
+@functools.lru_cache(maxsize=None)
+def _shared_solver(n_iter: int, precision: str):
+    import jax
+    import jax.numpy as jnp
+
+    def lanes(a, y, eps, mu, var_s):
+        m, n = a.shape
+        el = y.shape[0]
+
+        def step(carry, _):
+            x, z, ons = carry
+            z = y - _matvec(a, x, "mn,ln->lm", precision) + ons[:, None] * z
+            s2 = jnp.maximum(jnp.sum(z * z, axis=1) / m, 1e-30)
+            f = x + _matvec(a, z, "mn,lm->ln", precision)
+            x, d = eta_bg(f, s2[:, None], eps, mu, var_s)
+            return (x, z, jnp.sum(d, axis=1) / m), None
+
+        init = (jnp.zeros((el, n), jnp.float32),
+                jnp.zeros((el, m), jnp.float32), jnp.zeros(el, jnp.float32))
+        (x, _, _), _ = jax.lax.scan(step, init, None, length=n_iter)
+        return x
+
+    return jax.jit(lanes)
+
+
+def solve_shared(a, y, eps, n_iter: int, mu: float = 0.0,
+                 sigma: float = 1.0, precision: str = "highest"):
+    """Centralized AMP over the lanes of one A: a (M, N), a device array
+    that may be split by rows over a mesh, y (L, M), eps the prior's
+    sparsity; returns x (L, N) after ``n_iter`` iterations, the same
+    recursion as ``solve``."""
+    import jax.numpy as jnp
+    assert precision in PRECISIONS, precision
+    fn = _shared_solver(int(n_iter), precision)
+    return fn(a, jnp.asarray(y, jnp.float32), jnp.float32(eps),
+              jnp.float32(mu), jnp.float32(sigma * sigma))

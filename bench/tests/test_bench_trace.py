@@ -4,6 +4,7 @@ gaps and the per-layer metrics, on a small trace recorded on one v5e
 P=4, and 13 ms of the row cell with its ops shorter than 2 us left out)."""
 import json
 import os
+import re
 import types
 
 import numpy as np
@@ -143,3 +144,28 @@ def test_no_trace_or_no_device_reads_nothing():
     ctx["trace"] = trace_reduce.reduce(host_only, harness.KERNELS)
     assert layer.idle_share(ctx) is None
     assert layer.lc_roofline(ctx) is None
+
+
+@pytest.mark.parametrize("key", ["col", "row"])
+def test_every_device_of_several_has_to_hold_its_launches(key):
+    # the recorded stretch as if two devices ran it; then the second one
+    # loses its kernel events, while the sum over both still suffices
+    ev = EVENTS[key]
+    dev = {e["plane"] for e in ev if e["line"] == "XLA Ops"}.pop()
+    twin = [dict(e, plane=dev + "#2") for e in ev if e["plane"] == dev]
+    ctx = _ctx(key)
+    ctx["trace"] = dict(trace_reduce.reduce(ev + twin, harness.KERNELS),
+                        host_span=(9.5, 11.5))
+    counts = ctx["trace"]["plane_counts"]
+    assert {k: sorted(v) for k, v in counts.items()} == {
+        k: sorted([dev, dev + "#2"]) for k in harness.KERNELS}
+    assert layer.trace_complete(ctx)
+    assert layer.idle_share(ctx) is not None
+    lost = [e for e in twin if not any(
+        re.search(p, e["name"]) for p in harness.KERNELS.values())]
+    ctx["trace"] = dict(trace_reduce.reduce(ev + lost, harness.KERNELS),
+                        host_span=(9.5, 11.5))
+    label = layer.LC[SHAPE[key][0]][0]
+    assert ctx["trace"]["kernels"][label]["count"] >= KEPT[key]
+    assert not layer.trace_complete(ctx)
+    assert layer.idle_share(ctx) is None

@@ -80,3 +80,30 @@ def test_committed_mixes_load():
         plan = traffic.Plan(mix, 32, BIG, 0).take(40)
         assert all(p[1] in mix["policies"] for p in plan)
         json.dumps(plan)
+
+
+def _load(tmp_path, mix):
+    os.makedirs(tmp_path / "bench" / "traffic", exist_ok=True)
+    json.dump(mix, open(tmp_path / "bench" / "traffic" / "m.json", "w"))
+    return traffic.load(str(tmp_path), "m")
+
+
+def test_a_key_may_name_its_transport(tmp_path):
+    keys = {"bt": 2, "lossless": 1, "lossless/block8": 1}
+    mix = _load(tmp_path, _mix(policies=keys, compare=keys))
+    plan = traffic.Plan(mix, 4, BIG, 0).take(400)
+    assert collections.Counter(p[1] for p in plan) == {
+        "bt": 200, "lossless": 100, "lossless/block8": 100}
+    assert traffic.split_key("lossless/block8", "ecsq") == ("lossless",
+                                                            "block8")
+    assert traffic.split_key("bt", "ecsq") == ("bt", "ecsq")
+
+
+@pytest.mark.parametrize("key", ["lossless/int2", "lossless/", "fixed",
+                                 "sgd/block8", "lossless/block8/ecsq"])
+@pytest.mark.parametrize("where", ["policies", "compare"])
+def test_an_unknown_policy_or_transport_is_refused(tmp_path, key, where):
+    mix = _mix()
+    mix[where] = {**mix[where], key: 1}
+    with pytest.raises(ValueError):
+        _load(tmp_path, mix)

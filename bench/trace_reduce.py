@@ -85,8 +85,11 @@ def reduce(events: list, kernels: dict | None = None) -> dict:
     per module and per kernel (``kernels`` maps a label to a regular
     expression its op events match), and the idle time by what the host
     was doing (the innermost ``bench.*`` span over each gap). A kernel's
-    ``count`` is its events in the window, ``launches`` the same with a
-    launch cut by the window's edge counted by the share inside it."""
+    ``count`` is its events in the window, summed over the devices,
+    ``launches`` the same with a launch cut by the window's edge counted
+    by the share inside it. A trace of several devices also gives each
+    kernel's count per device (``plane_counts``: label -> plane ->
+    count), since a sum would hide a device that lost its events."""
     win = [e for e in events if e["name"] == WINDOW]
     if not win:
         raise ValueError(f"trace has no {WINDOW!r} span")
@@ -96,6 +99,7 @@ def reduce(events: list, kernels: dict | None = None) -> dict:
     busy_by_plane, ops, modules = {}, {}, {}
     kern = {k: {"count": 0, "launches": 0.0, "seconds": 0.0}
             for k in (kernels or {})}
+    per_plane = {k: dict.fromkeys(planes, 0) for k in kern}
     for pl in planes:
         pe = [e for e in events if e["plane"] == pl]
         op_ev = [e for e in pe if e["line"] == OPS_LINE]
@@ -117,6 +121,7 @@ def reduce(events: list, kernels: dict | None = None) -> dict:
                 for label, pat in (kernels or {}).items():
                     if re.search(pat, e["name"]):
                         kern[label]["count"] += 1
+                        per_plane[label][pl] += 1
                         kern[label]["launches"] += (
                             (b - a) / e["dur"] if e["dur"] > 0 else 1.0)
                         kern[label]["seconds"] += s
@@ -140,7 +145,7 @@ def reduce(events: list, kernels: dict | None = None) -> dict:
     top = lambda d: sorted(([k, v[1] if isinstance(v, list) else v]
                             for k, v in d.items()),
                            key=lambda kv: -kv[1])
-    return {
+    out = {
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy_s,
         "devices": len(planes),
@@ -151,6 +156,9 @@ def reduce(events: list, kernels: dict | None = None) -> dict:
         "breakdown": {"device_ops": top(ops)[:10],
                       "idle_gaps": top(idle)[:10]},
     }
+    if len(planes) > 1:
+        out["plane_counts"] = per_plane
+    return out
 
 
 def _label(host: list, a: float, b: float) -> str:

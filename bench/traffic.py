@@ -9,14 +9,16 @@ A mix is a JSON file ``bench/traffic/<name>.json``. Its keys:
   shuffled from the seed, then a fixed deck of draws per ``deck`` requests);
 * ``policies``: counts per deck of policies, e.g. ``{"bt": 4,
   "lossless": 1}``; each deck is shuffled, so every seed sends the same
-  mix in another order;
+  mix in another order. A key is a policy, sent over the configuration's
+  transport, or ``"<policy>/<transport>"``, e.g. ``"lossless/block8"``:
+  exact fusion in the service, int8 on the wire;
 * ``signals_per_sensor``: size of the pool of signals drawn per sensor.
   Each sensor takes its signals in turn, the warm-up (stream 1) from the
   pool's second half, so a signal comes back only after the whole pool:
   make the pool larger than a window's requests per sensor, since a
   repeated signal repeats its answer and whatever the service caches
   for it (the SE-drift prediction of its realized BT schedule);
-* ``compare``: how many answers of each policy are compared with the
+* ``compare``: how many answers of each key are compared with the
   reference after the window, drawn from the seed.
 
 A new mix is a new file: nothing here names a mix.
@@ -30,6 +32,7 @@ import numpy as np
 
 KINDS = ("closed",)
 POLICIES = ("lossless", "bt", "dp")
+TRANSPORTS = ("ecsq", "block8", "block4")
 DECK = 64   # draws per popularity deck
 
 
@@ -40,9 +43,23 @@ def load(root: str, name: str) -> dict:
         mix = json.load(fh)
     if mix.get("kind") not in KINDS:
         raise ValueError(f"{path}: kind must be one of {KINDS}")
-    if not mix.get("policies") or set(mix["policies"]) - set(POLICIES):
+    if not mix.get("policies"):
         raise ValueError(f"{path}: policies must be counts of {POLICIES}")
+    for key in (*mix["policies"], *mix.get("compare", {})):
+        policy, sep, transport = key.partition("/")
+        if policy not in POLICIES or (sep and transport not in TRANSPORTS):
+            raise ValueError(f"{path}: {key!r} is not a policy of "
+                             f"{POLICIES}, alone or as '<policy>/"
+                             f"<transport>' with a transport of "
+                             f"{TRANSPORTS}")
     return mix
+
+
+def split_key(key: str, transport: str) -> tuple:
+    """(policy, transport) of a mix key; a bare policy goes over
+    ``transport``, the configuration's."""
+    policy, _, own = key.partition("/")
+    return policy, own or transport
 
 
 class Plan:
