@@ -404,18 +404,19 @@ def kernel_phase(checks: Checks, seed: int, interpret: bool = False,
 
     k = jax.random.split(jax.random.PRNGKey(seed), 8)
     p, mp, n = row["p"], row["m"] // row["p"], row["n"]
-    a, y = ops.pad_row_shards(
-        jax.random.normal(k[0], (p, mp, n)) / np.sqrt(row["m"]),
-        jax.random.normal(k[1], (p, mp)))
-    x = jnp.pad(jax.random.normal(k[2], (n,)) * 0.3, (0, a.shape[2] - n))
-    z = jax.random.normal(k[3], y.shape) * 0.1
+    a0 = jax.random.normal(k[0], (p, mp, n)) / np.sqrt(row["m"])
+    y0 = jax.random.normal(k[1], (p, mp))
+    x0 = jax.random.normal(k[2], (n,)) * 0.3
     for dt in (jnp.float32, jnp.bfloat16):
-        ad = a.astype(dt)
+        # the tiles, and so N's padding, depend on the dtype A streams in
+        ad, y = ops.pad_row_shards(a0.astype(dt), y0)
+        x = jnp.pad(x0, (0, ad.shape[2] - n))
+        z = jax.random.normal(k[3], y.shape) * 0.1
         got = jax.jit(lambda *v: ops.amp_local_grid(
             *v, 0.4, p, use_pallas=True, interpret=interpret))(ad, x, y, z)
         want = jax.jit(lambda *v: ref.amp_local_ref_grid(*v, 0.4, p))(
             ad, x, y, z)
-        report(f"row LC {tuple(a.shape)} A {jnp.dtype(dt).name}", got, want)
+        report(f"row LC {tuple(ad.shape)} A {jnp.dtype(dt).name}", got, want)
 
     p, m, np_ = col["p"], col["m"], col["n"] // col["p"]
     a = jax.random.normal(k[4], (p, m, np_)) / np.sqrt(m)

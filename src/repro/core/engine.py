@@ -1482,7 +1482,7 @@ class AmpEngine:
         a_p, y_p = split_problem(np.asarray(a_mat, np.float32),
                                  np.asarray(y, np.float32), self.cfg.n_proc)
         if self.cfg.kernel_on:
-            a_p, y_p = pad_row_shards(a_p, y_p)
+            a_p, y_p = pad_row_shards(a_p, y_p, self.cfg.a_jdtype)
         return (jnp.asarray(a_p, self.cfg.a_jdtype), jnp.asarray(y_p))
 
     def _split_col(self, y, a_mat):
@@ -1653,7 +1653,7 @@ class AmpEngine:
             a_b = a_mats.reshape(b, p, mp_, n)
         y_b = ys.reshape(b, p, mp_)
         if self.cfg.kernel_on:
-            a_b, _ = pad_row_shards(a_b, None)
+            a_b, _ = pad_row_shards(a_b, None, self.cfg.a_jdtype)
             if a_b.shape[-2] != mp_:
                 y_b = np.pad(y_b,
                              ((0, 0), (0, 0), (0, a_b.shape[-2] - mp_)))
@@ -1753,7 +1753,7 @@ class AmpEngine:
 
             def solve_batch(a_b, y_b, hp: HetParams):
                 if cfg.kernel_on:
-                    a_b, y_b = pad_row_shards(a_b, y_b)
+                    a_b, y_b = pad_row_shards(a_b, y_b, cfg.a_jdtype)
                 return jax.vmap(solve_one)(a_b.astype(cfg.a_jdtype), y_b,
                                            hp)
 
@@ -2082,7 +2082,7 @@ class AmpEngine:
             def solve_padded(a_p, y_p, hp: HetParams):
                 # tile-align the global operands once, before shard_map
                 if cfg.kernel_on:
-                    a_p, y_p = pad_row_shards(a_p, y_p)
+                    a_p, y_p = pad_row_shards(a_p, y_p, cfg.a_jdtype)
                 return fn(a_p.astype(cfg.a_jdtype), y_p, hp)
 
             # donate y only: the sharded A may be a long-lived cached

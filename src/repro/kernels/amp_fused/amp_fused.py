@@ -26,6 +26,12 @@ TPU layout rules (the (8, 128) tiling of the last two block dims):
 * A blocks are ``(1, bm, bn)`` with ``bm`` a multiple of 8 (the whole
   padded shard) or of 128 (tiled shards, whose z' blocks must then be lane
   multiples too) and ``bn`` a multiple of 128 — ``ops.row_tiles``;
+* a block is sized by bytes, not by a fixed column count: ``bn`` is the
+  widest balanced split of N whose ``bm x bn`` block of A fits
+  ``BLOCK_BYTES``. Each grid step carries a fixed pipeline cost (a few
+  tenths of a microsecond on a v5e) beside its DMA, so a 229 KB block
+  (112 x 512 f32) streams A at about half the HBM roofline where a
+  ~1 MB block (112 x 2,560) amortizes it;
 * scalars (the Onsager coefficient in, the sum of squares out) live in
   SMEM as ``(1, 1)`` arrays.
 
@@ -47,7 +53,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BM = 512   # max rows of A per tile (M axis) when a shard is split
-BN = 512   # max cols of A per tile (N axis)
+BN = 512   # N pads no further than a split into tiles of <= BN columns
+BLOCK_BYTES = 2 << 20   # max bytes of one (bm, bn) A block (N axis width)
 
 _HI = jax.lax.Precision.HIGHEST
 _NT = (((1,), (1,)), ((), ()))   # (1, k) x (m, k) -> (1, m)

@@ -14,7 +14,12 @@ of at most ``BM`` rows is one M tile padded to the 8-row sublane quantum
 (its lane-major z' block then equals the whole padded length), so
 serving-sized shards such as Mp = 75 or 100 pad to 80 / 104 rather than
 to a lane multiple; taller shards split into 128-row-aligned tiles. N
-splits into balanced 128-column-aligned tiles of at most ``BN``.
+splits into the fewest balanced 128-column-aligned tiles whose A block
+fits ``BLOCK_BYTES`` (in A's own dtype), padding N no further than tiles
+of at most ``BN`` would: the grid steps are few and large, because each
+step costs a fixed few tenths of a microsecond beside its DMA. The tiles
+depend on A's dtype, so a stack is padded for the dtype the kernel will
+stream (``pad_row_shards(..., a_dtype=)``).
 
 ``amp_local_step`` keeps the single-shard signature (pads per call) for
 per-op tests and external callers; the engine does not use it.
@@ -25,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .amp_fused import BM, BN, amp_local_pallas_grid
+from .amp_fused import BLOCK_BYTES, BM, BN, amp_local_pallas_grid
 from .col import col_inner_pallas, col_residual_pallas, eta_bg_and_deriv
 from .ref import (amp_local_ref, amp_local_ref_grid, col_inner_step_ref,
                   col_residual_ref)
@@ -59,11 +64,25 @@ def _m_tile(m: int, full: int) -> int:
     return _balanced_tile(m, full, 128)
 
 
-def row_tiles(mp: int, n: int) -> tuple[int, int]:
-    """(bm, bn) for a (P, Mp, N) row-shard stack: one M tile up to ``BM``
-    rows, (<= BM, <= BN) tiles beyond; small or slightly-off serving
-    shards pad by at most one quantum per tile."""
-    return _m_tile(mp, BM), _balanced_tile(n, BN, 128)
+def row_tiles(mp: int, n: int, a_bytes: int = 4) -> tuple[int, int]:
+    """(bm, bn) for a (P, Mp, N) row-shard stack of ``a_bytes``-wide A.
+
+    bm: one M tile up to ``BM`` rows (8-row quantum), balanced 128-row
+    tiles beyond. bn: the fewest balanced 128-column tiles whose
+    ``bm x bn x a_bytes`` block fits ``BLOCK_BYTES``, among the splits
+    that pad N no further than balanced tiles of at most ``BN`` columns
+    (so a wider block never grows the padded stack). Fewer, larger grid
+    steps amortize each step's fixed cost: at the paper's bucket (bm 112,
+    N 10,240, f32) four 2,560-column tiles instead of twenty of 512.
+    """
+    bm = _m_tile(mp, BM)
+    n = max(n, 1)
+    limit = _round_up(n, _balanced_tile(n, BN, 128))
+    widest = max(128, BLOCK_BYTES // (bm * a_bytes) // 128 * 128)
+    k = -(-n // widest)
+    while k * _round_up(-(-n // k), 128) > limit:
+        k += 1
+    return bm, _round_up(-(-n // k), 128)
 
 
 def col_tiles(m: int) -> int:
@@ -72,12 +91,15 @@ def col_tiles(m: int) -> int:
     return _m_tile(m, 128)
 
 
-def pad_row_shards(a_p, y_p):
+def pad_row_shards(a_p, y_p, a_dtype=None):
     """Align a (..., P, Mp, N) row-shard stack (+ matching y, or None) to
-    kernel tiles with zero padding. Works on numpy or jax arrays; no-op
-    (returns the inputs unchanged) when already aligned."""
+    the kernel tiles of ``a_dtype`` (default: ``a_p``'s own; pass the
+    dtype the kernel will stream when A is cast after padding) with zero
+    padding. Works on numpy or jax arrays; no-op (returns the inputs
+    unchanged) when already aligned."""
     mp_, n = a_p.shape[-2], a_p.shape[-1]
-    bm, bn = row_tiles(mp_, n)
+    a_bytes = np.dtype(a_p.dtype if a_dtype is None else a_dtype).itemsize
+    bm, bn = row_tiles(mp_, n, a_bytes)
     dm, dn = _round_up(mp_, bm) - mp_, _round_up(n, bn) - n
     if dm == 0 and dn == 0:
         return a_p, y_p
@@ -116,7 +138,7 @@ def amp_local_grid(a_p, x, y_p, z_p, onsager, n_proc: int,
         use_pallas = jax.default_backend() == "tpu"
     if not use_pallas:
         return amp_local_ref_grid(a_p, x, y_p, z_p, onsager, n_proc)
-    bm, bn = row_tiles(a_p.shape[1], a_p.shape[2])
+    bm, bn = row_tiles(a_p.shape[1], a_p.shape[2], a_p.dtype.itemsize)
     return amp_local_pallas_grid(a_p, x, y_p, z_p, onsager, n_proc,
                                  interpret=interpret, bm=bm, bn=bn)
 
@@ -160,7 +182,7 @@ def amp_local_step(a, x, y, z, onsager, n_proc: int,
     ap, yp = pad_row_shards(a[None], y[None])
     xp_ = jnp.pad(x, (0, ap.shape[2] - n))
     zp = jnp.pad(z, (0, ap.shape[1] - m))[None]
-    bm, bn = row_tiles(ap.shape[1], ap.shape[2])
+    bm, bn = row_tiles(ap.shape[1], ap.shape[2], ap.dtype.itemsize)
     z_new, f, _ = amp_local_pallas_grid(jnp.asarray(ap), xp_,
                                         jnp.asarray(yp), zp, onsager, n_proc,
                                         interpret=interpret, bm=bm, bn=bn)

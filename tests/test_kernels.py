@@ -156,6 +156,74 @@ def test_amp_local_grid_matches_ref(p, mp, n, a_dtype):
     np.testing.assert_allclose(float(ss1), float(ss0), rtol=1e-5)
 
 
+@pytest.mark.parametrize("bm,bn", [(128, 128), (128, 256), (256, 128)])
+@pytest.mark.parametrize("a_dtype", ["float32", "bfloat16"])
+def test_amp_local_pallas_grid_multi_tile_matches_ref(bm, bn, a_dtype):
+    """Explicit small tiles, so that both passes accumulate over several
+    grid steps (z over N tiles, f over M tiles, the fused ss over
+    (p, i)): the paths that byte-budgeted tiles leave one-step at small
+    test shapes."""
+    from repro.kernels.amp_fused.amp_fused import amp_local_pallas_grid
+    from repro.kernels.amp_fused.ref import amp_local_ref_grid
+    p, mp, n = 3, 256, 512
+    rng = np.random.default_rng(bm * bn)
+    a = jnp.asarray((rng.normal(size=(p, mp, n)) / np.sqrt(p * mp))
+                    .astype(np.float32)).astype(a_dtype)
+    x = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    y = jnp.asarray(rng.normal(size=(p, mp)).astype(np.float32))
+    z = jnp.asarray(rng.normal(size=(p, mp)).astype(np.float32))
+    z1, f1, ss1 = amp_local_pallas_grid(a, x, y, z, 0.37, 10, interpret=True,
+                                        bm=bm, bn=bn)
+    z0, f0, ss0 = amp_local_ref_grid(a, x, y, z, 0.37, 10)
+    np.testing.assert_allclose(np.asarray(z1), np.asarray(z0),
+                               rtol=3e-5, atol=3e-6)
+    np.testing.assert_allclose(np.asarray(f1), np.asarray(f0),
+                               rtol=3e-5, atol=3e-5)
+    np.testing.assert_allclose(float(ss1), float(ss0), rtol=1e-5)
+
+
+def _round_up(v, q):
+    return -(-v // q) * q
+
+
+def _n_pad_512(n):
+    """N padded by balanced tiles of at most 512 columns (the bound every
+    byte-budgeted split must stay within)."""
+    k = -(-n // 512)
+    return k * _round_up(-(-n // k), 128)
+
+
+@pytest.mark.parametrize("a_bytes", [2, 4])
+@pytest.mark.parametrize("mp", [1, 75, 100, 112, 150, 512, 513, 912, 1024])
+def test_row_tiles_fit_budget_align_and_never_grow_n(mp, a_bytes):
+    """``row_tiles`` over a sweep of N: the A block fits the byte budget,
+    bm sits on the 8-row quantum (one tile) or the 128-row one (several),
+    bn on the 128-lane quantum, padded N never exceeds the 512-column
+    split's, no fewer tiles would do, and the tiles re-derived from the
+    padded stack (as the kernel does) divide it."""
+    from repro.kernels.amp_fused.amp_fused import BLOCK_BYTES
+    from repro.kernels.amp_fused.ops import row_tiles
+    for n in [*range(1, 130_000, 293), 10_000, 10_240, 120_000]:
+        bm, bn = row_tiles(mp, n, a_bytes)
+        if mp <= 512:
+            assert bm == _round_up(mp, 8), (mp, bm)
+        else:
+            assert bm % 128 == 0 and bm <= 512, (mp, bm)
+        assert bn % 128 == 0 and bm * bn * a_bytes <= BLOCK_BYTES, (n, bn)
+        n_pad = _round_up(n, bn)
+        assert n_pad <= _n_pad_512(n), (n, bn)
+        k = n_pad // bn
+        for fewer in range(max(1, k - 3), k):
+            t = _round_up(-(-n // fewer), 128)
+            assert (bm * t * a_bytes > BLOCK_BYTES
+                    or fewer * t > _n_pad_512(n)), (n, bn, fewer)
+        bm2, bn2 = row_tiles(_round_up(mp, bm), n_pad, a_bytes)
+        assert _round_up(mp, bm) % bm2 == 0 and n_pad % bn2 == 0, (n, bn2)
+    # the paper's bucket (Mp 112, N 10,240): four ~1.1 MB f32 blocks
+    assert row_tiles(112, 10_240, 4) == (112, 2560)
+    assert row_tiles(112, 10_240, 2) == (112, 5120)
+
+
 def _walk_eqns(jaxpr):
     """All eqns of a jaxpr, recursing into sub-jaxprs (scan/pjit/...)."""
     for eqn in jaxpr.eqns:

@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.amp_fused import col
+from repro.kernels.amp_fused.amp_fused import BLOCK_BYTES
 from repro.kernels.amp_fused.ops import (amp_local_grid, col_inner_step,
                                          col_residual, col_tiles,
                                          pad_row_shards, row_tiles)
@@ -58,8 +59,8 @@ def _compile(fn, one_chip, *shapes):
 
 def _row_shapes(p, mp, n, a_dtype, batch=()):
     """Aligned (A, x, y, z, onsager) shapes of one row LC step, as the
-    engine pads them (``pad_row_shards``)."""
-    a = jax.ShapeDtypeStruct((p, mp, n), jnp.float32)
+    engine pads them (``pad_row_shards``, for the dtype A streams in)."""
+    a = jax.ShapeDtypeStruct((p, mp, n), a_dtype)
     a_pad, _ = jax.eval_shape(lambda a: pad_row_shards(a, None), a)
     _, mpp, npp = a_pad.shape
     f32 = jnp.float32
@@ -70,10 +71,24 @@ def _row_shapes(p, mp, n, a_dtype, batch=()):
 @pytest.mark.parametrize("a_dtype", [jnp.float32, jnp.bfloat16])
 def test_row_kernel_compiles_at_paper_point(one_chip, a_dtype):
     bm, bn = row_tiles(PAPER["mp"], PAPER["n"])
-    assert (bm, bn) == (104, 512)
+    assert (bm, bn) == (104, 2560)
     step = partial(amp_local_grid, n_proc=PAPER["p"], use_pallas=True)
     _compile(step, one_chip, *_row_shapes(PAPER["p"], PAPER["mp"],
                                           PAPER["n"], a_dtype))
+
+
+@pytest.mark.parametrize("a_dtype", [jnp.float32, jnp.bfloat16])
+def test_row_kernel_compiles_for_tall_proc_shard(one_chip, a_dtype):
+    """A processor-sharded (``proc``) device's stack at N=120,000,
+    M=36,000, P=40 over four chips: 10 processors of 1,024 rows (the
+    912-row bucket in two 512-row tiles, the tallest row tile). The byte
+    budget narrows its N tile, and the kernel still fits the default
+    scoped VMEM."""
+    itemsize = jnp.dtype(a_dtype).itemsize
+    bm, bn = row_tiles(1024, 120_000, itemsize)
+    assert bm == 512 and bm * bn * itemsize <= BLOCK_BYTES
+    step = partial(amp_local_grid, n_proc=40, use_pallas=True)
+    _compile(step, one_chip, *_row_shapes(10, 1024, 120_000, a_dtype))
 
 
 def test_row_kernel_compiles_vmapped_batch(one_chip):
