@@ -6,8 +6,7 @@ Measurements (DESIGN.md §5-§6, hot path §9):
     ``AmpEngine.solve`` at a time vs one ``SolveService`` dispatch
     (>=2x at B=32 on CPU under honest interleaved timing — the historic
     5x figure compared against an under-warmed sequential baseline;
-    ISSUE 6 acceptance: >=1x at B=1 with prewarm + the singleton fast
-    path), and
+    ISSUE 6 acceptance: >=1x at B=1 with prewarm), and
   * request latency percentiles — a prewarmed continuous-batching stream
     timed per request (submit -> result), p50/p95/p99 plus the service's
     operand-cache / compile counters, and
@@ -646,8 +645,8 @@ def main():
     oc = counters["operand_cache"]
     print(f"operand cache: {oc['hits']} hits / {oc['misses']} misses / "
           f"{oc['evictions']} evictions ({oc['bytes'] / 1024:.0f} KiB); "
-          f"compiles {counters['compiles']['total']}; singleton dispatches "
-          f"{counters['singleton_dispatches']}")
+          f"compiles {counters['compiles']['total']}; dispatches "
+          f"{counters['dispatches']['total']}")
     report["latency"] = latency
     report["counters"] = counters
 
@@ -766,8 +765,7 @@ def main():
                         f"acceptance target")
     if args.prewarm and 1 in speedups and speedups[1] < 1.0:
         failures.append(f"B=1 speedup {speedups[1]:.2f}x below the 1x "
-                        f"acceptance target (prewarm + singleton fast "
-                        f"path, ISSUE 6)")
+                        f"acceptance target (with prewarm, ISSUE 6)")
     if latency["incomplete_spans"] != 0:
         failures.append(f"{latency['incomplete_spans']} requests returned "
                         f"incomplete span trees (must be 0)")

@@ -13,22 +13,26 @@ fusion — is one iteration body parameterized by
 ``AmpEngine`` runs the full T-iteration solve as a *single* ``lax.scan``
 over that body — including BT back-tracking rate control, re-expressed as a
 fixed-count in-graph bisection against precomputed MMSE/rate tables — so
-there is no per-iteration host round-trip (the ``float(s2)`` syncs of the
-pre-engine ``mp_amp.py`` host loop). A ``vmap``-batched ``solve_many``
-solves many CS instances at once (the serving scenario), and the local
-computation routes through the ``kernels/amp_fused`` suite (DESIGN.md §8)
-on TPU: batched Pallas grids covering the whole (batch, P) stack in one
-launch with the sigma2_hat reduction fused, fused column-layout kernels,
-tile padding hoisted to solve entry, and optional bf16 A-streaming
-(``EngineConfig.a_dtype``) with f32 accumulation.
+there is no per-iteration host round-trip. The engine has one program
+family, the *het* programs: a ``vmap`` over B instances whose problem
+parameters (true M and N, prior, iteration count, schedule or BT tables,
+erasure mask) ride as per-instance operands (``HetParams``). The serving
+layer batches through them (``dispatch_het``), and ``solve`` /
+``solve_many`` are thin adapters that run the same programs at a batch of
+one or B. The local computation routes through the ``kernels/amp_fused``
+suite (DESIGN.md §8) on TPU: batched Pallas grids covering the whole
+(batch, P) stack in one launch with the sigma2_hat reduction fused, fused
+column-layout kernels, tile padding hoisted to solve entry, and optional
+bf16 A-streaming (``EngineConfig.a_dtype``) with f32 accumulation.
 
 The mesh is an engine axis, not a separate code path (DESIGN.md §6):
-``solve_sharded`` runs the *same* scan body inside ``shard_map`` over a
-mesh axis, with the per-processor (A, y) shards as sharded operands and
-schedules / BT tables riding replicated. Device-collective transports
-(``PsumFusion``, ``CompressedPsumTransport``) make the paper's fusion
-``f_t = sum_p Q(f_t^p)`` an actual (optionally lossy-compressed) collective
-on the device links, with straggler ``drop`` rescaling folded in.
+``dispatch_sharded`` / ``solve_sharded`` run the *same* scan body inside
+``shard_map`` over a mesh axis, with the per-processor (A, y) shards as
+sharded operands and the per-instance operands riding replicated.
+Device-collective transports (``PsumFusion``, ``CompressedPsumTransport``)
+make the paper's fusion ``f_t = sum_p Q(f_t^p)`` an actual (optionally
+lossy-compressed) collective on the device links, with straggler ``drop``
+rescaling folded in.
 
 The partition **layout** is a third engine axis (DESIGN.md §7): the paper's
 row-wise scheme (``RowPartition``, each processor owns M/P rows and the
@@ -47,8 +51,8 @@ evolution (``state_evolution.se_trajectory_col``).
 ``core/amp.py`` (centralized), ``core/mp_amp.py`` (emulated multi-processor)
 and ``launch/solver.py`` (mesh-distributed) are thin frontends over this
 module; arbitrary Python rate-controller callables are still supported via
-``solve_host_loop``, which reuses the exact same jitted iteration body one
-step at a time.
+``solve_host_loop``, which runs the same LC step and transports one
+iteration at a time.
 """
 from __future__ import annotations
 
@@ -89,7 +93,8 @@ __all__ = [
     "RateController", "FixedSchedule", "DPSchedule", "BTRateControl",
     "ColDPSchedule", "ColumnBTRateControl", "ColBTTables", "col_bt_delta_for",
     "BTTables", "HetParams", "bt_delta_for", "stack_bt_tables",
-    "pad_bt_tables", "amp_gc_step", "split_problem", "split_problem_cols",
+    "pad_bt_tables", "het_params", "stack_het_params", "amp_gc_step",
+    "split_problem", "split_problem_cols",
 ]
 
 
@@ -98,25 +103,27 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 def split_problem(a_mat: np.ndarray, y: np.ndarray, n_proc: int):
-    """Row-partition (A, y) across processors: (P, M/P, N), (P, M/P)."""
-    m, n = a_mat.shape
+    """Row-partition (A, y) across processors: (..., M, N), (..., M) ->
+    (..., P, M/P, N), (..., P, M/P)."""
+    *lead, m, n = a_mat.shape
     assert m % n_proc == 0, f"M={m} not divisible by P={n_proc}"
     mp = m // n_proc
-    return a_mat.reshape(n_proc, mp, n), y.reshape(n_proc, mp)
+    return (a_mat.reshape(*lead, n_proc, mp, n),
+            y.reshape(*lead, n_proc, mp))
 
 
 def split_problem_cols(a_mat: np.ndarray, n_proc: int) -> np.ndarray:
-    """Column-partition A across processors: (M, N) -> (P, M, N/P).
+    """Column-partition A across processors: (..., M, N) -> (..., P, M, N/P).
 
     Processor p owns the contiguous column block ``A[:, p*N/P:(p+1)*N/P]``
     and the matching slice of the signal (C-MP-AMP, DESIGN.md §7); y is
     shared, not split — the measurements are common to every processor.
     """
-    m, n = a_mat.shape
+    *lead, m, n = a_mat.shape
     assert n % n_proc == 0, f"N={n} not divisible by P={n_proc}"
     np_ = n // n_proc
-    return np.ascontiguousarray(
-        a_mat.reshape(m, n_proc, np_).transpose(1, 0, 2))
+    return np.ascontiguousarray(np.moveaxis(
+        a_mat.reshape(*lead, m, n_proc, np_), -2, -3))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -962,13 +969,15 @@ class EngineConfig:
 
 
 class HetParams(NamedTuple):
-    """Per-instance operands of a heterogeneous batch (``solve_het``).
+    """Per-instance operands of the het programs (``dispatch_het``).
 
-    Every field carries a leading batch axis B when passed to ``solve_het``
-    (shapes below are per-instance). Together with the per-instance sensing
+    Every field carries a leading batch axis B when passed to
+    ``dispatch_het`` (shapes below are per-instance; ``dispatch_sharded``
+    takes one instance without it). Together with the per-instance sensing
     shards, these are the quantities the serving layer varies *inside* one
     compiled solve; everything structural (padded M/N, P, T_max, transport)
-    is part of the bucket key instead.
+    is part of the bucket key instead. Build them with ``het_params`` and
+    ``stack_het_params``.
     """
 
     sched: jnp.ndarray     # (T,) fixed/DP bin sizes (inf = lossless)
@@ -986,6 +995,56 @@ class HetParams(NamedTuple):
                            # None is an *empty pytree node*, so drop-free
                            # batches keep the pre-erasure operand avals
                            # and programs byte-identical.
+
+
+def het_params(sched, n_iter: int, m: int, n: int, prior: BernoulliGauss,
+               bt: BTTables | ColBTTables | None = None, drop=None,
+               col: bool = False) -> HetParams:
+    """One instance's het operands, without a batch axis.
+
+    ``sched`` holds the instance's own bins, one per iteration it runs
+    (inf = lossless; its length is ``t_active``), padded with lossless
+    bins to the program's ``n_iter``. ``bt`` switches the instance onto
+    its in-graph BT controller: tables already padded to ``n_iter``
+    (``pad_bt_tables``); without it the layout's (``col``) dummy tables
+    ride along unused. ``drop`` is an optional (t_active, P) erasure
+    mask, zero-padded to ``n_iter`` rounds. (m, n) are the true problem
+    dims and ``prior`` the instance's Bernoulli-Gauss prior.
+    """
+    t = len(sched)
+    assert t <= n_iter, f"schedule of {t} exceeds the program's T={n_iter}"
+    s = np.full(n_iter, np.inf, np.float32)
+    s[:t] = sched
+    if drop is not None:
+        d = np.zeros((n_iter,) + np.shape(drop)[1:], np.float32)
+        d[:t] = drop
+        drop = d
+    if bt is None:
+        tables = (ColBTTables if col else BTTables).dummy(n_iter)
+    else:
+        assert bt.targets.shape == (n_iter,), bt.targets.shape
+        tables = bt
+    return HetParams(
+        sched=s, t_active=np.int32(t), m_real=np.float32(m),
+        n_real=np.int32(n), eps=np.float32(prior.eps),
+        mu_s=np.float32(prior.mu_s), sigma_s=np.float32(prior.sigma_s),
+        use_bt=np.bool_(bt is not None), bt=tables, drop=drop)
+
+
+def stack_het_params(params: "list[HetParams]") -> HetParams:
+    """Stack per-instance ``het_params`` into one batch (leading axis B).
+    The masks ride only when some instance has one; the others then get
+    all-zero masks (a numeric no-op)."""
+    drops = [p.drop for p in params]
+    if all(d is None for d in drops):
+        drop = None
+    else:
+        zero = np.zeros_like(next(d for d in drops if d is not None))
+        drop = np.stack([zero if d is None else d for d in drops])
+    # every field before ``bt`` is a plain array or numpy scalar
+    head = [np.array(col) for col in zip(*(p[:8] for p in params))]
+    return HetParams(*head, bt=stack_bt_tables([p.bt for p in params]),
+                     drop=drop)
 
 
 @dataclasses.dataclass
@@ -1020,15 +1079,15 @@ class AmpEngine:
             controller = FixedSchedule(np.full(cfg.n_iter, np.inf))
         self.controller = controller
         self._jit_cache: dict = {}
-        # program-builder cache lock: builders nest (solve_many's vmap
-        # build calls _scan_fn), hence re-entrant. Background prewarm and
-        # foreground flush() race these dicts otherwise — see _cached.
-        self._build_lock = threading.RLock()
+        # program-builder cache lock: background prewarm and foreground
+        # flush() race these dicts otherwise — see _cached.
+        self._build_lock = threading.Lock()
         # AOT executable cache (DESIGN §9): (program key, operand-aval key)
         # -> jax Compiled. Owning the cache (instead of leaning on jit's
         # internal one) makes compiles *observable* — ``compile_count`` is
         # the serving layer's zero-steady-state-recompile invariant — and
-        # lets ``prewarm``/``compile_het`` populate it ahead of traffic.
+        # lets ``prewarm`` populate it ahead of traffic
+        # (``dispatch_het(..., compile_only=True)``).
         self._exec_cache: dict = {}
         self._exec_lock = threading.Lock()
         self.compile_count = 0
@@ -1104,8 +1163,7 @@ class AmpEngine:
         thread and a foreground dispatch can never observe a half-built
         entry, build the same program twice, or drop each other's insert
         (plain ``if key not in dict`` admission loses one of two racing
-        builds). The lock is re-entrant because builders nest — the
-        vmapped solve builds wrap ``_scan_fn``/``_col_scan_fn``."""
+        builds)."""
         fn = self._jit_cache.get(key)
         if fn is None:
             with self._build_lock:
@@ -1117,7 +1175,7 @@ class AmpEngine:
 
     # -- shared iteration body ----------------------------------------------
 
-    def _local(self, x, z_p, onsager, a_p, y_p, m_eff=None, axis=None):
+    def _local(self, x, z_p, onsager, a_p, y_p, m_eff, axis=None):
         """LC: the whole processor stack through one batched-grid fused op.
 
         ``a_p`` may be tile-padded (kernel path; ``pad_row_shards`` at
@@ -1125,14 +1183,12 @@ class AmpEngine:
         stays at the true N, so the body pads only the (N,) message vector
         (never the (M, N) operand) and slices ``f_p`` back — padded rows/
         columns are exactly zero end-to-end, so the fused sum-of-squares
-        is the true sigma2_hat numerator. ``m_eff`` overrides the
-        normalizer (the heterogeneous path passes the *real* measurement
-        count); ``axis`` (sharded mode) makes the plug-in a psum over the
-        mesh axis — one kernel launch per device covers its P/D emulated
-        processors.
+        is the true sigma2_hat numerator. ``m_eff`` is the normalizer, the
+        *real* measurement count; ``axis`` (sharded mode) makes the
+        plug-in a psum over the mesh axis — one kernel launch per device
+        covers its P/D emulated processors.
         """
         cfg = self.cfg
-        m = a_p.shape[0] * a_p.shape[1] if m_eff is None else m_eff
         n, n_pad = x.shape[0], a_p.shape[2]
         x_in = jnp.pad(x, (0, n_pad - n)) if n_pad != n else x
         z_new, f_p, ss = amp_local_grid(
@@ -1142,7 +1198,7 @@ class AmpEngine:
             f_p = f_p[:, :n]
         if axis is not None:
             ss = lax.psum(ss, axis)
-        sigma2_hat = ss / m
+        sigma2_hat = ss / m_eff
         return z_new, f_p, sigma2_hat
 
     def _fuse(self, f_p, delta, drop=None):
@@ -1154,63 +1210,20 @@ class AmpEngine:
         if drop is None:
             assert not hasattr(self.transport, "axis"), \
                 f"{type(self.transport).__name__} is a device-collective " \
-                "transport: solve via solve_sharded/solve_sharded_het, " \
+                "transport: solve via solve_sharded/dispatch_sharded, " \
                 "not the emulated entry points"
             return self.transport.fuse(f_p, delta)
         return self.transport.fuse(f_p, delta, drop)
 
-    def _gc(self, f_p, sigma2_hat, delta, kappa, drop=None):
-        """GC: compress + fuse + denoise. Returns (x, onsager, extra, syms)."""
-        f, extra, syms = self._fuse(f_p, delta, drop)
+    def _gc(self, f_p, sigma2_hat, delta, kappa):
+        """Host-loop GC: compress + fuse + denoise with the engine's
+        prior. Returns (x, onsager, extra, syms)."""
+        f, extra, syms = self._fuse(f_p, delta)
         x_new, onsager_new = amp_gc_step(f, sigma2_hat + extra, self.prior,
                                          kappa)
         return x_new, onsager_new, extra, syms
 
-    def _body(self, carry, xs_t, a_p, y_p, kappa, axis=None, m_eff=None):
-        if axis is None:
-            # erasure-enabled emulated programs thread a (P,) drop mask as
-            # a third scan operand; the 2-tuple form is the drop-free
-            # program, byte-identical to the pre-erasure engine
-            if len(xs_t) == 3:
-                t, sched_delta, drop = xs_t
-            else:
-                (t, sched_delta), drop = xs_t, None
-        else:
-            t, sched_delta, drop = xs_t
-        x, z_p, onsager = carry
-        z_p, f_p, s2 = self._local(x, z_p, onsager, a_p, y_p, m_eff=m_eff,
-                                   axis=axis)
-        if isinstance(self.controller, FixedSchedule):
-            # fixed schedules arrive as a scan operand, so one compiled
-            # solve serves every schedule of the same length
-            delta, rate = sched_delta, jnp.float32(jnp.inf)
-        else:
-            delta, rate = self.controller.delta_for(t, s2)
-        x_new, onsager_new, extra, syms = self._gc(f_p, s2, delta, kappa,
-                                                   drop=drop)
-        cfg = self.cfg
-        out = (s2, delta, extra, rate,
-               x_new if cfg.collect_xs else jnp.zeros(()),
-               syms if cfg.collect_symbols else jnp.zeros(()))
-        return (x_new, z_p, onsager_new), out
-
-    def _sched_operand(self):
-        if isinstance(self.controller, FixedSchedule):
-            deltas = self.controller.deltas[:self.cfg.n_iter]
-            assert len(deltas) == self.cfg.n_iter, \
-                f"schedule has {len(self.controller.deltas)} entries, " \
-                f"need {self.cfg.n_iter}"
-            return jnp.asarray(deltas, jnp.float32)
-        return jnp.zeros(self.cfg.n_iter, jnp.float32)
-
     # -- column-layout iteration body (C-MP-AMP, DESIGN.md §7) ---------------
-
-    def _check_col_controller(self):
-        assert isinstance(self.controller,
-                          (FixedSchedule, ColumnBTRateControl)), \
-            "the column layout takes a FixedSchedule/ColDPSchedule or a " \
-            "ColumnBTRateControl (row-wise controllers predict through " \
-            f"the wrong SE), got {type(self.controller).__name__}"
 
     def _col_gather_x(self, x, axis):
         """Local (P, N/P) signal slices -> the flat (N,) estimate; in
@@ -1232,28 +1245,11 @@ class AmpEngine:
         return (x, jnp.zeros((p_loc,) + y.shape, jnp.float32),
                 jnp.zeros(p_loc, jnp.float32), v0)
 
-    def _col_prior_params(self, hp: HetParams | None = None):
-        """(eps, mu_s, sigma_s^2) as traced/array scalars for the fused
-        column kernels — from ``HetParams`` when given, else the engine's
-        static prior."""
-        if hp is not None:
-            return hp.eps, hp.mu_s, hp.sigma_s**2
-        pr = self.prior
-        # the fused kernel evaluates the BG conditional mean in closed
-        # form in-kernel — it cannot honor an arbitrary denoiser, so make
-        # the coupling explicit rather than silently diverging from the
-        # eta_fn the jnp path would have used
-        assert isinstance(pr, BernoulliGauss), \
-            f"column kernel path requires a BernoulliGauss prior, got " \
-            f"{type(pr).__name__}; solve with use_kernel=False"
-        return (jnp.float32(pr.eps), jnp.float32(pr.mu_s),
-                jnp.float32(pr.sigma_s**2))
-
     def _col_inner_kernels(self, x, g, z_p, a_cp, m_eff, pp, n_mask):
         """Kernel-path counterpart of ``_col_inner``: ``layout.n_inner``
         fused ``col_inner_step`` launches (message + in-kernel denoise +
-        residual update; DESIGN.md §8). ``pp`` is ``_col_prior_params``;
-        ``n_mask`` a (Np,) real-column mask (all-ones when unpadded)."""
+        residual update; DESIGN.md §8). ``pp`` is the prior's
+        ``(eps, mu_s, sigma_s^2)``; ``n_mask`` a (Np,) real-column mask."""
         cfg = self.cfg
         n_inner = cfg.layout.n_inner
         x0 = x
@@ -1265,7 +1261,7 @@ class AmpEngine:
                 interpret=cfg.kernel_interpret)
         return x, c_p, z_p
 
-    def _col_inner(self, x, g, z_p, a_cp, m_eff, eta_fn, n_mask=None):
+    def _col_inner(self, x, g, z_p, a_cp, m_eff, eta_fn, n_mask):
         """``layout.n_inner`` local AMP iterations at each processor on the
         fused residual ``g`` (C-MP-AMP inner stage).
 
@@ -1288,12 +1284,8 @@ class AmpEngine:
             s2_p = jnp.sum(z_p * z_p, axis=-1, keepdims=True) / m_eff
             fn = lambda v, s2=s2_p: eta_fn(v, s2)
             f_p = x + jnp.einsum("pmn,pm->pn", a_cp, z_p, precision=_HI)
-            if n_mask is None:
-                x_new = fn(f_p)
-                deriv = jax.grad(lambda v: jnp.sum(fn(v)))(f_p)
-            else:
-                x_new = fn(f_p) * n_mask
-                deriv = jax.grad(lambda v: jnp.sum(fn(v) * n_mask))(f_p)
+            x_new = fn(f_p) * n_mask
+            deriv = jax.grad(lambda v: jnp.sum(fn(v) * n_mask))(f_p)
             c_p = jnp.sum(deriv, axis=-1) / m_eff
             if t + 1 < n_inner:
                 z_p = (g[None, :]
@@ -1304,7 +1296,7 @@ class AmpEngine:
         return x, c_p, z_p
 
     def _col_round(self, x, mem, coef, delta, a_cp, y, m_eff, eta_fn,
-                   n_mask=None, drop=None, axis=None, pp=None):
+                   n_mask, pp, drop=None, axis=None):
         """Shared round computation: fuse, apply the boundary Onsager
         memory, run the inner stage.  Returns the new carry pieces plus
         the round's trace quantities ``(v_hat, extra, syms)``.
@@ -1312,8 +1304,8 @@ class AmpEngine:
         On the kernel path (``cfg.kernel_on``) the residual contributions
         and the inner stage run as fused Pallas launches (``col_residual``
         / ``col_inner_step``); ``pp`` carries the prior scalars the
-        in-kernel denoiser needs and ``n_mask`` must then be a (Np,)
-        real-column mask. M may be tile-padded: padded rows of A/y are
+        in-kernel denoiser needs. ``n_mask`` masks the real columns of
+        each slice. M may be tile-padded: padded rows of A/y are
         zero, so every padded entry of r/g/z is exactly zero and the
         transports (0 -> 0) and the v_hat sum are unaffected.
         """
@@ -1377,11 +1369,8 @@ class AmpEngine:
         v_hat = jnp.sum(g * g) / m_eff
         z0 = jnp.broadcast_to(g, x.shape[:1] + g.shape)
         if kern:
-            km = (jnp.ones(a_cp.shape[2], jnp.float32) if n_mask is None
-                  else n_mask.reshape(-1))
             x_new, c_p, z_last = self._col_inner_kernels(
-                x, g, z0, a_cp, m_eff,
-                self._col_prior_params() if pp is None else pp, km)
+                x, g, z0, a_cp, m_eff, pp, n_mask.reshape(-1))
         else:
             x_new, c_p, z_last = self._col_inner(x, g, z0, a_cp, m_eff,
                                                  eta_fn, n_mask=n_mask)
@@ -1394,77 +1383,12 @@ class AmpEngine:
             mem_new, coef_new = z_last, c_p
         return x_new, mem_new, coef_new, v_hat, extra, syms
 
-    def _col_body(self, carry, xs_t, a_cp, y, m_eff, axis=None):
-        """One C-MP-AMP outer round: fuse quantized residual contributions,
-        then run the inner stage.
-
-        The scan carry is ``(x, mem, coef, v_prev)``: the per-processor
-        signal slices, the Onsager boundary memory (``_col_init``), and
-        the previous round's plug-in ``||g||^2/M`` — the column controller
-        input (round 0 is lossless for free, so the controller always has
-        a measured variance to act on).
-        """
-        if axis is None:
-            if len(xs_t) == 3:
-                s, sched_delta, drop = xs_t
-            else:
-                (s, sched_delta), drop = xs_t, None
-        else:
-            s, sched_delta, drop = xs_t
-        x, mem, coef, v_prev = carry
-        if isinstance(self.controller, FixedSchedule):
-            delta, rate = sched_delta, jnp.float32(jnp.inf)
-        else:
-            delta, rate = self.controller.delta_for(s, v_prev)
-        prior = self.prior
-        x_new, mem_new, coef_new, v_hat, extra, syms = self._col_round(
-            x, mem, coef, delta, a_cp, y, m_eff,
-            lambda v, s2: eta(v, s2, prior, xp=jnp), drop=drop, axis=axis)
-        # round 0 quantizes all-zero contributions exactly: no noise
-        # actually enters g, whatever bin the schedule names — keep the
-        # trace's accounting truthful
-        extra = jnp.where(s == 0, 0.0, extra)
-        cfg = self.cfg
-        out = (v_hat, delta, extra, rate,
-               self._col_gather_x(x_new, axis) if cfg.collect_xs
-               else jnp.zeros(()),
-               syms if cfg.collect_symbols else jnp.zeros(()))
-        return (x_new, mem_new, coef_new, v_hat), out
-
-    # -- compiled entry points ----------------------------------------------
-
-    def _scan_fn(self, m: int, n: int, erasure: bool = False):
-        """Build (once per shape) the jitted full-solve scan. ``m``/``n``
-        are the *true* problem dims; operands may arrive tile-padded.
-        ``erasure`` programs take a (T, P) drop mask as a fourth operand
-        (threaded as a third scan input); the drop-free program stays
-        byte-identical to the pre-erasure engine."""
-
-        def build():
-            cfg, kappa = self.cfg, m / n
-
-            def solve_core(a_p, y_p, sched, drops=None):
-                init = (jnp.zeros(n, jnp.float32), jnp.zeros_like(y_p),
-                        jnp.zeros(()))
-                body = lambda c, xs: self._body(c, xs, a_p, y_p, kappa,
-                                                m_eff=jnp.float32(m))
-                xs = (jnp.arange(cfg.n_iter), sched)
-                if drops is not None:
-                    xs = xs + (drops,)
-                (x, _, _), outs = jax.lax.scan(body, init, xs)
-                return x, outs
-
-            if erasure:
-                return jax.jit(lambda a_p, y_p, sched, drops:
-                               solve_core(a_p, y_p, sched, drops))
-            return jax.jit(solve_core)
-
-        return self._cached(("scan", m, n, erasure), build)
+    # -- host-loop steps and the trace ----------------------------------------
 
     def _step_fns(self, m: int, n: int):
-        """Jitted single-iteration (LC, GC) pair for host-loop mode — the
-        same body as the scan, sliced at the LC/GC boundary so an online
-        host-side controller can observe sigma_hat_{t,D}^2."""
+        """Jitted single-iteration (LC, GC) pair for host-loop mode, sliced
+        at the LC/GC boundary so an online host-side controller can observe
+        sigma_hat_{t,D}^2."""
 
         def build():
             kappa = m / n
@@ -1475,93 +1399,6 @@ class AmpEngine:
             return (local, gc)
 
         return self._cached(("step", m, n), build)
-
-    def _split(self, y, a_mat):
-        """Row-split (A, y); on the kernel path, tile-align once here —
-        host-side, so no pad of the (M, N) operand enters the program."""
-        a_p, y_p = split_problem(np.asarray(a_mat, np.float32),
-                                 np.asarray(y, np.float32), self.cfg.n_proc)
-        if self.cfg.kernel_on:
-            a_p, y_p = pad_row_shards(a_p, y_p, self.cfg.a_jdtype)
-        return (jnp.asarray(a_p, self.cfg.a_jdtype), jnp.asarray(y_p))
-
-    def _split_col(self, y, a_mat):
-        """Column-split A (shared y); kernel path tile-aligns M here."""
-        a_cp = split_problem_cols(np.asarray(a_mat, np.float32),
-                                  self.cfg.n_proc)
-        y = np.asarray(y, np.float32)
-        if self.cfg.kernel_on:
-            a_cp, y = pad_col_shards(a_cp, y)
-        return jnp.asarray(a_cp, self.cfg.a_jdtype), jnp.asarray(y)
-
-    def _col_scan_fn(self, m: int, n: int, erasure: bool = False):
-        """Build (once per shape) the jitted full-solve column scan.
-        ``erasure`` as in ``_scan_fn`` (mask shape (T, P); column reset
-        semantics — ``_col_round``)."""
-
-        def build():
-            cfg = self.cfg
-            p = cfg.n_proc
-
-            def solve_core(a_cp, y, sched, drops=None):
-                np_ = a_cp.shape[2]
-                init = self._col_init(p, np_, y, jnp.sum(y * y) / m)
-                body = lambda c, xs: self._col_body(c, xs, a_cp, y,
-                                                    jnp.float32(m))
-                xs = (jnp.arange(cfg.n_iter), sched)
-                if drops is not None:
-                    xs = xs + (drops,)
-                (x, _, _, _), outs = jax.lax.scan(body, init, xs)
-                return x.reshape(-1), outs
-
-            if erasure:
-                return jax.jit(lambda a_cp, y, sched, drops:
-                               solve_core(a_cp, y, sched, drops))
-            return jax.jit(solve_core)
-
-        return self._cached(("col", m, n, erasure), build)
-
-    def _solve_col(self, y, a_mat, drop_sched=None) -> EngineTrace:
-        self._check_col_controller()
-        m, n = np.shape(a_mat)             # true dims; _split_col may pad M
-        a_cp, yj = self._split_col(y, a_mat)
-        if drop_sched is None:
-            x, outs = self._col_scan_fn(m, n)(a_cp, yj,
-                                              self._sched_operand())
-        else:
-            drop_sched = np.asarray(drop_sched, np.float32)
-            assert drop_sched.shape == (self.cfg.n_iter, self.cfg.n_proc), \
-                drop_sched.shape
-            x, outs = self._col_scan_fn(m, n, erasure=True)(
-                a_cp, yj, self._sched_operand(), jnp.asarray(drop_sched))
-        return self._trace(x, outs)
-
-    def _solve_many_col(self, ys, a_mats) -> EngineTrace:
-        self._check_col_controller()
-        ys = np.asarray(ys, np.float32)
-        a_mats = np.asarray(a_mats, np.float32)
-        shared_a = a_mats.ndim == 2
-        b = ys.shape[0]
-        p = self.cfg.n_proc
-        m, n = a_mats.shape[-2:]
-        if shared_a:
-            a_b = split_problem_cols(a_mats, p)
-        else:
-            assert a_mats.shape[0] == b
-            a_b = np.stack(
-                [split_problem_cols(a_mats[i], p) for i in range(b)])
-        if self.cfg.kernel_on:
-            a_b, ys = pad_col_shards(a_b, ys)
-        a_b = jnp.asarray(a_b, self.cfg.a_jdtype)
-        y_b = jnp.asarray(ys)
-        def build():
-            fn = self._col_scan_fn(m, n)
-            in_axes = (None, 0, None) if shared_a else (0, 0, None)
-            return jax.jit(jax.vmap(fn, in_axes=in_axes))
-
-        vfn = self._cached(("col_vmap", m, n, shared_a), build)
-        x, outs = vfn(a_b, y_b, self._sched_operand())
-        return self._trace(x, outs)
 
     def _trace(self, x, outs) -> EngineTrace:
         cfg = self.cfg
@@ -1576,115 +1413,21 @@ class AmpEngine:
             xs=np.asarray(xs) if cfg.collect_xs else None,
         )
 
-    def dispatch_single(self, a_p, y_p, m: int, n: int, sched=None,
-                        drop_sched=None, compile_only: bool = False):
-        """Launch one plain (row-layout, homogeneous) solve from pre-split
-        operands, returning raw ``(x, outs)`` — the serving layer's
-        singleton fast path: a lone request skips batch padding and
-        het-operand assembly entirely and runs the true-dims ``_scan_fn``
-        program through the AOT executable cache. ``sched`` overrides the
-        engine controller's schedule operand (lossless/fixed/DP deltas ride
-        here); ``drop_sched`` a (T, P) erasure mask (``ErasureSpec``),
-        routed to the erasure-enabled program variant; ``a_p`` may be a
-        long-lived cached device buffer — this path never donates."""
-        assert not self.cfg.is_col, \
-            "dispatch_single is a row-layout entry point"
-        # keep host operands as numpy: the compiled call's shard_args path
-        # uploads them cheaper than an eager device_put per operand, and
-        # an already-resident cached a_p passes through untouched
-        if getattr(a_p, "dtype", None) != self.cfg.a_jdtype:
-            a_p = np.asarray(a_p, np.float32) \
-                if isinstance(a_p, np.ndarray) and self.cfg.a_dtype == "float32" \
-                else jnp.asarray(a_p, self.cfg.a_jdtype)
-        y_p = np.asarray(y_p, np.float32)
-        if sched is None:
-            sched = self._sched_operand()
-        sched = np.asarray(sched, np.float32)
-        assert sched.shape == (self.cfg.n_iter,), \
-            (sched.shape, self.cfg.n_iter)
-        erasure = drop_sched is not None
-        args = (a_p, y_p, sched)
-        if erasure:
-            drop_sched = np.asarray(drop_sched, np.float32)
-            assert drop_sched.shape == (self.cfg.n_iter, self.cfg.n_proc), \
-                drop_sched.shape
-            args = args + (drop_sched,)
-        return self._run(("scan", m, n, erasure),
-                         self._scan_fn(m, n, erasure), args, compile_only)
-
-    def solve(self, y, a_mat, drop_sched=None) -> EngineTrace:
-        """Full T-iteration solve as one scan-compiled call (no host sync).
-
-        Under a ``ColumnPartition`` layout this is the full outer-round
-        C-MP-AMP solve (``cfg.n_iter`` fusion exchanges).
-
-        ``drop_sched`` (T, P) optionally marks erased fusion packets per
-        iteration (sample one with ``ErasureSpec.sample_mask``): the row
-        layout rescales the survivors unbiasedly, the column layout resets
-        the erased signal blocks (DESIGN.md §10). ``None`` runs the
-        pre-erasure program unchanged."""
-        if self.cfg.is_col:
-            return self._solve_col(y, a_mat, drop_sched)
-        m, n = np.shape(a_mat)             # true dims; _split may tile-pad
-        a_p, y_p = self._split(y, a_mat)
-        return self._trace(*self.dispatch_single(a_p, y_p, m, n,
-                                                 drop_sched=drop_sched))
-
-    def solve_many(self, ys, a_mats) -> EngineTrace:
-        """vmap-batched solve of B independent CS instances.
-
-        ys (B, M); a_mats (B, M, N) or a single shared (M, N) matrix.
-        Symbol collection is typically disabled for batches (memory).
-        """
-        if self.cfg.is_col:
-            return self._solve_many_col(ys, a_mats)
-        ys = np.asarray(ys, np.float32)
-        a_mats = np.asarray(a_mats, np.float32)
-        shared_a = a_mats.ndim == 2
-        b = ys.shape[0]
-        p = self.cfg.n_proc
-        m, n = a_mats.shape[-2:]
-        assert m % p == 0, f"M={m} not divisible by P={p}"
-        mp_ = m // p
-        if shared_a:
-            a_b = a_mats.reshape(p, mp_, n)
-        else:
-            assert a_mats.shape[0] == b
-            a_b = a_mats.reshape(b, p, mp_, n)
-        y_b = ys.reshape(b, p, mp_)
-        if self.cfg.kernel_on:
-            a_b, _ = pad_row_shards(a_b, None, self.cfg.a_jdtype)
-            if a_b.shape[-2] != mp_:
-                y_b = np.pad(y_b,
-                             ((0, 0), (0, 0), (0, a_b.shape[-2] - mp_)))
-        a_b = jnp.asarray(a_b, self.cfg.a_jdtype)
-        y_b = jnp.asarray(y_b)
-
-        def build():
-            fn = self._scan_fn(m, n)
-            in_axes = (None, 0, None) if shared_a else (0, 0, None)
-            return jax.jit(jax.vmap(fn, in_axes=in_axes))
-
-        vfn = self._cached(("vmap", m, n, shared_a), build)
-        x, outs = vfn(a_b, y_b, self._sched_operand())
-        return self._trace(x, outs)
-
-    # -- heterogeneous batches (the serving path) -----------------------------
+    # -- the het program family: every solve runs one of these ----------------
 
     def _body_het(self, carry, xs_t, a_p, y_p, hp: HetParams, n_mask,
                   has_bt: bool, axis=None):
-        """One masked iteration with per-instance (traced) problem params.
-
-        Same LC/GC split as ``_body``; differences: sigma2_hat normalizes by
-        the real M, the denoiser runs with traced prior parameters, the
-        Onsager mean covers only real columns, the quantizer bin comes from
-        either the per-instance schedule operand or the per-instance BT
-        tables, and the carry freezes once ``t >= t_active`` (masked
-        early-exit: short requests return their own T-iteration fixpoint
-        regardless of the bucket's T_max). ``has_bt`` is static: batches
-        with no BT request compile without the in-graph controller.
-        ``axis`` runs the body processor-sharded (the same shard_map mode as
-        ``_body``; HetParams ride replicated).
+        """One masked row iteration with per-instance (traced) problem
+        params: LC (``_local``), then the quantizer bin from either the
+        instance's schedule operand or its BT tables, then GC — fuse and
+        denoise with the instance's prior. sigma2_hat normalizes by the
+        real M, the Onsager sum covers only real columns
+        (``sum(eta' * mask) / M``), and the carry freezes once
+        ``t >= t_active`` (masked early exit: a short request returns its
+        own T-iteration fixpoint whatever the bucket's T_max). ``has_bt``
+        is static: batches with no BT instance compile without the
+        in-graph controller. ``axis`` runs the body processor-sharded
+        inside ``shard_map`` (HetParams ride replicated).
         """
         if axis is None:
             if len(xs_t) == 3:
@@ -1765,9 +1508,15 @@ class AmpEngine:
     def _col_body_het(self, carry, xs_t, a_cp, y, hp: HetParams, n_mask,
                       has_bt: bool, axis=None):
         """One masked C-MP-AMP outer round with per-instance (traced)
-        problem params — the column counterpart of ``_body_het``.  Same
-        carry as ``_col_body`` plus the ``t_active`` freeze; ``hp.bt``
-        holds stacked ``ColBTTables`` for column buckets."""
+        problem params — the column counterpart of ``_body_het``.
+
+        The scan carry is ``(x, mem, coef, v_prev)``: the per-processor
+        signal slices, the Onsager boundary memory (``_col_init``), and
+        the previous round's plug-in ``||g||^2/M`` — the column
+        controller's input (round 0 is lossless for free, so the
+        controller always has a measured variance to act on); it freezes
+        past ``t_active`` like the row carry. ``hp.bt`` holds
+        ``ColBTTables``."""
         if axis is None:
             if len(xs_t) == 3:
                 s, sched_delta, drop = xs_t
@@ -1785,8 +1534,7 @@ class AmpEngine:
         x_new, mem_new, coef_new, v_hat, extra, syms = self._col_round(
             x, mem, coef, delta, a_cp, y, hp.m_real,
             lambda v, s2: eta_bg(v, s2, hp.eps, hp.mu_s, hp.sigma_s**2),
-            n_mask=n_mask, drop=drop, axis=axis,
-            pp=self._col_prior_params(hp))
+            n_mask, (hp.eps, hp.mu_s, hp.sigma_s**2), drop=drop, axis=axis)
         extra = jnp.where(s == 0, 0.0, extra)   # zero round-0 payload
         act = s < hp.t_active
         x1 = jnp.where(act, x_new, x)
@@ -1882,34 +1630,6 @@ class AmpEngine:
                          self._scan_fn_het(mp_, n, has_bt, has_er),
                          (a_b, y_b, params), compile_only)
 
-    def lower_het(self, a_b, y_b, params: HetParams,
-                  has_bt: bool | None = None):
-        """AOT entry: trace + lower the het program for these operands
-        without compiling or executing (inspection / offline compile).
-        Does not touch the executable cache; pair with ``compile_het`` for
-        the cached pipeline."""
-        a_b = jnp.asarray(a_b, self.cfg.a_jdtype)
-        y_b = jnp.asarray(y_b, jnp.float32)
-        if has_bt is None:
-            has_bt = bool(np.any(np.asarray(params.use_bt)))
-        has_er = params.drop is not None
-        if self.cfg.is_col:
-            _, _, m_pad, np_pad = a_b.shape
-            fn = self._col_scan_fn_het(m_pad, np_pad, has_bt, has_er)
-        else:
-            _, _, mp_, n = a_b.shape
-            fn = self._scan_fn_het(mp_, n, has_bt, has_er)
-        return fn.lower(a_b, y_b, params)
-
-    def compile_het(self, a_b, y_b, params: HetParams,
-                    has_bt: bool | None = None):
-        """AOT entry: compile the het program for these operand avals into
-        the executable cache (idempotent) and return the executable.
-        Subsequent ``dispatch_het`` calls with matching shapes/shardings
-        run with zero new compiles."""
-        return self.dispatch_het(a_b, y_b, params, has_bt,
-                                 compile_only=True)
-
     def trace_of(self, x_outs) -> EngineTrace:
         """Materialize a ``dispatch_het``/``dispatch_sharded`` result."""
         return self._trace(*x_outs)
@@ -1946,111 +1666,6 @@ class AmpEngine:
             f"P={self.cfg.n_proc} must be a multiple of the mesh " \
             f"'{axis}' axis ({n_dev})"
         return axis, n_dev
-
-    def _sharded_fn(self, m: int, n: int, mesh, axis: str):
-        """Jitted full-solve scan under shard_map: the same iteration body
-        as ``_scan_fn``, with (A, y) row-sharded over ``axis`` (each device
-        carries P/D emulated processors) and the schedule replicated."""
-
-        def build():
-            cfg, kappa = self.cfg, m / n
-
-            def solve_fn(a_p, y_p, sched, drops):
-                # local: a_p (P/D, M/P, N), y_p (P/D, M/P), drops (T, 1)
-                init = (jnp.zeros(n, jnp.float32), jnp.zeros_like(y_p),
-                        jnp.zeros(()))
-                body = lambda c, xs: self._body(c, xs, a_p, y_p, kappa,
-                                                axis=axis,
-                                                m_eff=jnp.float32(m))
-                (x, _, _), outs = jax.lax.scan(
-                    body, init, (jnp.arange(cfg.n_iter), sched, drops[:, 0]))
-                return x, outs
-
-            fn = jax.shard_map(
-                solve_fn, mesh=mesh,
-                in_specs=(PartitionSpec(axis, None, None),
-                          PartitionSpec(axis, None), PartitionSpec(),
-                          PartitionSpec(None, axis)),
-                out_specs=PartitionSpec(), axis_names={axis}, check_vma=False)
-            return jax.jit(fn)
-
-        return self._cached(("sharded", m, n, mesh, axis), build)
-
-    def _col_sharded_fn(self, m: int, n: int, mesh, axis: str):
-        """Jitted column-layout solve under shard_map: each device owns P/D
-        column blocks; the fusion psums residual contributions (length M)
-        and the boundary Onsager scalar across the mesh axis; y and the
-        fused residual are replicated. ``drops`` (T, n_dev) marks erased
-        device shards per round — column reset semantics
-        (``_col_round``); an all-zeros schedule is bit-exact with the
-        drop-free solve (every adjustment multiplies by exactly 1.0)."""
-
-        def build():
-            cfg = self.cfg
-
-            def solve_fn(a_cp, y, sched, drops):
-                # local: a_cp (P/D, M, N/P); y (M,) replicated
-                p_loc, _, np_ = a_cp.shape
-                init = self._col_init(p_loc, np_, y, jnp.sum(y * y) / m)
-                body = lambda c, xs: self._col_body(c, xs, a_cp, y,
-                                                    jnp.float32(m),
-                                                    axis=axis)
-                (x, _, _, _), outs = jax.lax.scan(
-                    body, init, (jnp.arange(cfg.n_iter), sched, drops[:, 0]))
-                return self._col_gather_x(x, axis), outs
-
-            fn = jax.shard_map(
-                solve_fn, mesh=mesh,
-                in_specs=(PartitionSpec(axis, None, None), PartitionSpec(),
-                          PartitionSpec(), PartitionSpec(None, axis)),
-                out_specs=PartitionSpec(), axis_names={axis}, check_vma=False)
-            return jax.jit(fn)
-
-        return self._cached(("col_sharded", m, n, mesh, axis), build)
-
-    def _solve_sharded_col(self, y, a_mat, mesh, drop_sched=None
-                           ) -> EngineTrace:
-        axis, n_dev = self._sharded_axis(mesh)
-        self._check_col_controller()
-        m, n = np.shape(a_mat)
-        a_cp, yj = self._split_col(y, a_mat)
-        if drop_sched is None:
-            drop_sched = np.zeros((self.cfg.n_iter, n_dev), np.float32)
-        drop_sched = np.asarray(drop_sched, np.float32)
-        assert drop_sched.shape == (self.cfg.n_iter, n_dev), drop_sched.shape
-        x, outs = self._col_sharded_fn(m, n, mesh, axis)(
-            a_cp, yj, self._sched_operand(), jnp.asarray(drop_sched))
-        return self._trace(x, outs)
-
-    def solve_sharded(self, y, a_mat, mesh, drop_sched=None) -> EngineTrace:
-        """Device-sharded solve: row-partitioned (A, y) across the mesh axis
-        of the engine's device-collective transport, fusion on the wire.
-
-        The iteration body, controller, and trace semantics are identical to
-        ``solve`` — only the fusion sum (and the sigma2_hat reduction) cross
-        device links. ``drop_sched`` (T, n_dev) optionally marks straggler/
-        erased shards per iteration; the transport rescales the survivors
-        unbiasedly instead of stalling the solve.
-
-        Under a ``ColumnPartition`` layout the mesh axis carries the column
-        blocks and the fusion psums residual contributions; a dropped shard
-        there is handled by *reset*, not rescale — its signal blocks
-        restart from zero and re-fuse next round (``_col_round``,
-        DESIGN.md §10), since rescaling the other blocks cannot stand in
-        for the missing one.
-        """
-        if self.cfg.is_col:
-            return self._solve_sharded_col(y, a_mat, mesh, drop_sched)
-        axis, n_dev = self._sharded_axis(mesh)
-        m, n = np.shape(a_mat)
-        a_p, y_p = self._split(y, a_mat)
-        if drop_sched is None:
-            drop_sched = np.zeros((self.cfg.n_iter, n_dev), np.float32)
-        drop_sched = np.asarray(drop_sched, np.float32)
-        assert drop_sched.shape == (self.cfg.n_iter, n_dev), drop_sched.shape
-        x, outs = self._sharded_fn(m, n, mesh, axis)(
-            a_p, y_p, self._sched_operand(), jnp.asarray(drop_sched))
-        return self._trace(x, outs)
 
     def _sharded_het_fn(self, mp_: int, n: int, has_bt: bool, mesh,
                         axis: str, has_er: bool = False):
@@ -2176,10 +1791,120 @@ class AmpEngine:
                                               has_er),
                          (a_p, y_p, params), compile_only)
 
-    def solve_sharded_het(self, a_p, y_p, params: HetParams, mesh,
-                          has_bt: bool | None = None) -> EngineTrace:
-        return self._trace(*self.dispatch_sharded(a_p, y_p, params, mesh,
-                                                  has_bt))
+    # -- entry points: thin adapters over the het programs --------------------
+
+    def _params(self, m: int, n: int, drop=None) -> HetParams:
+        """The engine's prior and controller as one instance's het
+        operands: a fixed schedule rides as the schedule operand, a BT
+        controller as its in-graph tables. Any other controller is
+        refused."""
+        t, ctrl = self.cfg.n_iter, self.controller
+        if self.cfg.is_col:
+            assert isinstance(ctrl, (FixedSchedule, ColumnBTRateControl)), \
+                "the column layout takes a FixedSchedule/ColDPSchedule or a " \
+                "ColumnBTRateControl (row-wise controllers predict through " \
+                f"the wrong SE), got {type(ctrl).__name__}"
+        else:
+            assert isinstance(ctrl, (FixedSchedule, BTRateControl)), \
+                "the row layout takes a FixedSchedule/DPSchedule or a " \
+                f"BTRateControl, got {type(ctrl).__name__}"
+        if isinstance(ctrl, FixedSchedule):
+            assert len(ctrl.deltas) >= t, \
+                f"schedule has {len(ctrl.deltas)} entries, need {t}"
+            return het_params(ctrl.deltas[:t], t, m, n, self.prior,
+                              drop=drop, col=self.cfg.is_col)
+        return het_params(np.full(t, np.inf, np.float32), t, m, n,
+                          self.prior, bt=pad_bt_tables(ctrl.tables, t),
+                          drop=drop, col=self.cfg.is_col)
+
+    def _split(self, ys, a_mats):
+        """Split B instances, ys (B, M) and A (B, M, N), into the het
+        program's shards: row (B, P, M/P, N) with y (B, P, M/P), column
+        (B, P, M, N/P) with the shared y (B, M). On the kernel path they
+        are tile-aligned here, host-side, so no pad of A runs on the
+        device."""
+        cfg = self.cfg
+        ys = np.asarray(ys, np.float32)
+        a_mats = np.asarray(a_mats, np.float32)
+        if cfg.is_col:
+            a_b = split_problem_cols(a_mats, cfg.n_proc)
+            return pad_col_shards(a_b, ys) if cfg.kernel_on else (a_b, ys)
+        a_b, y_b = split_problem(a_mats, ys, cfg.n_proc)
+        if cfg.kernel_on:
+            return pad_row_shards(a_b, y_b, cfg.a_jdtype)
+        return a_b, y_b
+
+    def _cut(self, tr: EngineTrace, n: int) -> EngineTrace:
+        """Drop the row layout's tile-padding columns (kernel path) from a
+        trace; column traces keep every entry."""
+        if self.cfg.is_col or tr.x.shape[-1] == n:
+            return tr
+        cut = lambda v: None if v is None else v[..., :n]
+        return dataclasses.replace(tr, x=cut(tr.x), xs=cut(tr.xs),
+                                   symbols=cut(tr.symbols))
+
+    def _solve_lanes(self, ys, a_mats, drop_sched=None) -> EngineTrace:
+        b, m, n = np.shape(a_mats)
+        if drop_sched is not None:
+            drop_sched = np.asarray(drop_sched, np.float32)
+            assert drop_sched.shape == (self.cfg.n_iter, self.cfg.n_proc), \
+                drop_sched.shape
+        params = stack_het_params([self._params(m, n, drop_sched)] * b)
+        a_b, y_b = self._split(ys, a_mats)
+        return self._cut(self.solve_het(a_b, y_b, params), n)
+
+    def solve(self, y, a_mat, drop_sched=None) -> EngineTrace:
+        """Full T-iteration solve of one instance: the het program at a
+        batch of one (no host sync inside).
+
+        Under a ``ColumnPartition`` layout this is the full outer-round
+        C-MP-AMP solve (``cfg.n_iter`` fusion exchanges).
+
+        ``drop_sched`` (T, P) optionally marks erased fusion packets per
+        iteration (sample one with ``ErasureSpec.sample_mask``): the row
+        layout rescales the survivors unbiasedly, the column layout resets
+        the erased signal blocks (DESIGN.md §10). ``None`` runs the
+        drop-free program."""
+        tr = self._solve_lanes(np.asarray(y)[None], np.asarray(a_mat)[None],
+                               drop_sched)
+        return EngineTrace(*(None if v is None else v[0]
+                             for v in vars(tr).values()))
+
+    def solve_many(self, ys, a_mats) -> EngineTrace:
+        """Batched solve of B independent CS instances: the het program
+        at a batch of B. ys (B, M); a_mats (B, M, N) or one (M, N) matrix
+        shared by every lane (broadcast on the host). Symbol collection is
+        typically disabled for batches (memory)."""
+        ys = np.asarray(ys, np.float32)
+        a_mats = np.asarray(a_mats, np.float32)
+        if a_mats.ndim == 2:
+            a_mats = np.broadcast_to(a_mats, (ys.shape[0],) + a_mats.shape)
+        return self._solve_lanes(ys, a_mats)
+
+    def solve_sharded(self, y, a_mat, mesh, drop_sched=None) -> EngineTrace:
+        """Device-sharded solve of one instance: the processor-sharded het
+        program, (A, y) split across the mesh axis of the engine's
+        device-collective transport, fusion on the wire.
+
+        The iteration, controller and trace semantics are those of
+        ``solve``; only the fusion sum (and the sigma2_hat reduction) cross
+        device links. ``drop_sched`` (T, n_dev) optionally marks straggler
+        or erased shards per iteration; the transport rescales the
+        survivors unbiasedly instead of stalling the solve. Under a
+        ``ColumnPartition`` layout the mesh axis carries the column blocks
+        and a dropped shard is *reset*, not rescaled: its signal blocks
+        restart from zero and re-fuse next round (``_col_round``,
+        DESIGN.md §10)."""
+        _, n_dev = self._sharded_axis(mesh)
+        m, n = np.shape(a_mat)
+        if drop_sched is not None:
+            drop_sched = np.asarray(drop_sched, np.float32)
+            assert drop_sched.shape == (self.cfg.n_iter, n_dev), \
+                drop_sched.shape
+        hp = self._params(m, n, drop_sched)
+        a_b, y_b = self._split(np.asarray(y)[None], np.asarray(a_mat)[None])
+        return self._cut(
+            self.trace_of(self.dispatch_sharded(a_b[0], y_b[0], hp, mesh)), n)
 
     def solve_host_loop(self, y, a_mat, host_schedule=None) -> EngineTrace:
         """Per-iteration host loop over the same jitted body.
@@ -2194,7 +1919,8 @@ class AmpEngine:
             "are scan-only (their controllers are in-graph by design)"
         cfg = self.cfg
         m, n = np.shape(a_mat)
-        a_p, y_p = self._split(y, a_mat)
+        a_p, y_p = self._split(np.asarray(y)[None], np.asarray(a_mat)[None])
+        a_p, y_p = jnp.asarray(a_p[0], cfg.a_jdtype), jnp.asarray(y_p[0])
         local, gc = self._step_fns(m, n)
 
         if host_schedule is None:

@@ -49,8 +49,7 @@ from .state_evolution import (CSProblem, erasure_amplification,
 
 __all__ = ["BTController", "bt_schedule_offline", "dp_allocate",
            "dp_allocate_col", "col_sigma_q2_for_rate", "DPResult",
-           "rate_for_sigma_q2", "sigma_q2_for_rate", "stack_schedules",
-           "erasure_rate_factors"]
+           "rate_for_sigma_q2", "sigma_q2_for_rate", "erasure_rate_factors"]
 
 
 def erasure_rate_factors(erasure_rate: float, recovery: str):
@@ -70,23 +69,6 @@ def erasure_rate_factors(erasure_rate: float, recovery: str):
     if recovery == "retransmit":
         return keep, 1.0, 1.0 / keep
     return 1.0, 1.0 / keep, keep
-
-
-def stack_schedules(schedules, n_iter: int) -> np.ndarray:
-    """Stack variable-length per-request delta schedules into (B, n_iter).
-
-    The serving layer buckets requests with different iteration counts into
-    one scan of length ``n_iter`` (the bucket's T_max); shorter schedules
-    are padded with +inf — lossless no-op bins that sit beyond the
-    request's ``t_active`` early-exit mask, so they are never acted on.
-    """
-    out = np.full((len(schedules), n_iter), np.inf, np.float32)
-    for i, sched in enumerate(schedules):
-        sched = np.asarray(sched, np.float32)
-        assert sched.ndim == 1 and len(sched) <= n_iter, \
-            f"schedule {i}: {sched.shape} exceeds bucket T_max={n_iter}"
-        out[i, :len(sched)] = sched
-    return out
 
 
 # ---------------------------------------------------------------------------

@@ -223,7 +223,7 @@ def main():
                  if args.prewarm else "")
               + f", operand cache {oc['hits']} hits / {oc['misses']} misses"
               f" ({oc['bytes'] / (1 << 20):.1f} MiB), "
-              f"{st['singleton_dispatches']} singleton dispatches")
+              f"{st['dispatches']['total']} dispatches")
 
     # telemetry plane (DESIGN.md §12): SE-drift summary + optional dumps
     drifts = [r.se_drift for r in results

@@ -2,7 +2,7 @@
 batches eagerly, flush stragglers on demand (DESIGN.md §5).
 
 The batcher owns no compute — it only decides *which* requests form the
-next ``solve_het`` call. A group dispatches as soon as it reaches
+next ``dispatch_het`` call. A group dispatches as soon as it reaches
 ``policy.max_batch`` (so a steady stream of same-bucket requests runs at
 the full batch width without waiting for a flush), and ``drain`` hands
 back whatever is left, largest groups first (they amortize best).

@@ -3,10 +3,10 @@
 Heterogeneous solve requests arrive with arbitrary (N, M, P, T). XLA
 compiles one program per shape, so the service pads every request up to a
 small set of canonical shapes — the *buckets* — and reuses one compiled
-``AmpEngine.solve_het`` per bucket. The bucket key is exactly the set of
-*structural* parameters (things that change array shapes or the traced
-program); everything else (prior, SNR, schedule, BT tables, iteration
-count) rides as vmapped per-instance operands inside the batch.
+``AmpEngine.dispatch_het`` program per bucket. The bucket key is exactly
+the set of *structural* parameters (things that change array shapes or
+the traced program); everything else (prior, SNR, schedule, BT tables,
+iteration count) rides as vmapped per-instance operands inside the batch.
 
 Padding semantics (must preserve single-solve results bit-near-exactly):
 

@@ -6,7 +6,7 @@ One ``SolveService`` owns a compile cache of ``AmpEngine``s (one per
 ``Batcher``. Requests may differ in *everything* the paper varies — shape
 (N, M), processor count P, prior sparsity, SNR, iteration budget T, and
 rate policy (lossless / fixed schedule / offline DP / online BT) — and the
-service still executes them as a handful of vmapped ``solve_het`` calls:
+service still executes them as a handful of vmapped ``dispatch_het`` calls:
 structural parameters select the bucket, everything else rides as
 per-instance operands (``HetParams``).
 
@@ -48,15 +48,15 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core.denoisers import BernoulliGauss
 from ..core.engine import (AmpEngine, BlockQuantTransport, BTRateControl,
-                           BTTables, ColBTTables, ColDPSchedule,
-                           ColumnBTRateControl, ColumnPartition,
-                           CompressedPsumTransport, EcsqTransport,
-                           EngineConfig, ErasureSpec, HetParams, PsumFusion,
-                           RowPartition, pad_bt_tables, split_problem_cols,
-                           stack_bt_tables)
+                           ColDPSchedule, ColumnBTRateControl,
+                           ColumnPartition, CompressedPsumTransport,
+                           EcsqTransport, EngineConfig, ErasureSpec,
+                           PsumFusion, RowPartition, het_params,
+                           pad_bt_tables, split_problem_cols,
+                           stack_het_params)
 from ..core.quantize import ecsq_entropy, message_mixture, residual_mixture
 from ..core.rate_alloc import (dp_allocate, dp_allocate_col,
-                               erasure_rate_factors, stack_schedules)
+                               erasure_rate_factors)
 from ..core.rate_distortion import RDModel
 from ..core.state_evolution import CSProblem
 from ..telemetry import (DRIFT_ALERT, DRIFT_BUCKETS, DRIFT_COUNTS,
@@ -101,8 +101,7 @@ class SolveRequest:
     ``erasure_seed``).  ``recovery`` selects the bit-accounting
     discipline the allocators plan for — ``"retransmit"`` (lost bits are
     re-sent, shrinking the payload budget) or ``"rate_up"`` (survivors
-    spend the dropped share) — see ``rate_alloc``.  Erasure requests run
-    the het program family (no singleton fast path).
+    spend the dropped share) — see ``rate_alloc``.
 
     ``measure_wire`` opts the request into measured-bytes accounting:
     the engine traces the quantizer symbol streams and the service
@@ -271,7 +270,7 @@ _DRIFT_ATTRS = operator.attrgetter("n_iter", "n", "m", "snr_db",
 
 
 class SolveService:
-    """Shape-bucketed continuous batching over ``AmpEngine.solve_het``,
+    """Shape-bucketed continuous batching over ``AmpEngine.dispatch_het``,
     with mesh-aware bucket placement when a device mesh is provided."""
 
     def __init__(self, policy: BucketPolicy | None = None,
@@ -280,7 +279,6 @@ class SolveService:
                  kernel_interpret: bool = False,
                  mesh=None, mesh_axis: str = "data",
                  operand_cache_bytes: int = 256 << 20,
-                 singleton_fastpath: bool = True,
                  donate: bool = True,
                  wire_model: WireModel | None = None,
                  telemetry: bool = True,
@@ -317,14 +315,11 @@ class SolveService:
         self._pending: list[_Pending] = []
         self._next_id = 0
         # hot-path state (DESIGN.md §9): device-resident A shards keyed by
-        # content fingerprint (0 bytes disables), plain-dispatch routing for
-        # lone row requests, and operand donation on the batched engines
+        # content fingerprint (0 bytes disables), and operand donation on
+        # the batched engines
         self._opcache = (OperandCache(operand_cache_bytes)
                          if operand_cache_bytes > 0 else None)
-        self.singleton_fastpath = singleton_fastpath
         self.donate = donate
-        self._single_engines: dict = {}
-        self._singleton_dispatches = 0
         self._prewarm_report: dict | None = None
         self._prewarm_thread: threading.Thread | None = None
         # a background prewarm's failure, re-raised by the next
@@ -578,26 +573,6 @@ class SolveService:
                 cache[ekey] = eng
         return eng
 
-    def _single_engine(self, req: SolveRequest) -> AmpEngine:
-        """True-dims plain engine for the singleton fast path. Keyed on
-        everything ``_scan_fn`` closes over (the prior lives on the engine
-        here, unlike the het path where it rides as an operand)."""
-        skey = (req.n, req.m, req.n_proc, req.n_iter, req.transport,
-                req.prior)
-        with self._lock:
-            eng = self._single_engines.get(skey)
-            if eng is None:
-                cfg = EngineConfig(
-                    n_proc=req.n_proc, n_iter=req.n_iter,
-                    use_kernel=self.use_kernel,
-                    kernel_interpret=self.kernel_interpret,
-                    collect_symbols=False, collect_xs=self.collect_xs)
-                # donate=False: this path runs on cache-resident operands
-                eng = AmpEngine(req.prior, cfg,
-                                _TRANSPORTS[req.transport]())
-                self._single_engines[skey] = eng
-        return eng
-
     def _dp_deltas(self, req: SolveRequest) -> np.ndarray:
         """Offline DP allocation realized as ECSQ bin sizes (DPSchedule /
         ColDPSchedule for column requests).
@@ -736,8 +711,11 @@ class SolveService:
             y_b = np.zeros((b, mp_pad), np.float32)
         else:
             y_b = np.zeros((b, p, mp_pad), np.float32)
-        scheds, tacts, mreals, nreals = [], [], [], []
-        eps, mus, sss, use_bt, tables = [], [], [], [], []
+        # erasure masks ride only when some request of the batch loses
+        # packets (``stack_het_params``); on the processor-sharded
+        # placement the mask axis is the mesh device
+        p_mask = self.n_devices if key.placement == "proc" else p
+        hps = []
         for i, r in enumerate(batch):
             if is_col:
                 y_b[i, :r.m] = np.asarray(r.y, np.float32)
@@ -745,51 +723,15 @@ class SolveService:
                 mp = r.m // p
                 y_b[i, :, :mp] = np.asarray(r.y, np.float32).reshape(p, mp)
             if r.policy in ("fixed", "dp"):
-                scheds.append(np.asarray(r.deltas, np.float32))
+                sched = r.deltas
             else:  # lossless / bt: schedule operand unused or all-lossless
-                scheds.append(np.full(r.n_iter, np.inf, np.float32))
-            tacts.append(r.n_iter)
-            mreals.append(r.m)
-            nreals.append(r.n)
-            eps.append(r.prior.eps)
-            mus.append(r.prior.mu_s)
-            sss.append(r.prior.sigma_s)
-            if r.policy == "bt":
-                use_bt.append(True)
-                tables.append(self._bt_tables(r, t_max))
-            else:
-                use_bt.append(False)
-                tables.append(ColBTTables.dummy(t_max) if is_col
-                              else BTTables.dummy(t_max))
-
-        # erasure masks ride as a (B, T, P) operand only when some request
-        # in the batch actually loses packets — drop=None keeps the
-        # pre-erasure operand avals and compiled programs byte-identical.
-        # Lossless co-batched requests get all-zero masks (a numeric no-op
-        # through the survivor-rescale/reset paths). On the
-        # processor-sharded placement the mask axis is the mesh device.
-        drops = None
-        if any(r.erasure_rate > 0.0 for r in batch):
-            p_mask = self.n_devices if key.placement == "proc" else p
-            drops = np.zeros((b, t_max, p_mask), np.float32)
-            for i, r in enumerate(batch):
-                m = self._drop_mask(r, p_mask)
-                if m is not None:
-                    drops[i, :r.n_iter] = m
-
-        params = HetParams(
-            sched=stack_schedules(scheds, t_max),
-            t_active=np.asarray(tacts, np.int32),
-            m_real=np.asarray(mreals, np.float32),
-            n_real=np.asarray(nreals, np.int32),
-            eps=np.asarray(eps, np.float32),
-            mu_s=np.asarray(mus, np.float32),
-            sigma_s=np.asarray(sss, np.float32),
-            use_bt=np.asarray(use_bt),
-            bt=stack_bt_tables(tables),
-            drop=drops,
-        )
-        return y_b, params, any(use_bt)
+                sched = np.full(r.n_iter, np.inf, np.float32)
+            bt = self._bt_tables(r, t_max) if r.policy == "bt" else None
+            hps.append(het_params(sched, t_max, r.m, r.n, r.prior, bt=bt,
+                                  drop=self._drop_mask(r, p_mask),
+                                  col=is_col))
+        params = stack_het_params(hps)
+        return y_b, params, bool(params.use_bt.any())
 
     def _het_operands(self, key: BucketKey, batch: list,
                       use_cache: bool = True):
@@ -810,8 +752,6 @@ class SolveService:
         if key.placement == "proc":
             (r,) = reqs     # proc requests dispatch alone, from submit
             return self._dispatch_proc(key, r)
-        if len(reqs) == 1 and self._singleton_ok(key, reqs[0]):
-            return self._dispatch_singleton(key, reqs[0])
 
         b_real = len(reqs)
         b_pad = pad_batch_size(b_real, self.policy)
@@ -995,58 +935,6 @@ class SolveService:
             return None
         return [op, ["compute", None, op[3], pl[3]], pl]
 
-    def _singleton_ok(self, key: BucketKey, r: SolveRequest) -> bool:
-        """Whether a lone request may skip batch padding + het-operand
-        assembly and run the plain true-dims ``dispatch_single`` program
-        (DESIGN.md §9). BT stays on the het path (its controller is the
-        in-graph het table machinery); col stays batched (no plain
-        single-dispatch entry point); erasure and measured-wire requests
-        stay on the het path too (drop operands and symbol tracing are
-        het-program features)."""
-        return (self.singleton_fastpath and key.placement == "local"
-                and key.layout == "row" and r.policy != "bt"
-                and r.erasure_rate == 0.0 and not r.measure_wire)
-
-    def _dispatch_singleton(self, key: BucketKey, r: SolveRequest) \
-            -> _Pending:
-        """Singleton fast path: true-dims solve on a plain engine, A from
-        the operand cache, schedule riding as the ``sched`` operand. No
-        bucket padding, no HetParams stack, no donation (A is
-        cache-resident)."""
-        eng = self._single_engine(r)
-        self._singleton_dispatches += 1
-        tel = self.telemetry
-        with _phase("operands", tel) as op:
-            with _phase("a_stack", tel):
-                ck = ("single", self._fingerprint(r), r.n_proc,
-                      eng.cfg.kernel_on, eng.cfg.a_dtype)
-                # _split row-splits + tile-aligns + casts; cache the
-                # result so a repeated-A stream pays it once
-                build = lambda: eng._split(np.zeros(r.m, np.float32), r.a)[0]
-                a_p = build() if self._opcache is None \
-                    else self._opcache.get(ck, build)
-            with _phase("params", tel):
-                p = r.n_proc
-                mp = r.m // p
-                y_p = np.asarray(r.y, np.float32).reshape(p, mp)
-                mp_pad = a_p.shape[1]
-                if mp_pad != mp:   # kernel-path tile alignment
-                    y_p = np.pad(y_p, ((0, 0), (0, mp_pad - mp)))
-                if r.policy in ("fixed", "dp"):
-                    sched = np.asarray(r.deltas, np.float32)
-                else:
-                    sched = np.full(r.n_iter, np.inf, np.float32)
-        with _phase("dispatch", tel):
-            x_outs = eng.dispatch_single(a_p, y_p, r.m, r.n, sched=sched)
-
-        def finalize() -> list[SolveResult]:
-            with _phase("pull", tel) as pl:
-                trace = eng.trace_of(x_outs)
-            return [self._request_tail(key, r, trace, None, 1,
-                                       self._batch_spans(op, pl))]
-
-        return finalize
-
     def _dispatch_proc(self, key: BucketKey, r: SolveRequest) -> _Pending:
         """Processor-sharded placement: the request owns the whole mesh
         for one ``dispatch_sharded`` call (still padded to the bucket
@@ -1081,7 +969,7 @@ class SolveService:
     def _result_one(self, key: BucketKey, r: SolveRequest, trace,
                     i: int | None, batch_size: int) -> SolveResult:
         """Unpad one request's slice of a trace (``i=None``: unbatched
-        singleton / processor-sharded trace) and account its rates. With
+        processor-sharded trace) and account its rates. With
         telemetry on, a measured-wire result carries its
         ``wire_measure`` span for the tail to place; the tails fill
         ``se_drift`` and the span list."""
@@ -1129,7 +1017,7 @@ class SolveService:
     def _request_tail(self, key: BucketKey, r: SolveRequest, trace,
                       i: int | None, batch_size: int,
                       shared: list | None) -> SolveResult:
-        """One request's result and telemetry tail, for the singleton,
+        """One request's result and telemetry tail, for the
         processor-sharded and measured-wire paths (the batched hot path
         uses ``_batch_tail``): the same ``complete`` span with its
         ``results`` and ``drift`` children, per request, and the
@@ -1318,22 +1206,14 @@ class SolveService:
                 eng.dispatch_het(a_b, y_b, params, has_bt=has_bt,
                                  compile_only=True)
                 programs += 1
-            if self._singleton_ok(key, req):
-                seng = self._single_engine(req)
-                a_p, y_p = seng._split(req.y, req.a)
-                sched = (req.deltas if req.policy in ("fixed", "dp")
-                         else np.full(req.n_iter, np.inf, np.float32))
-                seng.dispatch_single(a_p, y_p, req.m, req.n, sched=sched,
-                                     compile_only=True)
-                programs += 1
         report = {"programs": programs, "buckets": sorted(buckets),
                   "seconds": time.perf_counter() - t0}
         self._prewarm_report = report
         return report
 
     def compile_count(self) -> int:
-        """Total XLA compiles across every engine this service owns (het
-        bucket engines and singleton fast-path engines). Flat after
+        """Total XLA compiles across every engine this service owns (the
+        bucket engines and their measured-wire twins). Flat after
         prewarm under steady-state traffic — the zero-recompile
         invariant tests pin. Each engine's count is read through its
         ``counters()`` snapshot (taken under the engine's compile lock),
@@ -1341,8 +1221,7 @@ class SolveService:
         fully or not at all — never half."""
         with self._lock:
             engines = (list(self._engines.values())
-                       + list(self._wire_engines.values())
-                       + list(self._single_engines.values()))
+                       + list(self._wire_engines.values()))
         return sum(e.counters()["compiles"] for e in engines)
 
     def _collect_metrics(self, reg: MetricsRegistry) -> None:
@@ -1370,9 +1249,6 @@ class SolveService:
             comp.set_total(v, bucket=label)
         for label, v in st["dispatches"]["by_bucket"].items():
             disp.set_total(v, bucket=label)
-        reg.counter("amp_singleton_dispatches_total",
-                    "Singleton fast-path dispatches").set_total(
-                        st["singleton_dispatches"])
         dem = reg.counter("amp_bucket_demand_total",
                           "Requests ever admitted per bucket", ("bucket",))
         for k, v in st["bucket_demand"].items():
@@ -1410,8 +1286,8 @@ class SolveService:
 
     def stats(self) -> dict:
         """Hot-path observability: operand-cache counters, per-bucket
-        compile/dispatch counts, singleton fast-path traffic, per-bucket
-        demand (requests ever admitted), and the last prewarm report.
+        compile/dispatch counts, per-bucket demand (requests ever
+        admitted), and the last prewarm report.
 
         The whole aggregation runs under the service lock and reads each
         engine through its atomic ``counters()`` snapshot: a concurrent
@@ -1425,7 +1301,6 @@ class SolveService:
             engines = ([(k, e, "") for k, e in self._engines.items()]
                        + [(k, e, "/wire")
                           for k, e in self._wire_engines.items()])
-            singles = list(self._single_engines.items())
             by_bucket = {}
             dispatches = {}
             for key, eng, tag in engines:
@@ -1435,13 +1310,7 @@ class SolveService:
                 c = eng.counters()
                 by_bucket[label] = c["compiles"]
                 dispatches[label] = c["dispatches"]
-            for (n, m, p, t, transport, _prior), eng in singles:
-                label = f"single/n{n}/m{m}/p{p}/t{t}/{transport}"
-                c = eng.counters()
-                by_bucket[label] = c["compiles"]
-                dispatches[label] = c["dispatches"]
             demand = self._batcher.demand()
-            singleton_dispatches = self._singleton_dispatches
             prewarm_report = self._prewarm_report
             opstats = (self._opcache.stats()
                        if self._opcache is not None else None)
@@ -1451,7 +1320,6 @@ class SolveService:
                          "by_bucket": by_bucket},
             "dispatches": {"total": sum(dispatches.values()),
                            "by_bucket": dispatches},
-            "singleton_dispatches": singleton_dispatches,
             "bucket_demand": {str(k): v for k, v in demand.items()},
             "prewarm": prewarm_report,
         }

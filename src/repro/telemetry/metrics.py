@@ -33,7 +33,8 @@ __all__ = [
     "LATENCY_BUCKETS", "DRIFT_BUCKETS", "RECOVERY_BUCKETS", "HOST_STATES",
 ]
 
-# Request latencies span ~100us (cached singleton) to seconds (cold batch).
+# Request latencies span ~100us (a cached lone request) to seconds (a cold
+# batch).
 LATENCY_BUCKETS: Tuple[float, ...] = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0,

@@ -13,12 +13,12 @@ import pytest
 
 from repro.core.amp import sample_problem
 from repro.core.denoisers import BernoulliGauss, make_mmse_interp
-from repro.core.engine import (AmpEngine, BlockQuantTransport, ColBTTables,
+from repro.core.engine import (AmpEngine, BlockQuantTransport,
                                ColDPSchedule, ColumnBTRateControl,
                                ColumnPartition, EcsqTransport, EngineConfig,
-                               ExactFusion, FixedSchedule, HetParams,
-                               col_bt_delta_for, split_problem_cols,
-                               stack_bt_tables)
+                               ExactFusion, FixedSchedule, col_bt_delta_for,
+                               het_params, split_problem_cols,
+                               stack_het_params)
 from repro.core.rate_alloc import col_sigma_q2_for_rate, dp_allocate_col
 from repro.core.state_evolution import CSProblem, se_trajectory_col
 from repro.serving import BucketPolicy, SolveRequest, SolveService
@@ -264,20 +264,11 @@ def test_column_het_padding_is_exact(golden_point):
     a_b[1, :, :560, :450] = split_problem_cols(np.asarray(a1, np.float32),
                                                p)
     y_b[1, :560] = y1
-    from repro.core.rate_alloc import stack_schedules
-    params = HetParams(
-        sched=stack_schedules(
-            [np.full(10, np.inf, np.float32),
-             np.concatenate([[np.inf],
-                             np.full(7, 0.02)]).astype(np.float32)], t_max),
-        t_active=np.asarray([10, 8], np.int32),
-        m_real=np.asarray([600, 560], np.float32),
-        n_real=np.asarray([2000, 1800], np.int32),
-        eps=np.full(2, prior.eps, np.float32),
-        mu_s=np.zeros(2, np.float32), sigma_s=np.ones(2, np.float32),
-        use_bt=np.asarray([False, False]),
-        bt=stack_bt_tables([ColBTTables.dummy(t_max)] * 2),
-    )
+    params = stack_het_params([
+        het_params(np.full(10, np.inf, np.float32), t_max, 600, 2000, prior,
+                   col=True),
+        het_params(np.concatenate([[np.inf], np.full(7, 0.02)]), t_max,
+                   560, 1800, prior, col=True)])
     eng = _col_engine(prior, p, t_max, EcsqTransport(), collect_xs=False)
     tr = eng.solve_het(a_b, y_b, params)
 
@@ -380,17 +371,9 @@ def test_column_het_kernel_interpret_matches_ref(golden_point):
     y_b = np.zeros((1, m_pad), np.float32)
     a_b[0, :, :600, :500] = split_problem_cols(np.asarray(a, np.float32), p)
     y_b[0, :600] = y
-    from repro.core.rate_alloc import stack_schedules
-    params = HetParams(
-        sched=stack_schedules([np.full(6, np.inf, np.float32)], t_max),
-        t_active=np.asarray([6], np.int32),
-        m_real=np.asarray([600], np.float32),
-        n_real=np.asarray([2000], np.int32),
-        eps=np.full(1, prior.eps, np.float32),
-        mu_s=np.zeros(1, np.float32), sigma_s=np.ones(1, np.float32),
-        use_bt=np.asarray([False]),
-        bt=stack_bt_tables([ColBTTables.dummy(t_max)]),
-    )
+    params = stack_het_params([het_params(np.full(6, np.inf, np.float32),
+                                          t_max, 600, 2000, prior,
+                                          col=True)])
     ref = _col_engine(prior, p, t_max, EcsqTransport(),
                       collect_xs=False).solve_het(a_b, y_b, params)
     pal = _col_engine(prior, p, t_max, EcsqTransport(), collect_xs=False,

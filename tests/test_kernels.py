@@ -245,20 +245,21 @@ def test_no_matrix_pad_inside_scan_body():
     contains no rank>=2 ``pad`` (only the cheap (N,) message-vector pad
     is allowed inside the scan)."""
     from repro.core.denoisers import BernoulliGauss
-    from repro.core.engine import AmpEngine, EngineConfig
+    from repro.core.engine import AmpEngine, EngineConfig, stack_het_params
 
     prior = BernoulliGauss(eps=0.1)
     eng = AmpEngine(prior, EngineConfig(n_proc=2, n_iter=3, use_kernel=True,
                                         kernel_interpret=True,
                                         collect_symbols=False))
     m, n = 300, 1000                      # forces tile padding (150 -> 256)
-    a = np.zeros((m, n), np.float32)
-    y = np.zeros(m, np.float32)
-    a_p, y_p = eng._split(y, a)
-    assert a_p.shape != (2, 150, 1000), "test expects a padded shard stack"
-    jaxpr = jax.make_jaxpr(
-        lambda ap, yp, sched: eng._scan_fn(m, n)(ap, yp, sched))(
-            a_p, y_p, eng._sched_operand())
+    a = np.zeros((1, m, n), np.float32)
+    y = np.zeros((1, m), np.float32)
+    a_b, y_b = eng._split(y, a)
+    assert a_b.shape[1:] != (2, 150, 1000), \
+        "test expects a padded shard stack"
+    params = stack_het_params([eng._params(m, n)])
+    jaxpr = jax.make_jaxpr(eng._scan_fn_het(*a_b.shape[2:], False))(
+        a_b, y_b, params)
     scans = [e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "scan"]
     assert scans, "solve should be scan-compiled"
     for scan in scans:

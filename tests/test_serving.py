@@ -8,8 +8,9 @@ import pytest
 from repro.core.amp import sample_problem
 from repro.core.denoisers import BernoulliGauss
 from repro.core.engine import (AmpEngine, BTRateControl, DPSchedule,
-                               EcsqTransport, EngineConfig, FixedSchedule)
-from repro.core.rate_alloc import dp_allocate, stack_schedules
+                               EcsqTransport, EngineConfig, FixedSchedule,
+                               het_params, stack_het_params)
+from repro.core.rate_alloc import dp_allocate
 from repro.core.rate_distortion import RDModel
 from repro.core.state_evolution import CSProblem
 from repro.serving import (Batcher, BucketPolicy, SolveRequest, SolveService,
@@ -102,8 +103,14 @@ def test_batcher_dispatch_and_drain():
 
 
 def test_stack_schedules_padding():
-    out = stack_schedules([np.array([0.1, 0.2]), np.array([0.3])], 4)
-    assert out.shape == (2, 4)
+    """Per-request schedules of different lengths stack into one (B, T)
+    operand, inf-padded past each request's own T (= its t_active)."""
+    lanes = [het_params(s, 4, 8, 8, BernoulliGauss())
+             for s in (np.array([0.1, 0.2]), np.array([0.3]))]
+    params = stack_het_params(lanes)
+    out = params.sched
+    assert out.shape == (2, 4) and out.dtype == np.float32
+    assert params.t_active.tolist() == [2, 1]
     assert np.allclose(out[0, :2], [0.1, 0.2]) and np.isinf(out[0, 2:]).all()
     assert out[1, 0] == np.float32(0.3) and np.isinf(out[1, 1:]).all()
 

@@ -1,7 +1,7 @@
 """Serving hot-path tests (ISSUE 6 acceptance): AOT prewarm -> zero
 steady-state recompiles, operand-cache hit/miss/evict semantics, buffer
-donation not breaking finalize, and the singleton fast path parity-pinned
-against the batched path."""
+donation not breaking finalize, and a lone request parity-pinned against
+a batched group and the engine solve."""
 import jax
 import numpy as np
 import pytest
@@ -104,15 +104,15 @@ def test_zero_recompiles_after_prewarm(inst):
     assert c0 == report["programs"]
 
     # mixed widths over one bucket: a full group, a straggler pair, and a
-    # lone request (the singleton program was prewarmed too); lossless
-    # and fixed share the has_bt=False programs
+    # lone request (the width-1 program of the ladder); lossless and fixed
+    # share the has_bt=False programs
     list(svc.stream([_req(a, y, prior) for _ in range(4)]))
     svc.solve([_req(a, y, prior), _req(a, y, prior, policy="lossless")])
     svc.solve([_req(a, y, prior)])
     stats = svc.stats()
     assert svc.compile_count() == c0, stats["compiles"]
     assert stats["operand_cache"]["hits"] > 0
-    assert stats["singleton_dispatches"] == 1
+    assert stats["dispatches"]["total"] == 3     # one per group
     # demand counters saw every admitted request
     assert sum(stats["bucket_demand"].values()) == 7
 
@@ -129,8 +129,8 @@ def test_prewarm_background_thread(inst):
     c0 = svc.compile_count()
     svc.solve([_req(a, y, prior), _req(a, y, prior)])
     assert svc.compile_count() == c0
-    # het width-2 program + the singleton fast-path program
-    assert svc.stats()["prewarm"]["programs"] == 2
+    # the het width-2 program, the one width asked for
+    assert svc.stats()["prewarm"]["programs"] == 1
 
 
 @pytest.mark.parametrize("background", [False, True])
@@ -224,32 +224,30 @@ def test_cache_disabled_still_serves(inst):
 
 
 # ---------------------------------------------------------------------------
-# singleton fast path + donation
+# lone requests + donation
 # ---------------------------------------------------------------------------
 
-def test_singleton_fastpath_parity(inst):
-    """A lone row request routes through ``dispatch_single`` and matches
-    both the batched het path and the plain engine solve."""
+def test_lone_request_parity(inst):
+    """A lone lossless request runs the het program at width 1, like any
+    group: it equals lane 0 of a B=2 group and the plain engine solve,
+    which runs the same program."""
     prior, a, y, _ = inst
-    fast = SolveService(policy=POLICY)
-    slow = SolveService(policy=POLICY, singleton_fastpath=False)
-    rf, = fast.solve([_req(a, y, prior)])
-    rs, = slow.solve([_req(a, y, prior)])
-    assert fast.stats()["singleton_dispatches"] == 1
-    assert slow.stats()["singleton_dispatches"] == 0
-    assert float(np.mean((rf.x - rs.x) ** 2)) <= 1e-10
-    np.testing.assert_allclose(rf.sigma2_hat, rs.sigma2_hat, rtol=1e-4)
-    np.testing.assert_allclose(rf.rates, rs.rates, rtol=1e-6)
-    # plain-engine reference: the fast path is that exact program
+    svc = SolveService(policy=POLICY)
+    rl, = svc.solve([_req(a, y, prior, policy="lossless")])
+    r0, _ = svc.solve([_req(a, y, prior, policy="lossless"),
+                       _req(a, y, prior)])
+    assert (rl.batch_size, r0.batch_size) == (1, 2)
+    assert float(np.mean((rl.x - r0.x) ** 2)) <= 1e-10
+    np.testing.assert_allclose(rl.sigma2_hat, r0.sigma2_hat, rtol=1e-4)
+    np.testing.assert_allclose(rl.rates, r0.rates, rtol=1e-6)
+    # plain-engine reference: the same program at a batch of one
     eng = AmpEngine(prior, EngineConfig(n_proc=P, n_iter=T,
-                                        collect_symbols=False),
-                    EcsqTransport(), FixedSchedule(np.full(T, 0.05)))
+                                        collect_symbols=False,
+                                        collect_xs=False),
+                    EcsqTransport(), FixedSchedule(np.full(T, np.inf)))
     ref = eng.solve(y, a)
-    np.testing.assert_allclose(rf.x, ref.x)
-    # BT stays on the het path (in-graph controller machinery)
-    rb, = fast.solve([_req(a, y, prior, policy="bt")])
-    assert fast.stats()["singleton_dispatches"] == 1
-    assert np.isfinite(rb.total_bits)
+    np.testing.assert_allclose(rl.x, ref.x)
+    np.testing.assert_allclose(rl.sigma2_hat, ref.sigma2_hat)
 
 
 def test_donation_smoke(inst):
@@ -259,8 +257,7 @@ def test_donation_smoke(inst):
     service."""
     prior, a, y, _ = inst
     svc = SolveService(policy=POLICY, rate_accounting=False)  # donate=True
-    ref = SolveService(policy=POLICY, rate_accounting=False, donate=False,
-                       singleton_fastpath=False)
+    ref = SolveService(policy=POLICY, rate_accounting=False, donate=False)
     assert svc._engine(svc._key_for(svc._prepare(
         _req(a, y, prior)))).cfg.donate
     for _ in range(2):                          # reuse across flushes

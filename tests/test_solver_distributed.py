@@ -47,7 +47,7 @@ assert np.all(nv > 0)   # noise accounting active
 # one compiled solve_sharded program, no per-iteration Python loop
 assert isinstance(sv8._engine, AmpEngine)
 assert isinstance(sv8._engine.transport, CompressedPsumTransport)
-assert [k[0] for k in sv8._engine._jit_cache] == ['sharded']
+assert [k[0] for k in sv8._engine._jit_cache] == ['sharded_het']
 
 # straggler mode still converges to a usable solution
 svd = DistributedMPAMP(mesh, prior, SolverConfig(n_iter=12, bits=8, drop_rate=0.15))

@@ -1,6 +1,6 @@
 """Telemetry-plane tests (DESIGN.md §12): metrics registry + Prometheus
-rendering, span propagation across every serving path (batched,
-singleton fast path, measured-wire twin, cluster), codec round-trips of
+rendering, span propagation across every serving path (batched, lone
+requests, measured-wire twin, cluster), codec round-trips of
 span headers and metrics frames, TCP RTT histograms, and the live
 SE-drift monitor — including the tier-2 acceptance criterion that a
 mis-rated solve is flagged while clean solves pass."""
@@ -283,7 +283,7 @@ def test_codec_span_counts_roundtrip():
     req = dataclasses.replace(req)
     req.spans = [list(s) for s in spans]
     assert decode_request(encode_request(req)).spans == spans
-    res = dataclasses.replace(_solved_singleton(),
+    res = dataclasses.replace(_solved_lone(),
                               spans=[list(s) for s in spans])
     assert decode_result(encode_result(res)).spans == spans
 
@@ -305,22 +305,22 @@ def test_codec_span_and_drift_headers_roundtrip(raw, drift):
     back = decode_request(encode_request(req))
     assert back.spans == spans
 
-    res = dataclasses.replace(_solved_singleton(), se_drift=float(drift),
+    res = dataclasses.replace(_solved_lone(), se_drift=float(drift),
                               spans=[list(s) for s in spans] or None)
     back = decode_result(encode_result(res))
     assert back.se_drift == float(drift)
     assert back.spans == res.spans
 
 
-_SINGLETON_CACHE = []
+_LONE_CACHE = []
 
 
-def _solved_singleton():
-    if not _SINGLETON_CACHE:
+def _solved_lone():
+    if not _LONE_CACHE:
         svc = SolveService(policy=POL, rate_accounting=False)
         _, reqs = make_reqs(1, seed=77)
-        _SINGLETON_CACHE.append(svc.solve(reqs)[0])
-    return _SINGLETON_CACHE[0]
+        _LONE_CACHE.append(svc.solve(reqs)[0])
+    return _LONE_CACHE[0]
 
 
 def test_codec_metrics_frame_roundtrip():
@@ -514,16 +514,16 @@ def test_service_metrics_surface(telem_svc):
     assert "amp_se_drift_bucket" in text
 
 
-def test_singleton_fast_path_span_tree():
-    """The singleton fast path (lone lossless row request) emits the same
-    complete span vocabulary as the batched path."""
+def test_lone_request_span_tree():
+    """A lone lossless row request, dispatched as a group of one, emits
+    the same complete span vocabulary as a full batch."""
     svc = SolveService(policy=POL, rate_accounting=False)
     _, (req,) = make_reqs(1, seed=30)
     req = dataclasses.replace(req, policy="lossless", deltas=None)
     svc.submit(req)
     (res,) = svc.flush()
     assert res.batch_size == 1
-    assert svc.stats()["singleton_dispatches"] == 1
+    assert svc.stats()["dispatches"]["total"] == 1
     assert missing_spans(res.spans) == []
     assert spans_monotonic(res.spans), res.spans
     assert res.se_drift is not None and res.se_drift < DRIFT_ALERT
